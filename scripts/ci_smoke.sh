@@ -48,7 +48,7 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 # runtime hygiene (mirrors scripts/run.sh): persist jit compilations across
 # stage processes — every stage re-imports jax, and recompiles of the same
 # kernels otherwise dominate smoke wall time
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-${TMPDIR:-/tmp}/repro_jax_cache}"
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}"
 
 stage_lint() {
   echo "== lint (ruff) =="
@@ -162,7 +162,7 @@ EOF
 }
 
 stage_tune() {
-  # the stage owns its cache file: CI must not read or write ~/.cache
+  # the stage owns its cache file: CI must not share the checkout's table
   local tune_cache="${REPRO_AUTOTUNE_CACHE:-${TMPDIR:-/tmp}/repro_ci_autotune.json}"
   export REPRO_AUTOTUNE_CACHE="$tune_cache"
   rm -f "$tune_cache"

@@ -17,11 +17,18 @@ the invariants the plane is built on:
     content-addressed no_band/stale_band heal re-assigns the slab, no new
     degraded builds happen, and ``cluster.worker_rejoins`` ticks.
 
+One process per device: the single-host reference (fingerprints and
+losses of every signal the gate registers) is computed up front by a child
+process that exits before any worker starts, and the gate process itself
+never loads JAX — it only drives servers through the SDK.  On a chip host
+the four-chip form of this check is ``python chip_smoke.py --chips 4``.
+
 Run:  python scripts/cluster_gate.py [--reprobe 0.5]
 """
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import pathlib
 import re
@@ -29,6 +36,7 @@ import subprocess
 import sys
 import threading
 import time
+import zlib
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "src"))
@@ -38,9 +46,10 @@ import numpy as np  # noqa: E402
 from repro.client import CoresetClient  # noqa: E402
 from repro.core.segmentation import random_tree_segmentation  # noqa: E402
 from repro.data.signals import piecewise_signal  # noqa: E402
-from repro.service import CoresetEngine  # noqa: E402
 
 N, M, K, EPS = 96, 64, 6, 0.3
+# (signal name, generator seed, loss queries) for each phase of the gate
+SIGNALS = (("sig", 7, 4), ("sig-degraded", 8, 6), ("sig-rejoin", 9, 4))
 _URL_RE = re.compile(r"listening on (http://[\d.]+:\d+)")
 
 
@@ -91,22 +100,56 @@ def _port(url: str) -> int:
     return int(url.rsplit(":", 1)[1])
 
 
-def _parity(client: CoresetClient, single: CoresetEngine, name: str,
-            y: np.ndarray, errors: list[str], *, queries: int = 4) -> None:
-    """Register + build + query ``name`` on both planes; any fingerprint or
-    loss divergence is appended to ``errors``."""
-    client.register_signal(name, values=y)
-    single.register_signal(name, y)
+def _queries(name: str, count: int) -> list:
+    # seeded from the name's CRC (str hashes differ between processes)
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    return [random_tree_segmentation(N, M, K, rng) for _ in range(count)]
+
+
+def _reference() -> None:
+    """Child process: the single-host build of every gate signal, printed
+    as one JSON line {name: {"fingerprint", "losses"}}."""
+    from repro.service import CoresetEngine
+    single = CoresetEngine(num_bands=3, workers=4)
+    out = {}
+    try:
+        for name, seed, count in SIGNALS:
+            single.register_signal(name, piecewise_signal(N, M, K, seed=seed))
+            cs, _, _ = single.get_coreset(name, K, EPS)
+            out[name] = {"fingerprint": cs.fingerprint(), "losses": [
+                single.tree_loss(name, q.rects, q.labels, eps=EPS)["loss"]
+                for q in _queries(name, count)]}
+    finally:
+        single.close()
+    print(json.dumps(out))
+
+
+def _single_host_reference() -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src" + (os.pathsep + env["PYTHONPATH"]
+                                 if env.get("PYTHONPATH") else "")
+    proc = subprocess.run([sys.executable, __file__, "--reference"],
+                          cwd=ROOT, env=env, capture_output=True, text=True,
+                          timeout=600)
+    if proc.returncode != 0:
+        raise RuntimeError(f"single-host reference failed:\n{proc.stderr}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def _parity(client: CoresetClient, reference: dict, signal: tuple,
+            errors: list[str]) -> None:
+    """Register + build + query one gate signal on the cluster; any
+    fingerprint or loss divergence from the single-host reference is
+    appended to ``errors``."""
+    name, seed, count = signal
+    ref = reference[name]
+    client.register_signal(name, values=piecewise_signal(N, M, K, seed=seed))
     rb = client.build(name, K, EPS)
-    cs, _, _ = single.get_coreset(name, K, EPS)
-    if rb.fingerprint != cs.fingerprint():
+    if rb.fingerprint != ref["fingerprint"]:
         errors.append(f"{name}: cluster fingerprint {rb.fingerprint[:12]} != "
-                      f"single-host {cs.fingerprint()[:12]}")
-    rng = np.random.default_rng(hash(name) % (1 << 31))
-    for _ in range(queries):
-        q = random_tree_segmentation(N, M, K, rng)
+                      f"single-host {ref['fingerprint'][:12]}")
+    for q, ls in zip(_queries(name, count), ref["losses"]):
         rc = client.query_loss(name, q.rects, q.labels, eps=EPS)
-        ls = single.tree_loss(name, q.rects, q.labels, eps=EPS)["loss"]
         if abs(rc.loss - ls) > 1e-9:
             errors.append(f"{name}: loss off single-host by "
                           f"{abs(rc.loss - ls):.2e} > 1e-9")
@@ -117,12 +160,17 @@ def main() -> int:
     ap.add_argument("--reprobe", type=float, default=0.5,
                     help="coordinator down-worker cooldown seconds")
     ap.add_argument("--rpc-timeout", type=float, default=15.0)
+    ap.add_argument("--reference", action="store_true",
+                    help=argparse.SUPPRESS)   # the reference child's role
     args = ap.parse_args()
+    if args.reference:
+        _reference()
+        return 0
 
     procs: list[_Proc] = []
-    single = CoresetEngine(num_bands=3, workers=4)
     errors: list[str] = []
     try:
+        reference = _single_host_reference()
         workers = [_Proc(["--role", "worker", "--host", "127.0.0.1",
                           "--port", "0", "--worker-id", f"gate-w{i}"])
                    for i in range(3)]
@@ -139,8 +187,7 @@ def main() -> int:
               f"{[_port(u) for u in peer_urls]}")
 
         # ---- healthy plane: bitwise fingerprint + 1e-9 loss parity
-        _parity(client, single, "sig", piecewise_signal(N, M, K, seed=7),
-                errors)
+        _parity(client, reference, SIGNALS[0], errors)
         st = client.stats()["cluster"]
         if st["degraded_builds"] != 0:
             errors.append(f"healthy build degraded {st['degraded_builds']}x")
@@ -153,8 +200,7 @@ def main() -> int:
         victim = workers[1]
         victim_port = _port(peer_urls[1])
         victim.kill()
-        _parity(client, single, "sig-degraded",
-                piecewise_signal(N, M, K, seed=8), errors, queries=6)
+        _parity(client, reference, SIGNALS[1], errors)
         st = client.stats()["cluster"]
         degraded = st["degraded_builds"]
         if degraded < 1:
@@ -170,8 +216,7 @@ def main() -> int:
         procs.append(fresh)
         fresh.wait_url()
         time.sleep(args.reprobe + 0.2)   # let the cooldown lapse
-        _parity(client, single, "sig-rejoin",
-                piecewise_signal(N, M, K, seed=9), errors)
+        _parity(client, reference, SIGNALS[2], errors)
         st = client.stats()["cluster"]
         if st["degraded_builds"] != degraded:
             errors.append(f"rejoin still degraded: {st['degraded_builds']} "
@@ -187,8 +232,10 @@ def main() -> int:
     finally:
         for p in procs:
             p.kill()
-        single.close()
 
+    if "jax" in sys.modules:
+        errors.append("the gate process loaded JAX (it must only drive "
+                      "servers)")
     for e in errors:
         print(f"[cluster_gate] FAIL: {e}")
     print(f"[cluster_gate] {'PASS' if not errors else 'FAIL'}")

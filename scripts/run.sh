@@ -17,16 +17,16 @@
 #                                   stderr on every large signal.
 #   TF_CPP_MIN_LOG_LEVEL=4          silence the XLA/TSL C++ banner noise on
 #                                   every worker boot.
-#   JAX_COMPILATION_CACHE_DIR       persistent jit cache across restarts
-#                                   (serve_coresets applies it via
-#                                   jax.config at startup).
+#   JAX_COMPILATION_CACHE_DIR       persistent jit cache across restarts;
+#                                   defaults to .jax_cache in the checkout
+#                                   (a fixed path: it is part of the key).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD="${TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD:-17179869184}"
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$HOME/.cache/repro/jax_cache}"
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}"
 
 if [ -z "${LD_PRELOAD:-}" ]; then
   for so in /usr/lib/x86_64-linux-gnu/libtcmalloc.so.4 \

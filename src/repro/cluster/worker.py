@@ -37,7 +37,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import numpy as np
 
-from repro import obs
+from repro import obs, ops
 from repro.core.coreset import SignalCoreset, signal_coreset
 from repro.core.stats import PrefixStats
 from repro.service import protocol as P
@@ -225,6 +225,7 @@ class ShardWorker:
                      for name, st in self._bands.items()}
         return {"status": "ok", "role": "worker",
                 "worker_id": self.worker_id, "bands": bands,
+                "device": ops.device_info(),
                 "uptime_s": self.metrics.uptime_s()}
 
 

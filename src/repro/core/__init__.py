@@ -14,7 +14,7 @@ from .segmentation import (Segmentation, greedy_tree, optimal_labels,
                            optimal_tree_dp, random_tree_segmentation,
                            segment_1d_dp)
 from .streaming import StreamingBuilder, compose, recompress, weighted_signal_coreset
-from .sharded import fitting_loss_batched, sat_pjit, sharded_coreset
+from .sharded import fitting_loss_batched, sharded_coreset
 
 __all__ = [
     "PrefixStats", "opt1_from_sums", "slice_partition", "BalancedPartition",
@@ -25,5 +25,5 @@ __all__ = [
     "Segmentation", "greedy_tree", "optimal_labels", "optimal_tree_dp",
     "random_tree_segmentation", "segment_1d_dp", "StreamingBuilder",
     "compose", "recompress", "weighted_signal_coreset",
-    "fitting_loss_batched", "sat_pjit", "sharded_coreset",
+    "fitting_loss_batched", "sharded_coreset",
 ]

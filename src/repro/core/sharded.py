@@ -5,30 +5,25 @@ disjoint sub-signals compose exactly — see streaming.py).  On a real
 cluster each host builds the coreset of the row band whose data it owns
 (data never leaves the host: only the tiny coresets are gathered), which is
 how the paper's challenge (iv) (parallel training of a single tree) is met.
-In this single-process container the per-band builds run on a thread pool
-(NumPy releases the GIL in the hot loops) and the *array-heavy* stages run
-under pjit on the device mesh:
+In one process the per-band builds run on a thread pool (NumPy releases
+the GIL in the hot loops), and ``fitting_loss_batched`` evaluates MANY
+candidate trees at once (the hyperparameter-tuning inner loop) with blocks
+sharded over a device mesh and one psum at the end.
 
-  * ``sat_pjit``       — the (1, y, y^2) integral images, row-band sharded;
-  * ``fitting_loss_batched`` — Algorithm 5 evaluated for MANY candidate
-    trees at once (the hyperparameter-tuning inner loop), blocks sharded
-    over the mesh and one psum at the end.
+JAX is imported only inside the mesh path: the serving front end and the
+SDK import this module, and a process that only drives servers must not
+load JAX.
 """
 from __future__ import annotations
 
 import concurrent.futures as _fut
-from functools import partial
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from repro.sharding import compat_set_mesh, compat_shard_map
 
 from .coreset import SignalCoreset, signal_coreset
 from .streaming import compose, recompress
 
-__all__ = ["sharded_coreset", "shared_tolerance", "band_bounds", "sat_pjit",
+__all__ = ["sharded_coreset", "shared_tolerance", "band_bounds",
            "fitting_loss_batched"]
 
 
@@ -90,30 +85,6 @@ def sharded_coreset(values: np.ndarray, k: int, eps: float, num_bands: int,
     return recompress(cs) if recompress_result else cs
 
 
-# ----------------------------------------------------------------- pjit SAT
-@partial(jax.jit, static_argnames=("axis_name",))
-def _sat_kernel(y: jnp.ndarray, axis_name=None):
-    w0 = jnp.ones_like(y)
-    stk = jnp.stack([w0, y, y * y], axis=0)          # (3, n, m)
-    s = jnp.cumsum(jnp.cumsum(stk, axis=1), axis=2)
-    return s
-
-
-def sat_pjit(values, mesh=None, data_axis: str = "data"):
-    """Integral images under pjit: rows sharded over the data axis; the
-    cross-band carry is resolved by XLA's partitioned cumsum (a scan +
-    collective-permute chain on TPU)."""
-    y = jnp.asarray(values, jnp.float32)
-    if mesh is None:
-        return _sat_kernel(y)
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    yd = jax.device_put(y, NamedSharding(mesh, P(data_axis, None)))
-    with compat_set_mesh(mesh):
-        out = jax.jit(_sat_kernel,
-                      out_shardings=NamedSharding(mesh, P(None, data_axis, None)))(yd)
-    return out
-
-
 # ------------------------------------------------- batched Algorithm 5 eval
 MESH_BACKEND = "pallas+shard_map"
 
@@ -144,6 +115,8 @@ def fitting_loss_batched(cs: SignalCoreset, seg_rects: np.ndarray,
 
     import time as _time
 
+    import jax
+    import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.common import default_interpret
@@ -177,14 +150,15 @@ def fitting_loss_batched(cs: SignalCoreset, seg_rects: np.ndarray,
         return jax.lax.psum(part, axis_name=data_axis)
 
     spec = P(data_axis, None)
-    f = compat_shard_map(_body, mesh,
-                         in_specs=(spec, spec, spec, P(), P()),
-                         out_specs=P())
+    # replication check off: it cannot see through the Pallas call
+    f = jax.shard_map(_body, mesh=mesh,
+                      in_specs=(spec, spec, spec, P(), P()),
+                      out_specs=P(), check_vma=False)
     size = int(B) * int(T)
     t0 = _time.perf_counter()
     with _span("ops.dispatch", op="fitting_loss_batched",
                backend=MESH_BACKEND, size=size):
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = np.asarray(jax.jit(f)(rects, lab4, w4, sr, sl))
     if _profile._HOOKS:
         _profile.record("fitting_loss_batched", MESH_BACKEND, size,

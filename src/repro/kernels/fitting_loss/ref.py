@@ -18,18 +18,25 @@ def fitting_loss_ref(rects, labels4, weights4, seg_rects, seg_labels):
 
     rects (B,4) f32 half-open block corners; labels4/weights4 (B,4);
     seg_rects (K,4); seg_labels (K,).  Returns the scalar loss.
-    (The smoothed path reduces to the exact moment formula when one leaf
-    covers a block, so no separate exact branch is needed.)
+
+    A leaf that covers a whole block consumes the block's whole mass, which
+    is the exact moment formula of ``core.fitting_loss``'s first phase: a
+    block's mass equals its area in a one-shot coreset but not after a
+    merge-reduce recompression, where smoothing by area alone would drop
+    (or leave unassigned) part of the mass.
     """
     z_r = jnp.clip(jnp.minimum(rects[:, None, 1], seg_rects[None, :, 1])
                    - jnp.maximum(rects[:, None, 0], seg_rects[None, :, 0]), 0, None)
     z_c = jnp.clip(jnp.minimum(rects[:, None, 3], seg_rects[None, :, 3])
                    - jnp.maximum(rects[:, None, 2], seg_rects[None, :, 2]), 0, None)
     z = (z_r * z_c).astype(jnp.float32)              # (B, K)
-    Z = jnp.cumsum(z, axis=1)
-    Zp = Z - z
     U = jnp.cumsum(weights4, axis=1)                  # (B, 4)
     Up = U - weights4
+    area = ((rects[:, 1] - rects[:, 0])
+            * (rects[:, 3] - rects[:, 2])).astype(jnp.float32)
+    z = jnp.where(z == area[:, None], U[:, 3:], z)    # covering leaf: all mass
+    Z = jnp.cumsum(z, axis=1)
+    Zp = Z - z
     lo = jnp.maximum(Zp[:, :, None], Up[:, None, :])
     hi = jnp.minimum(Z[:, :, None], U[:, None, :])
     consumed = jnp.clip(hi - lo, 0.0, None)           # (B, K, 4)

@@ -44,6 +44,10 @@ from ..common import default_interpret
 __all__ = ["histograms_kernel_call"]
 
 _S_PAD = 8  # value lanes (3 used), padded to the f32 sublane quantum
+# f32 values through the MXU at full f32 precision: the default pass
+# rounds them to bf16 (3 significant digits), which the S2 - S1^2/S0
+# split gains downstream cannot absorb.  The one-hot side is exact anyway.
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _hist_kernel(codes_ref, vals_ref, o_ref):
@@ -61,7 +65,7 @@ def _hist_kernel(codes_ref, vals_ref, o_ref):
     for f in range(F):                                        # static unroll
         onehot = (codes_ref[f, :][:, None] == iota).astype(vals_ref.dtype)
         # (S, TP) @ (TP, B): bins on the lane axis, channels on sublanes
-        o_ref[f] += jnp.dot(vals_t, onehot,
+        o_ref[f] += jnp.dot(vals_t, onehot, precision=_PRECISION,
                             preferred_element_type=o_ref.dtype)
 
 
@@ -75,7 +79,7 @@ def _hist_kernel_partials(codes_ref, vals_ref, o_ref):
         jnp.int32, (codes_ref.shape[1], n_bins), 1)
     for f in range(F):
         onehot = (codes_ref[f, :][:, None] == iota).astype(vals_ref.dtype)
-        o_ref[0, f] = jnp.dot(vals_t, onehot,
+        o_ref[0, f] = jnp.dot(vals_t, onehot, precision=_PRECISION,
                               preferred_element_type=o_ref.dtype)
 
 
@@ -92,7 +96,7 @@ def _hist_kernel_legacy(codes_ref, vals_ref, o_ref):
     n_bins = o_ref.shape[1]
     onehot = (codes[:, None] == jax.lax.broadcasted_iota(
         jnp.int32, (codes.shape[0], n_bins), 1)).astype(vals_ref.dtype)
-    o_ref[0] += jnp.dot(onehot.T, vals_ref[...],
+    o_ref[0] += jnp.dot(onehot.T, vals_ref[...], precision=_PRECISION,
                         preferred_element_type=o_ref.dtype)
 
 

@@ -1,6 +1,11 @@
 """Jit'd public wrappers for the sat2d kernel.
 
-Each wrapper exists in two jitted flavours: the plain one, and — on
+The ``*_comp`` wrappers are the compensated (two-float) twins of the
+``ref.py`` ``*_comp_ref`` oracles on the Pallas scan — the chip's path for
+the precision-pinned integral images: they take and return (hi, lo) f32
+pairs, the caller recombining them in f64.
+
+Each plain wrapper exists in two jitted flavours: the plain one, and — on
 accelerator platforms only — one with **buffer donation** on the scan
 inputs.  The ``repro.ops`` backends ship fresh host arrays to the device on
 every call and never touch them again, so the carry/stack buffers can be
@@ -15,9 +20,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import sat2d, scan_rows
+from .kernel import sat2d, scan_rows, scan_rows_comp
 
-__all__ = ["sat", "sat_moments", "delta_sat_moments", "sat_stack"]
+__all__ = ["sat", "sat_moments", "delta_sat_moments", "sat_stack",
+           "sat_moments_comp", "delta_sat_moments_comp", "sat_stack_comp"]
 
 _DEFAULT_TILE = 256
 
@@ -127,3 +133,63 @@ def sat_stack(stk: jnp.ndarray, tile: int = _DEFAULT_TILE,
     fn = (_stack_donate if donate and _donation_supported()
           else _stack_plain)
     return fn(stk, tile=tile, interpret=interpret)
+
+
+# ------------------------------------------------------- compensated twins
+_comp_jit = functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+
+
+def _scan_2d_comp(hi, lo, tile, interpret, init=None):
+    """Compensated inclusive 2-D prefix over the last two axes of a
+    (c, n, m) pair stack: columns within each row, then down the rows
+    (the canonical order of ``ref.sat_stack_ref``); ``init`` seeds the
+    row-direction pass with a (c, m) carry pair."""
+    c, n, m = hi.shape
+    h, l = scan_rows_comp(hi.reshape(c * n, m), lo.reshape(c * n, m),
+                          tile, tile, interpret=interpret)
+    h = h.reshape(c, n, m).transpose(0, 2, 1).reshape(c * m, n)
+    l = l.reshape(c, n, m).transpose(0, 2, 1).reshape(c * m, n)
+    if init is not None:
+        init = tuple(a.reshape(c * m, 1) for a in init)
+    h, l = scan_rows_comp(h, l, tile, tile, interpret=interpret, init=init)
+    return (h.reshape(c, m, n).transpose(0, 2, 1),
+            l.reshape(c, m, n).transpose(0, 2, 1))
+
+
+@_comp_jit
+def sat_moments_comp(y_hi, y_lo, tile: int = _DEFAULT_TILE,
+                     interpret: bool | None = None):
+    """(hi, lo) pairs of the (3, n, m) moment integral images — the Pallas
+    twin of ``ref.sat_moments_comp_ref`` (analytic ones channel)."""
+    n, m = y_hi.shape
+    h, l = _scan_2d_comp(jnp.stack([y_hi, y_hi * y_hi]),
+                         jnp.stack([y_lo, 2.0 * y_hi * y_lo]), tile,
+                         interpret)
+    counts = ((jnp.arange(1, n + 1, dtype=jnp.float32)[:, None]
+               * jnp.arange(1, m + 1, dtype=jnp.float32)[None, :])[None])
+    return (jnp.concatenate([counts, h], axis=0),
+            jnp.concatenate([jnp.zeros_like(counts), l], axis=0))
+
+
+@_comp_jit
+def delta_sat_moments_comp(carry_hi, carry_lo, tail_hi, tail_lo,
+                           tile: int = _DEFAULT_TILE,
+                           interpret: bool | None = None):
+    """(hi, lo) pairs of the patched (3, b, m) rows — the Pallas twin of
+    ``ref.delta_sat_comp_ref``: the row-direction pass starts from the
+    stored carry row's pair."""
+    ones = jnp.ones_like(tail_hi)
+    return _scan_2d_comp(
+        jnp.stack([ones, tail_hi, tail_hi * tail_hi]),
+        jnp.stack([jnp.zeros_like(tail_hi), tail_lo, 2.0 * tail_hi * tail_lo]),
+        tile, interpret, init=(carry_hi, carry_lo))
+
+
+@_comp_jit
+def sat_stack_comp(stk_hi, stk_lo, tile: int = _DEFAULT_TILE,
+                   interpret: bool | None = None):
+    """Pallas twin of ``ref.sat_stack_comp_ref`` over (hi, lo) pairs."""
+    *lead, n, m = stk_hi.shape
+    h, l = _scan_2d_comp(stk_hi.reshape(-1, n, m), stk_lo.reshape(-1, n, m),
+                         tile, interpret)
+    return h.reshape(*lead, n, m), l.reshape(*lead, n, m)

@@ -17,6 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..common import two_sum
+
 __all__ = [
     "sat2d_ref", "sat_moments_ref", "delta_sat_ref", "sat_stack_ref",
     "split_hi_lo", "comp_cumsum", "sat_moments_comp_ref",
@@ -72,21 +74,10 @@ def split_hi_lo(x) -> tuple[jnp.ndarray, jnp.ndarray]:
     return jnp.asarray(hi), jnp.asarray(lo)
 
 
-def _two_sum(a, b):
-    """Knuth TwoSum on (hi, lo) pairs: the rounding error of ``hi`` adds is
-    recovered exactly and folded into ``lo``."""
-    a_hi, a_lo = a
-    b_hi, b_lo = b
-    s = a_hi + b_hi
-    z = s - a_hi
-    err = (a_hi - (s - z)) + (b_hi - z)
-    return s, a_lo + b_lo + err
-
-
 def comp_cumsum(hi: jnp.ndarray, lo: jnp.ndarray,
                 axis: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Compensated inclusive prefix sum along ``axis`` over (hi, lo) pairs."""
-    return jax.lax.associative_scan(_two_sum, (hi, lo), axis=axis)
+    return jax.lax.associative_scan(two_sum, (hi, lo), axis=axis)
 
 
 def sat_moments_comp_ref(y_hi: jnp.ndarray, y_lo: jnp.ndarray

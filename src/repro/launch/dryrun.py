@@ -158,7 +158,6 @@ def lower_cell(mesh, arch, shape, *, n_layers_override=None, unroll=False,
     # scan-counting problem would otherwise hide mb-1 of the accumulation)
     if unroll and microbatches is None:
         microbatches = 1
-    from repro.sharding import compat_set_mesh
     cfg, step, args, kind = build_cell(arch, shape,
                                        n_layers_override=n_layers_override,
                                        unroll=unroll, remat=remat,
@@ -167,7 +166,7 @@ def lower_cell(mesh, arch, shape, *, n_layers_override=None, unroll=False,
     # production aliasing: train updates (params, opt) in place; decode
     # updates the cache in place
     donate = {"train": (0, 1), "decode": (1,), "prefill": ()}[kind]
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step, in_shardings=in_sh, donate_argnums=donate)
         lowered = jitted.lower(*args)
         compiled = lowered.compile()

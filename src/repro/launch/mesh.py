@@ -8,18 +8,15 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh", "compat_make_mesh"]
+__all__ = ["make_production_mesh", "make_local_mesh", "make_mesh"]
 
 
-def compat_make_mesh(shape, axes, devices):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the AxisType
-    enum itself) only exist on newer jax; older versions get the default
-    (auto) axis semantics, which is what we ask for anyway."""
-    try:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes, devices=devices)
+def make_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with Auto axes: the sharding rules in this repo are
+    written for compiler-propagated (pjit-style) shardings, while
+    ``jax.make_mesh`` defaults to Explicit axes."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,11 +32,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for {shape} mesh, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax (launch/dryrun.py does this)")
-    return compat_make_mesh(shape, axes, devices)
+    return make_mesh(shape, axes, devices)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over whatever devices exist (tests / CPU runs)."""
     n = data * model
-    return compat_make_mesh((data, model), ("data", "model"),
-                            jax.devices()[:n])
+    return make_mesh((data, model), ("data", "model"), jax.devices()[:n])

@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+import pathlib
 import sys
 import threading
 import urllib.request
@@ -159,51 +161,53 @@ def run_smoke(*, clients: int = 4, rounds: int = 6, verbose: bool = True) -> int
     return 0 if not errors else 1
 
 
-def _runtime_hygiene(verbose: bool = True) -> None:
-    """Best-effort serving-process hygiene (the process-level half —
-    tcmalloc preload, TF log silencing — lives in ``scripts/run.sh``):
+def compile_cache_dir() -> str:
+    """Where the persistent XLA compilation cache lives: the directory
+    ``$JAX_COMPILATION_CACHE_DIR`` names (JAX reads it itself), else a
+    fixed path inside the checkout.  The path is part of the cache key, so
+    it must not move between runs."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env
+    return str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
 
-      * persistent XLA compilation cache: jit recompiles of the same
-        kernels across restarts are pure waste on a serving box
-        (``JAX_COMPILATION_CACHE_DIR`` overrides the location);
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent XLA compilation cache before the first
+    compile: a restarted server (or a second smoke run) then reads its
+    kernels back instead of recompiling them.  Returns the directory."""
+    import jax
+    cache_dir = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip():
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
+
+
+def _runtime_hygiene(verbose: bool = True) -> None:
+    """Serving-process hygiene (the process-level half — tcmalloc preload,
+    TF log silencing — lives in ``scripts/run.sh``):
+
+      * persistent XLA compilation cache (:func:`enable_compile_cache`):
+        jit recompiles of the same kernels across restarts are pure waste
+        on a serving box;
       * pre-load the kernel autotune cache so the first dispatch does not
         pay the disk read + fingerprint check mid-request.
-
-    Every step degrades to a no-op on failure: hygiene must never stop a
-    server from booting.
     """
-    import os
-    try:
-        import jax
-        cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                     or os.path.expanduser("~/.cache/repro/jax_cache"))
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        try:    # not present on every jax version shipped in the image
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.5)
-        except Exception:  # noqa: BLE001
-            pass
-        if verbose:
-            print(f"[serve_coresets] XLA compilation cache: {cache_dir}",
-                  flush=True)
-    except Exception as exc:  # noqa: BLE001
-        print(f"[serve_coresets] XLA compilation cache unavailable: "
-              f"{type(exc).__name__}: {exc}", flush=True)
-    try:
-        from repro.ops import autotune
-        snap = autotune.snapshot()
-        if verbose:
-            print(f"[serve_coresets] autotune cache: {snap['entries']} "
-                  f"entries from {snap['cache_path']} "
-                  f"(loaded={snap['cache_loaded']}, "
-                  f"fingerprint {snap['fingerprint']}, "
-                  f"precision={snap['precision_mode']})", flush=True)
-    except Exception as exc:  # noqa: BLE001
-        print(f"[serve_coresets] autotune cache unavailable: "
-              f"{type(exc).__name__}: {exc}", flush=True)
+    cache_dir = enable_compile_cache()
+    from repro.ops import autotune
+    snap = autotune.snapshot()
+    if verbose:
+        print(f"[serve_coresets] XLA compilation cache: {cache_dir}",
+              flush=True)
+        print(f"[serve_coresets] autotune cache: {snap['entries']} "
+              f"entries from {snap['cache_path']} "
+              f"(loaded={snap['cache_loaded']}, "
+              f"fingerprint {snap['fingerprint']}, "
+              f"precision={snap['precision_mode']})", flush=True)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8787)
@@ -271,6 +275,23 @@ def main() -> None:
                          "cache, autotune-cache preload)")
     ap.add_argument("--smoke", action="store_true",
                     help="self-check with concurrent SDK clients, then exit")
+    return ap
+
+
+def make_engine(args, admission=None) -> CoresetEngine:
+    """The single-role engine as the CLI configures it (``chip_smoke.py``
+    serves through this same constructor)."""
+    return CoresetEngine(cache_bytes=args.cache_mb << 20,
+                         workers=args.workers,
+                         num_bands=args.num_bands,
+                         query_window=args.query_window_ms / 1e3,
+                         query_max_fuse=args.query_max_fuse,
+                         coalesce=not args.no_coalesce,
+                         admission=admission)
+
+
+def main() -> None:
+    ap = build_parser()
     args = ap.parse_args()
 
     if not args.no_runtime_hygiene:
@@ -340,13 +361,7 @@ def main() -> None:
         print(f"[serve_coresets] coordinator: {up}/{len(peers)} workers up",
               flush=True)
     else:
-        engine = CoresetEngine(cache_bytes=args.cache_mb << 20,
-                               workers=args.workers,
-                               num_bands=args.num_bands,
-                               query_window=args.query_window_ms / 1e3,
-                               query_max_fuse=args.query_max_fuse,
-                               coalesce=not args.no_coalesce,
-                               admission=admission)
+        engine = make_engine(args, admission)
     srv = make_server(engine, host=args.host, port=args.port,
                       access_log=access_fp, slow_ms=args.slow_ms)
     print(f"[serve_coresets] listening on http://{args.host}:"

@@ -23,8 +23,7 @@ from repro.data.tokens import TokenStream
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import init_params
 from repro.runtime.fault_tolerance import supervise
-from repro.sharding import (compat_set_mesh, named,
-                            opt_specs, param_specs)
+from repro.sharding import named, opt_specs, param_specs
 from repro.train import AdamWConfig, adamw_init, make_train_step
 
 __all__ = ["train_loop", "main"]
@@ -48,7 +47,7 @@ def train_loop(cfg, *, steps: int, batch: int, seq_len: int, mesh=None,
     opt = jax.tree.map(jax.device_put, opt, ospec)
 
     step_fn = make_train_step(cfg, ocfg, num_microbatches=microbatches)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step_fn,
                          in_shardings=(pspec, ospec, None),
                          donate_argnums=(0, 1))
@@ -73,7 +72,7 @@ def train_loop(cfg, *, steps: int, batch: int, seq_len: int, mesh=None,
             raise RuntimeError("injected failure (test)")
         b = stream.batch_at(step)
         batch_dev = {k: jax.numpy.asarray(v) for k, v in b.items()}
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p, o, m = jitted(state["params"], state["opt"], batch_dev)
         loss = float(m["loss"])
         losses.append(loss)

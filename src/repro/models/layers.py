@@ -19,8 +19,7 @@ def maybe_constrain(x: jnp.ndarray, *axes) -> jnp.ndarray:
     """with_sharding_constraint that no-ops when the named axes are absent
     (CPU smoke tests run mesh-less; the dry-run/train run under set_mesh)."""
     from jax.sharding import PartitionSpec as P
-    from repro.sharding import compat_get_abstract_mesh
-    mesh_axes = set(compat_get_abstract_mesh().axis_names)
+    mesh_axes = set(jax.sharding.get_abstract_mesh().axis_names)
     spec = tuple(a if (a in mesh_axes) else None for a in axes)
     if not any(spec):
         return x

@@ -43,8 +43,7 @@ def _dispatch_groups(cfg) -> int:
     """GShard-style dispatch group count = DP shard count: every group's
     sort/cumsum/scatter stays local to its shard (no cross-device gathers),
     and the only cross-shard movement is the expert einsum's TP collectives."""
-    from repro.sharding import compat_get_abstract_mesh
-    sizes = dict(compat_get_abstract_mesh().shape)
+    sizes = dict(jax.sharding.get_abstract_mesh().shape)
     return max(sizes.get("pod", 1) * sizes.get("data", 1), 1)
 
 

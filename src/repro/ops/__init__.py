@@ -28,12 +28,13 @@ import numpy as np
 from . import autotune  # noqa: F401  (tuning cache + precision promotion)
 from . import backends as _backends  # noqa: F401  (registers implementations)
 from .registry import (BACKENDS, ENV_VAR, OPS, BackendError,
-                       available_backends, backend_override, dispatch,
-                       register, resolve, select_backend, snapshot)
+                       available_backends, backend_override, device_info,
+                       dispatch, register, resolve, select_backend, snapshot)
 
 __all__ = [
     "OPS", "BACKENDS", "ENV_VAR", "BackendError", "autotune",
-    "available_backends", "backend_override", "dispatch", "register",
+    "available_backends", "backend_override", "device_info", "dispatch",
+    "register",
     "resolve", "select_backend", "selected_backend", "snapshot",
     "sat_moments", "delta_sat", "fitting_loss", "fitting_loss_batched",
     "hist_split", "streaming_compress",

@@ -12,8 +12,8 @@ module closes all three gaps:
   * a **search**: per (op, backend) configuration space — Pallas tile
     sizes / grid shapes, XLA variant choices, compensated-summation on/off
     — measured against the numpy oracle on representative problems;
-  * a **persisted cache**: ``~/.cache/repro/autotune.json`` (override with
-    ``REPRO_AUTOTUNE_CACHE``), versioned by a fingerprint of the kernel
+  * a **persisted cache**: ``.autotune.json`` in the checkout (override
+    with ``REPRO_AUTOTUNE_CACHE``), versioned by a fingerprint of the kernel
     sources so stale entries never outlive the code they measured; corrupt
     or mismatched caches are ignored, never fatal;
   * a **dispatch consult**: ``registry.select_backend`` asks
@@ -68,14 +68,17 @@ PARITY_RTOL = 1e-6     # compensated-f32 certificate bound vs the f64 oracle
 #   compensated  — two-float (TwoSum) summation, f64-combined on the host
 #   tile / tile_p / tile_t / tile_b — Pallas block shapes
 #   variant      — algorithmically distinct lowering of the same op
+# A TPU host selects pallas before any certificate is consulted, so the
+# pallas integral-image spaces hold compensated configs only: a tuned
+# plain-f32 winner would otherwise be served there.
 SEARCH_SPACE: dict[str, dict[str, list[dict]]] = {
     "sat_moments": {
         "xla": [{"compensated": False}, {"compensated": True}],
-        "pallas": [{"tile": t} for t in (128, 256, 512)],
+        "pallas": [{"tile": t, "compensated": True} for t in (128, 256, 512)],
     },
     "delta_sat": {
         "xla": [{"compensated": False}, {"compensated": True}],
-        "pallas": [{"tile": t} for t in (128, 256, 512)],
+        "pallas": [{"tile": t, "compensated": True} for t in (128, 256, 512)],
     },
     "hist_split": {
         "xla": [{"variant": "vmap", "compensated": False},
@@ -98,7 +101,7 @@ SEARCH_SPACE: dict[str, dict[str, list[dict]]] = {
     },
     "streaming_compress": {
         "xla": [{"compensated": False}, {"compensated": True}],
-        "pallas": [{"tile": t} for t in (128, 256)],
+        "pallas": [{"tile": t, "compensated": True} for t in (128, 256)],
     },
 }
 
@@ -181,7 +184,7 @@ def cache_path() -> pathlib.Path:
     env = os.environ.get(CACHE_ENV_VAR, "").strip()
     if env:
         return pathlib.Path(env).expanduser()
-    return pathlib.Path("~/.cache/repro/autotune.json").expanduser()
+    return pathlib.Path(__file__).resolve().parents[3] / ".autotune.json"
 
 
 class TuneCache:
@@ -526,7 +529,7 @@ def main(argv=None) -> int:
     ap.add_argument("--budget", choices=("quick", "full"), default="quick")
     ap.add_argument("--cache", default=None,
                     help=f"cache file (default: ${CACHE_ENV_VAR} or "
-                         f"~/.cache/repro/autotune.json)")
+                         f".autotune.json in the checkout)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", action="store_true",
                     help="print the tuned entries as JSON on stdout")

@@ -11,7 +11,9 @@ kernel packages.  Conventions:
   * the ``pallas`` implementations accept ``interpret=`` so tests can pin
     interpret mode explicitly;
   * every implementation accepts ``config=`` — a tuning configuration dict
-    (tile sizes, lowering variant, compensated-summation flag).  ``None``
+    (tile sizes, lowering variant, compensated-summation flag; the xla
+    and pallas integral-image backends default to ``compensated`` — plain
+    f32 only when a config asks for it).  ``None``
     means "consult the autotune cache for this problem size" (a cold cache
     yields ``{}`` and the built-in defaults below); the tuner passes
     explicit configs while measuring.  The numpy oracle ignores it.
@@ -30,6 +32,13 @@ import numpy as np
 
 from . import autotune
 from .registry import register
+
+
+def _recombine(pair) -> np.ndarray:
+    """f64 host value of a compensated (hi, lo) f32 device pair."""
+    hi, lo = pair
+    return np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+
 
 # ------------------------------------------------------------- sat_moments
 # (3, n, m) inclusive integral images of (1, y, y^2) — PrefixStats' core.
@@ -60,9 +69,8 @@ def _sat_moments_xla():
     def sat_moments(y, config=None):
         cfg = config if config is not None else autotune.plan(
             "sat_moments", "xla", 3 * np.asarray(y).size)
-        if cfg.get("compensated"):
-            hi, lo = f_comp(*split_hi_lo(y))
-            return np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+        if cfg.get("compensated", True):
+            return _recombine(f_comp(*split_hi_lo(y)))
         return np.asarray(f(jnp.asarray(y, jnp.float32)))
     return sat_moments
 
@@ -71,13 +79,19 @@ def _sat_moments_xla():
 def _sat_moments_pallas():
     import jax.numpy as jnp
     from repro.kernels.sat2d.ops import sat_moments as kernel_sat_moments
+    from repro.kernels.sat2d.ops import sat_moments_comp
+    from repro.kernels.sat2d.ref import split_hi_lo
 
     def sat_moments(y, interpret=None, config=None):
         cfg = config if config is not None else autotune.plan(
             "sat_moments", "pallas", 3 * np.asarray(y).size)
+        tile = int(cfg.get("tile", 256))
+        if cfg.get("compensated", True):
+            return _recombine(sat_moments_comp(*split_hi_lo(y), tile=tile,
+                                               interpret=interpret))
         # donate: the device copy made here is never reused on the host
         return np.asarray(kernel_sat_moments(
-            jnp.asarray(y, jnp.float32), tile=int(cfg.get("tile", 256)),
+            jnp.asarray(y, jnp.float32), tile=tile,
             interpret=interpret, donate=True))
     return sat_moments
 
@@ -116,11 +130,10 @@ def _delta_sat_xla():
     def delta_sat(carry, tail, config=None):
         cfg = config if config is not None else autotune.plan(
             "delta_sat", "xla", 3 * np.asarray(tail).size)
-        if cfg.get("compensated"):
+        if cfg.get("compensated", True):
             # the stored carry enters as its own (hi, lo) pair, so chained
             # patches keep full two-float precision across calls
-            hi, lo = f_comp(*split_hi_lo(carry), *split_hi_lo(tail))
-            return np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+            return _recombine(f_comp(*split_hi_lo(carry), *split_hi_lo(tail)))
         return np.asarray(f(jnp.asarray(carry, jnp.float32),
                             jnp.asarray(tail, jnp.float32)))
     return delta_sat
@@ -129,14 +142,21 @@ def _delta_sat_xla():
 @register("delta_sat", "pallas")
 def _delta_sat_pallas():
     import jax.numpy as jnp
-    from repro.kernels.sat2d.ops import delta_sat_moments
+    from repro.kernels.sat2d.ops import (delta_sat_moments,
+                                        delta_sat_moments_comp)
+    from repro.kernels.sat2d.ref import split_hi_lo
 
     def delta_sat(carry, tail, interpret=None, config=None):
         cfg = config if config is not None else autotune.plan(
             "delta_sat", "pallas", 3 * np.asarray(tail).size)
+        tile = int(cfg.get("tile", 256))
+        if cfg.get("compensated", True):
+            return _recombine(delta_sat_moments_comp(
+                *split_hi_lo(carry), *split_hi_lo(tail), tile=tile,
+                interpret=interpret))
         return np.asarray(delta_sat_moments(
             jnp.asarray(carry, jnp.float32), jnp.asarray(tail, jnp.float32),
-            tile=int(cfg.get("tile", 256)), interpret=interpret, donate=True))
+            tile=tile, interpret=interpret, donate=True))
     return delta_sat
 
 
@@ -403,9 +423,9 @@ def _streaming_compress_xla():
         cfg = config if config is not None else autotune.plan(
             "streaming_compress", "xla", _compress_size(coresets))
         preps = [_recompress_prep(cs) for cs in coresets]
-        if cfg.get("compensated"):
-            hi, lo = f_comp(*split_hi_lo(_stack_rasters(preps, np.float64)))
-            sats = (np.asarray(hi, np.float64) + np.asarray(lo, np.float64))
+        if cfg.get("compensated", True):
+            sats = _recombine(f_comp(*split_hi_lo(
+                _stack_rasters(preps, np.float64))))
         else:
             sats = np.asarray(f(jnp.asarray(_stack_rasters(preps))))
         return _finish_from_sats(coresets, preps, sats, k, eps)
@@ -415,15 +435,22 @@ def _streaming_compress_xla():
 @register("streaming_compress", "pallas")
 def _streaming_compress_pallas():
     import jax.numpy as jnp
-    from repro.kernels.sat2d.ops import sat_stack
+    from repro.kernels.sat2d.ops import sat_stack, sat_stack_comp
+    from repro.kernels.sat2d.ref import split_hi_lo
 
     def sc(coresets, k=None, eps=None, interpret=None, config=None):
         from repro.core.streaming import _recompress_prep
         cfg = config if config is not None else autotune.plan(
             "streaming_compress", "pallas", _compress_size(coresets))
+        tile = int(cfg.get("tile", 256))
         preps = [_recompress_prep(cs) for cs in coresets]
-        sats = np.asarray(sat_stack(jnp.asarray(_stack_rasters(preps)),
-                                    tile=int(cfg.get("tile", 256)),
-                                    interpret=interpret, donate=True))
+        if cfg.get("compensated", True):
+            sats = _recombine(sat_stack_comp(
+                *split_hi_lo(_stack_rasters(preps, np.float64)), tile=tile,
+                interpret=interpret))
+        else:
+            sats = np.asarray(sat_stack(jnp.asarray(_stack_rasters(preps)),
+                                        tile=tile, interpret=interpret,
+                                        donate=True))
         return _finish_from_sats(coresets, preps, sats, k, eps)
     return sc

@@ -22,7 +22,10 @@ directly.  Selection order:
      winner for this (op, device, shape bucket) — only consulted when it
      beat the numpy oracle at tune time, and for precision-pinned ops only
      with a passing compensated-parity certificate;
-  5. capability: on a TPU host, ``pallas`` (the kernels are written for it);
+  5. capability: on a TPU host, ``pallas`` (the kernels are written for
+     it; the integral-image kernels run compensated two-float scans by
+     default — plain f32 images lose the S2 - S1^2/S0 cancellation on
+     signals with a DC offset);
   6. size: below the per-op ``XLA_SIZE_THRESHOLD`` the numpy oracle wins
      (no dispatch/compile overhead), above it the jitted xla path.
      Precision-critical ops (``XLA_SIZE_THRESHOLD[op] is None``) never
@@ -50,7 +53,7 @@ from repro.obs import trace as _trace
 __all__ = [
     "OPS", "BACKENDS", "ENV_VAR", "BackendError", "register",
     "available_backends", "select_backend", "resolve", "dispatch",
-    "backend_override", "snapshot",
+    "backend_override", "snapshot", "device_info",
 ]
 
 OPS = ("sat_moments", "delta_sat", "fitting_loss", "fitting_loss_batched",
@@ -63,8 +66,9 @@ ENV_VAR = "REPRO_OPS_BACKEND"
 # None = NEVER size-promote: sat_moments, delta_sat, hist_split and
 # streaming_compress feed the variance identity S2 - S1^2/S0, which is
 # catastrophically cancellation-sensitive — their float32 xla/pallas
-# backends are only used when explicitly pinned (env/override) or on TPU,
-# where f32 is the documented trade-off.  The two loss ops sum non-negative
+# backends are only used when explicitly pinned (env/override), tuned with
+# a parity certificate, or on TPU, where the integral-image backends
+# default to their compensated configs.  The two loss ops sum non-negative
 # terms, so f32 promotion is safe.
 XLA_SIZE_THRESHOLD = {
     "sat_moments": None,               # precision-critical (f64 oracle)
@@ -142,6 +146,30 @@ def _platform_is_tpu() -> bool:
     # paths (PrefixStats.build, per-node hist_split) must pay it only once
     import jax
     return jax.default_backend() == "tpu"
+
+
+def device_info() -> dict:
+    """The device this process computes on, as JAX reports it, plus the
+    accelerator device files the process holds open (Linux): two processes
+    that each own one chip show different files even where each numbers
+    its only device 0."""
+    import jax
+    d = jax.devices()[0]
+    files = set()
+    fd_dir = "/proc/self/fd"
+    if os.path.isdir(fd_dir):
+        for fd in os.listdir(fd_dir):
+            try:
+                target = os.readlink(os.path.join(fd_dir, fd))
+            except OSError:
+                continue
+            if target.startswith(("/dev/accel", "/dev/vfio/")) \
+                    and target != "/dev/vfio/vfio":
+                files.add(target)
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices()), "id": int(d.id),
+            "coords": list(getattr(d, "coords", ()) or ()),
+            "device_files": sorted(files)}
 
 
 def select_backend(op: str, size: int | None = None) -> str:

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.sharding import named, opt_specs, param_specs
 
 __all__ = ["plan_mesh", "reshard_state"]
@@ -24,7 +24,7 @@ def plan_mesh(n_healthy: int, model_size: int, axis_names=("data", "model")):
             f"cannot keep TP={model_size} with only {n_healthy} devices")
     data = n_healthy // model_size
     devices = jax.devices()[: data * model_size]
-    return compat_make_mesh((data, model_size), axis_names, devices)
+    return make_mesh((data, model_size), axis_names, devices)
 
 
 def reshard_state(state: dict, params_shapes, new_mesh):

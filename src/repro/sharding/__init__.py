@@ -1,59 +1,5 @@
-import jax
-
 from .rules import (batch_specs, cache_specs, data_axes, named, opt_specs,
                     param_specs)
 
 __all__ = ["batch_specs", "cache_specs", "data_axes", "named", "opt_specs",
-           "param_specs", "compat_set_mesh", "compat_abstract_mesh",
-           "compat_get_abstract_mesh", "compat_shard_map"]
-
-
-def compat_get_abstract_mesh():
-    """The mesh currently in scope (jax.sharding.get_abstract_mesh on newer
-    jax; the thread-resources physical mesh set by ``with mesh:`` on older).
-    Outside any mesh context both return an empty mesh (no axis names)."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax._src import mesh as _mesh_lib
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
-def compat_abstract_mesh(axis_sizes, axis_names):
-    """AbstractMesh across jax versions: newer jax takes (sizes, names),
-    older takes a single ((name, size), ...) shape tuple."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    Newer jax exports ``jax.shard_map`` (replication check keyword
-    ``check_vma``); older versions keep it in ``jax.experimental.shard_map``
-    (keyword ``check_rep``).  The replication check is disabled either way:
-    the bodies this repo maps contain a Pallas call, whose replication rule
-    the checker cannot see through.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-
-
-def compat_set_mesh(mesh):
-    """``with compat_set_mesh(mesh):`` across jax versions — newer jax has
-    jax.set_mesh; older versions use the Mesh object's own context manager
-    (same effect for the Auto axis semantics this repo runs under)."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+           "param_specs"]

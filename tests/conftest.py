@@ -6,7 +6,7 @@ import tempfile
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 # Tier-1 selection tests assert the *static* dispatch heuristics; a warm
-# developer tuning cache (~/.cache/repro/autotune.json) must not flip them.
+# developer tuning cache (.autotune.json in the checkout) must not flip them.
 # Point the autotune cache at a fresh per-run path unless the environment
 # already pins one; tuning tests repoint it again via monkeypatch.
 os.environ.setdefault(

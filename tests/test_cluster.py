@@ -8,6 +8,7 @@ gather span linking each worker's root (S3).  Workers run in-process with
 PRIVATE tracers — two roots continuing one trace id in the same ring
 buffer would collide — which also lets the tests inspect the worker side
 of a propagated trace directly."""
+import os
 import threading
 import time
 from types import SimpleNamespace
@@ -260,3 +261,18 @@ def test_cluster_metrics_gauges_histograms_and_stats(cluster):
     wtext = cluster.nodes[0].worker.metrics.render()
     assert "coreset_worker_band_builds" in wtext
     assert "# TYPE coreset_worker_bands_held gauge" in wtext
+
+
+def test_sdk_and_server_modules_import_no_jax():
+    """A process that only launches or drives servers (the cluster gate,
+    ``chip_smoke.py --chips 4``) must not load JAX: on a chip host the
+    chip belongs to the one process per device that computes."""
+    import subprocess
+    import sys
+    code = ("import sys; import repro.client, repro.service, repro.cluster, "
+            "repro.data; "
+            "print(sorted(m for m in sys.modules if m.split('.')[0] == 'jax'))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, check=True,
+                         env={**os.environ, "PYTHONPATH": "src"})
+    assert out.stdout.strip() == "[]", out.stdout

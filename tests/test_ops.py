@@ -117,6 +117,24 @@ def test_k_equals_one_all_backends():
         np.testing.assert_allclose(got, want, rtol=2e-3)
 
 
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_loss_parity_on_recompressed_coreset(backend):
+    """After a merge-reduce recompression a block's mass differs from its
+    area; a leaf covering the block must still consume all of the mass,
+    as the oracle's exact moment phase does."""
+    from repro.core import compose, recompress
+    y = piecewise_signal(64, 44, 5, noise=0.15, seed=23) + 50.0
+    parts = [signal_coreset(y[a:b], 5, 0.3) for a, b in ((0, 32), (32, 64))]
+    cs = recompress(compose(parts, [0, 32], n_total=64))
+    area = ((cs.rects[:, 1] - cs.rects[:, 0])
+            * (cs.rects[:, 3] - cs.rects[:, 2]))
+    assert np.any(np.abs(cs.weights.sum(axis=1) - area) > 1e-6 * area)
+    sr, sl = _candidates(64, 44, 5, 4, seed=26)
+    want = np.array([fitting_loss(cs, r, l) for r, l in zip(sr, sl)])
+    got = ops.fitting_loss_batched(cs, sr, sl, backend=backend)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+
+
 def test_zero_weight_padded_blocks_contribute_nothing():
     # the fitting_loss_batched pad path: explicit zero-weight blocks must
     # not change the loss (same invariant the kernel's internal B-padding
@@ -314,7 +332,7 @@ def test_mesh_sharded_batched_loss_matches_oracle():
     script = textwrap.dedent("""
         import numpy as np, jax
         assert jax.device_count() >= 8, jax.devices()
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core import (fitting_loss, fitting_loss_batched,
                                 random_tree_segmentation, signal_coreset)
         from repro.core.sharded import MESH_BACKEND
@@ -328,7 +346,7 @@ def test_mesh_sharded_batched_loss_matches_oracle():
         sl = np.stack([s.labels for s in segs])
         samples = []
         profile.add_hook(lambda op, b, size, dt: samples.append((op, b)))
-        mesh = compat_make_mesh((8,), ("data",), jax.devices())
+        mesh = make_mesh((8,), ("data",), jax.devices())
         got = fitting_loss_batched(cs, sr, sl, mesh=mesh)
         want = np.array([fitting_loss(cs, s.rects, s.labels) for s in segs])
         assert np.allclose(got, want, rtol=2e-3, atol=1e-3), (got, want)

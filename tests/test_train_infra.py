@@ -126,8 +126,9 @@ def test_sharding_specs_on_abstract_production_mesh():
     """Spec logic against AbstractMesh(16, 16): model dims sharded when
     divisible, norms replicated, ZeRO-1 adds a data axis."""
     from jax.sharding import PartitionSpec as P
-    from repro.sharding import compat_abstract_mesh, opt_specs, param_specs
-    mesh = compat_abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    from repro.sharding import opt_specs, param_specs
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = ARCHS["yi-9b"]
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     specs = param_specs(params, mesh)
