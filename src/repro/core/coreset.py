@@ -23,6 +23,8 @@ import time
 
 import numpy as np
 
+from repro import obs
+
 from .balanced import BalancedPartition, balanced_partition
 from .bicriteria import BicriteriaResult, bicriteria
 from .caratheodory import block_representatives
@@ -202,16 +204,19 @@ def signal_coreset(values: np.ndarray, k: int, eps: float, *,
     if _sigma_hint is not None:       # size-bisection path: sigma known
         sigma, certified, bic = _sigma_hint
     else:
-        bic = bicriteria(y, k, nu=nu, gamma_1d=gamma_1d, fidelity=fidelity)
+        with obs.cpu_span("core.bicriteria"):
+            bic = bicriteria(y, k, nu=nu, gamma_1d=gamma_1d,
+                             fidelity=fidelity)
         sigma = bic.sigma
         certified = True
         if sigma_mode == "auto" and fidelity != "paper":
             from .segmentation import greedy_tree
             from .fitting_loss import true_loss
-            g = greedy_tree(ps_full, k)
-            # /6 calibrated on the worst family (smooth fields): max rel err
-            # stays ~eps/2 at eps=0.1 (see EXPERIMENTS.md §Guarantee)
-            heur = true_loss(y, g.rects, g.labels, ps=ps_full) / 6.0
+            with obs.cpu_span("core.sigma_greedy"):
+                g = greedy_tree(ps_full, k)
+                # /6 calibrated on the worst family (smooth fields): max
+                # rel err stays ~eps/2 at eps=0.1 (EXPERIMENTS.md §Guarantee)
+                heur = true_loss(y, g.rects, g.labels, ps=ps_full) / 6.0
             if heur > sigma:
                 sigma, certified = heur, False
 
@@ -221,11 +226,12 @@ def signal_coreset(values: np.ndarray, k: int, eps: float, *,
     if max_slices_override is not None:
         max_slices = int(max_slices_override)
 
-    part: BalancedPartition = balanced_partition(ps_full, tol, max_slices)
-
-    block_id = part.block_id_raster(n, m)
-    labels, weights, moments = block_representatives(
-        y.ravel(), block_id.ravel(), part.num_blocks)
+    with obs.cpu_span("core.partition"):
+        part: BalancedPartition = balanced_partition(ps_full, tol, max_slices)
+        block_id = part.block_id_raster(n, m)
+    with obs.cpu_span("core.caratheodory"):
+        labels, weights, moments = block_representatives(
+            y.ravel(), block_id.ravel(), part.num_blocks)
 
     return SignalCoreset(
         n=n, m=m, k=k, eps=eps,

@@ -20,6 +20,8 @@ import concurrent.futures as _fut
 
 import numpy as np
 
+from repro import obs
+
 from .coreset import SignalCoreset, signal_coreset
 from .streaming import compose, recompress
 
@@ -77,11 +79,23 @@ def sharded_coreset(values: np.ndarray, k: int, eps: float, num_bands: int,
     y = np.asarray(values, np.float64)
     n = y.shape[0]
     if share_tolerance and "tolerance_override" not in kw:
-        kw = dict(kw, tolerance_override=shared_tolerance(y, k, eps, _stats))
+        with obs.cpu_span("core.shared_tolerance"):
+            tol = shared_tolerance(y, k, eps, _stats)
+        kw = dict(kw, tolerance_override=tol)
     bands = band_bounds(n, num_bands)
+    # pool threads do not inherit context: each band re-enters the
+    # caller's span, so its phases and dispatch join the build's trace
+    parent = obs.current_span()
+
+    def build(i: int, r0: int, r1: int) -> SignalCoreset:
+        with obs.attach(parent), \
+                obs.cpu_span("core.band", band=i, row0=r0, row1=r1):
+            return signal_coreset(y[r0:r1], k, eps, **kw)
+
     with _fut.ThreadPoolExecutor(max_workers=max_workers or len(bands)) as ex:
-        parts = list(ex.map(lambda b: signal_coreset(y[b[0]:b[1]], k, eps, **kw), bands))
-    cs = compose(parts, [b[0] for b in bands], n_total=n)
+        parts = list(ex.map(build, range(len(bands)), *zip(*bands)))
+    with obs.cpu_span("core.compose"):
+        cs = compose(parts, [b[0] for b in bands], n_total=n)
     return recompress(cs) if recompress_result else cs
 
 

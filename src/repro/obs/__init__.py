@@ -6,15 +6,20 @@ serving hot path records spans into:
     HTTP handler  ->  QueryScheduler wait / fused dispatch (linked)
                   ->  BuildScheduler build
                   ->  engine cache lookup / compress
+                  ->  core build phases (``core.*``, band threads included)
                   ->  repro.ops dispatch (op, backend, shape bucket)
+                  ->  its upload / device / read-back split (``dispatch.*``)
 
-plus the :mod:`repro.obs.profile` hook point the dispatcher feeds, so the
-engine can turn per-dispatch wall time into Prometheus families.  See
-DESIGN.md "Observability" for the span taxonomy and linking semantics.
+plus the :mod:`repro.obs.profile` hook points: the dispatcher feeds per-
+dispatch wall time, and JAX's backend-compile events count ``compiles`` on
+the compiling thread's span, so the engine can turn both into Prometheus
+families.  See DESIGN.md "Observability" for the span taxonomy and linking
+semantics.
 
 The module-level helpers below delegate to ``TRACER`` — call sites read as
 ``obs.span("cache.lookup")`` without threading a tracer through every
-constructor.  Tests that need isolation build their own ``Tracer``.
+constructor; beneath a span of a private tracer the child records into
+that tracer.  Tests that need isolation build their own ``Tracer``.
 """
 from __future__ import annotations
 
@@ -27,13 +32,19 @@ __all__ = [
     "NOOP", "TRACER", "Span", "SpanContext", "Tracer", "profile",
     "current_span", "parse_traceparent", "format_traceparent",
     "mint_trace_id", "mint_span_id",
-    "span", "child_span", "start_trace", "attach", "set_enabled",
+    "span", "cpu_span", "child_span", "start_trace", "attach", "set_enabled",
 ]
 
 
 def span(name: str, **attrs):
     """Context manager: child span of the current one (NOOP outside)."""
     return TRACER.span(name, **attrs)
+
+
+def cpu_span(name: str, **attrs):
+    """:func:`span` that also records ``cpu_s``, this thread's CPU seconds
+    over the span."""
+    return TRACER.cpu_span(name, **attrs)
 
 
 def child_span(name: str, *, parent=None, attrs: dict | None = None):

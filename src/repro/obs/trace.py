@@ -26,8 +26,13 @@ worker with :func:`Tracer.attach` — thread pools do not inherit context.
 Overhead discipline: when no trace is active (pure-library callers, or
 tracing disabled) every entry point returns the singleton :data:`NOOP`
 span, whose methods do nothing — the hot ``ops.dispatch`` path pays one
-contextvar read, nothing else.  The <5% serving-overhead budget is gated in
-CI (``scripts/check_bench_regression.py``, ``tracing`` row).
+contextvar read, nothing else.  What tracing costs when it is on is measured
+on the chip, end to end, in PERF.md (tracing-cost finding: ``ready_p50_s``
+with the tracer on and off).
+
+A child span records into the tracer that holds its parent's trace, so the
+module-level helpers (which go through ``TRACER``) also work beneath a
+span of a private tracer, such as an in-process cluster worker's.
 """
 from __future__ import annotations
 
@@ -236,6 +241,26 @@ class _SpanCM:
         return False
 
 
+class _CpuSpanCM(_SpanCM):
+    """``with tracer.cpu_span(...)``: a span that also records ``cpu_s``,
+    this thread's CPU time over it (``time.thread_time``); wall seconds
+    minus ``cpu_s`` is the time the thread was runnable but not running.
+    The clock is read only when the span is live."""
+
+    __slots__ = ("_cpu0",)
+
+    def __enter__(self) -> Span:
+        sp = _SpanCM.__enter__(self)
+        if sp:
+            self._cpu0 = time.thread_time()
+        return sp
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._token is not None:
+            self._span.set_attr("cpu_s", time.thread_time() - self._cpu0)
+        return _SpanCM.__exit__(self, exc_type, exc, tb)
+
+
 class _AttachCM:
     """``with tracer.attach(span)``: make a captured span current on this
     thread for the duration.  No-op for None/NOOP spans."""
@@ -338,7 +363,12 @@ class Tracer:
             parent = _CURRENT.get()
         if parent is None or not parent:
             return NOOP
-        return Span(self, name, parent.trace_id, mint_span_id(),
+        # record where the parent's trace is active: a Span's own tracer
+        # (a SpanContext carries none, so it stays with this one)
+        owner = getattr(parent, "_tracer", self)
+        if owner is not self and not owner._enabled:
+            return NOOP
+        return Span(owner, name, parent.trace_id, mint_span_id(),
                     parent.span_id, attrs)
 
     def span(self, name: str, **attrs) -> "_SpanCM":
@@ -347,6 +377,11 @@ class Tracer:
         rather than @contextmanager: the generator machinery costs ~1us per
         use, which matters at several spans per request."""
         return _SpanCM(self, name, attrs or None)
+
+    def cpu_span(self, name: str, **attrs) -> "_CpuSpanCM":
+        """:meth:`span` that also records ``cpu_s``, the calling thread's
+        CPU seconds over the span (see :class:`_CpuSpanCM`)."""
+        return _CpuSpanCM(self, name, attrs or None)
 
     def attach(self, span: Span | None) -> "_AttachCM":
         """Make ``span`` current on THIS thread (cross-thread re-entry: a

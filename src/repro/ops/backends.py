@@ -24,11 +24,16 @@ xla backends jit those oracles; nothing here re-derives a formula.  The
 and recombine the (hi, lo) pairs in f64 on the host: accelerator-resident
 f32 arithmetic whose result matches the f64 oracle to ~1e-10 scaled
 relative error — the path that lets the autotuner lift a precision pin
-(see ``autotune.py``).
+(see ``autotune.py``).  ``_compensated`` runs them, and under a live
+dispatch span times the upload, the device call and the read-back apart.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
+
+from repro import obs
 
 from . import autotune
 from .registry import register
@@ -38,6 +43,33 @@ def _recombine(pair) -> np.ndarray:
     """f64 host value of a compensated (hi, lo) f32 device pair."""
     hi, lo = pair
     return np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+
+
+def _compensated(call, *host) -> np.ndarray:
+    """``call`` on the (hi, lo) f32 pairs of the f64 host arrays ``host``,
+    its output pair recombined in f64 on the host.
+
+    Inside a live ``ops.dispatch`` span the dispatch gets three children,
+    each ended at a sync: ``dispatch.h2d`` (split and upload, ``bytes``
+    up), ``dispatch.device`` (the jitted call) and ``dispatch.d2h``
+    (read-back and f64 sum, ``bytes`` down).  Outside one it runs as an
+    untraced call always has: no sync, the same output."""
+    from repro.kernels.sat2d.ref import split_hi_lo
+    if not obs.current_span():
+        return _recombine(call(*(a for x in host for a in split_hi_lo(x))))
+    import jax
+    with obs.span("dispatch.h2d") as sp:
+        pairs = jax.block_until_ready(
+            [a for x in host for a in split_hi_lo(x)])
+        sp.set_attr("bytes", sum(int(a.nbytes) for a in pairs))
+    with obs.span("dispatch.device"):
+        out = jax.block_until_ready(call(*pairs))
+    # free the inputs before the read-back, as an untraced call does, so
+    # concurrent band dispatches hold no more device memory than untraced
+    del pairs
+    with obs.span("dispatch.d2h") as sp:
+        sp.set_attr("bytes", sum(int(a.nbytes) for a in out))
+        return _recombine(out)
 
 
 # ------------------------------------------------------------- sat_moments
@@ -61,8 +93,7 @@ def _sat_moments_numpy():
 def _sat_moments_xla():
     import jax
     import jax.numpy as jnp
-    from repro.kernels.sat2d.ref import (sat_moments_comp_ref,
-                                         sat_moments_ref, split_hi_lo)
+    from repro.kernels.sat2d.ref import sat_moments_comp_ref, sat_moments_ref
     f = jax.jit(sat_moments_ref)
     f_comp = jax.jit(sat_moments_comp_ref)
 
@@ -70,7 +101,7 @@ def _sat_moments_xla():
         cfg = config if config is not None else autotune.plan(
             "sat_moments", "xla", 3 * np.asarray(y).size)
         if cfg.get("compensated", True):
-            return _recombine(f_comp(*split_hi_lo(y)))
+            return _compensated(f_comp, y)
         return np.asarray(f(jnp.asarray(y, jnp.float32)))
     return sat_moments
 
@@ -80,15 +111,14 @@ def _sat_moments_pallas():
     import jax.numpy as jnp
     from repro.kernels.sat2d.ops import sat_moments as kernel_sat_moments
     from repro.kernels.sat2d.ops import sat_moments_comp
-    from repro.kernels.sat2d.ref import split_hi_lo
 
     def sat_moments(y, interpret=None, config=None):
         cfg = config if config is not None else autotune.plan(
             "sat_moments", "pallas", 3 * np.asarray(y).size)
         tile = int(cfg.get("tile", 256))
         if cfg.get("compensated", True):
-            return _recombine(sat_moments_comp(*split_hi_lo(y), tile=tile,
-                                               interpret=interpret))
+            return _compensated(functools.partial(
+                sat_moments_comp, tile=tile, interpret=interpret), y)
         # donate: the device copy made here is never reused on the host
         return np.asarray(kernel_sat_moments(
             jnp.asarray(y, jnp.float32), tile=tile,
@@ -122,8 +152,7 @@ def _delta_sat_numpy():
 def _delta_sat_xla():
     import jax
     import jax.numpy as jnp
-    from repro.kernels.sat2d.ref import (delta_sat_comp_ref, delta_sat_ref,
-                                         split_hi_lo)
+    from repro.kernels.sat2d.ref import delta_sat_comp_ref, delta_sat_ref
     f = jax.jit(delta_sat_ref)
     f_comp = jax.jit(delta_sat_comp_ref)
 
@@ -133,7 +162,7 @@ def _delta_sat_xla():
         if cfg.get("compensated", True):
             # the stored carry enters as its own (hi, lo) pair, so chained
             # patches keep full two-float precision across calls
-            return _recombine(f_comp(*split_hi_lo(carry), *split_hi_lo(tail)))
+            return _compensated(f_comp, carry, tail)
         return np.asarray(f(jnp.asarray(carry, jnp.float32),
                             jnp.asarray(tail, jnp.float32)))
     return delta_sat
@@ -144,16 +173,15 @@ def _delta_sat_pallas():
     import jax.numpy as jnp
     from repro.kernels.sat2d.ops import (delta_sat_moments,
                                         delta_sat_moments_comp)
-    from repro.kernels.sat2d.ref import split_hi_lo
 
     def delta_sat(carry, tail, interpret=None, config=None):
         cfg = config if config is not None else autotune.plan(
             "delta_sat", "pallas", 3 * np.asarray(tail).size)
         tile = int(cfg.get("tile", 256))
         if cfg.get("compensated", True):
-            return _recombine(delta_sat_moments_comp(
-                *split_hi_lo(carry), *split_hi_lo(tail), tile=tile,
-                interpret=interpret))
+            return _compensated(functools.partial(
+                delta_sat_moments_comp, tile=tile, interpret=interpret),
+                carry, tail)
         return np.asarray(delta_sat_moments(
             jnp.asarray(carry, jnp.float32), jnp.asarray(tail, jnp.float32),
             tile=tile, interpret=interpret, donate=True))
@@ -273,8 +301,6 @@ def _hist_split_numpy():
 
 @register("hist_split", "xla")
 def _hist_split_xla():
-    import functools
-
     import jax
     import jax.numpy as jnp
     from repro.kernels.sat2d.ref import split_hi_lo
@@ -413,8 +439,7 @@ def _streaming_compress_numpy():
 def _streaming_compress_xla():
     import jax
     import jax.numpy as jnp
-    from repro.kernels.sat2d.ref import (sat_stack_comp_ref, sat_stack_ref,
-                                         split_hi_lo)
+    from repro.kernels.sat2d.ref import sat_stack_comp_ref, sat_stack_ref
     f = jax.jit(sat_stack_ref)
     f_comp = jax.jit(sat_stack_comp_ref)
 
@@ -424,8 +449,7 @@ def _streaming_compress_xla():
             "streaming_compress", "xla", _compress_size(coresets))
         preps = [_recompress_prep(cs) for cs in coresets]
         if cfg.get("compensated", True):
-            sats = _recombine(f_comp(*split_hi_lo(
-                _stack_rasters(preps, np.float64))))
+            sats = _compensated(f_comp, _stack_rasters(preps, np.float64))
         else:
             sats = np.asarray(f(jnp.asarray(_stack_rasters(preps))))
         return _finish_from_sats(coresets, preps, sats, k, eps)
@@ -436,7 +460,6 @@ def _streaming_compress_xla():
 def _streaming_compress_pallas():
     import jax.numpy as jnp
     from repro.kernels.sat2d.ops import sat_stack, sat_stack_comp
-    from repro.kernels.sat2d.ref import split_hi_lo
 
     def sc(coresets, k=None, eps=None, interpret=None, config=None):
         from repro.core.streaming import _recompress_prep
@@ -445,9 +468,9 @@ def _streaming_compress_pallas():
         tile = int(cfg.get("tile", 256))
         preps = [_recompress_prep(cs) for cs in coresets]
         if cfg.get("compensated", True):
-            sats = _recombine(sat_stack_comp(
-                *split_hi_lo(_stack_rasters(preps, np.float64)), tile=tile,
-                interpret=interpret))
+            sats = _compensated(functools.partial(
+                sat_stack_comp, tile=tile, interpret=interpret),
+                _stack_rasters(preps, np.float64))
         else:
             sats = np.asarray(sat_stack(jnp.asarray(_stack_rasters(preps)),
                                         tile=tile, interpret=interpret,

@@ -209,6 +209,10 @@ def resolve(op: str, backend: str | None = None,
                         f"no {name!r} backend registered for op {op!r}; "
                         f"available: {available_backends(op)}")
                 fn = _RESOLVED[key] = factory()
+                if name != "numpy":
+                    # the accelerator factories have loaded JAX: count its
+                    # compiles from here on
+                    _profile.install_compile_listener()
     return name, fn
 
 
@@ -220,9 +224,14 @@ def dispatch(op: str, *args, backend: str | None = None,
     # sample.  Outside a trace the span is the NOOP singleton and with no
     # hooks installed the profile branch is one falsy check — the pure-
     # library hot paths (per-node hist_split) pay two perf_counter reads.
+    # A live span is made current, so the backend can split it
+    # (``dispatch.*``) and a compile lands on it.
     span = _trace.TRACER.child_span("ops.dispatch")
     t0 = time.perf_counter()
     try:
+        if span:
+            with _trace.TRACER.attach(span):
+                return fn(*args, **kw)
         return fn(*args, **kw)
     finally:
         dt = time.perf_counter() - t0

@@ -538,7 +538,9 @@ class _Handler(BaseHTTPRequestHandler):
                     self._get_trace(path, query)
                 elif method == "POST" and v1_path in _V1_POST:
                     msg_cls, handler = _V1_POST[v1_path]
-                    raw = self._body()
+                    with obs.span("http.read_body") as sp:
+                        raw = self._body()
+                        sp.set_attr("bytes", len(raw))
                     if successor is not None:
                         # legacy flat-dict schema; JSON only, like the old API
                         msg = _legacy_to_msg(path, json.loads(raw or b"{}"))
@@ -552,7 +554,8 @@ class _Handler(BaseHTTPRequestHandler):
                                 ("", P.CONTENT_TYPE_JSON, P.CONTENT_TYPE_BINARY)):
                             raise ApiError(415, "unsupported_media",
                                            f"unsupported Content-Type {ctype!r}")
-                        msg = P.decode(ctype, raw, expect=msg_cls)
+                        with obs.span("http.decode"):
+                            msg = P.decode(ctype, raw, expect=msg_cls)
                         with self._admitted(eng, msg):
                             resp = handler(eng, msg)
                         if (v1_path == "/v1/query/compress"

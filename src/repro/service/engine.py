@@ -314,6 +314,15 @@ class CoresetEngine:
         self._profile_hook = _on_dispatch
         obs.profile.add_hook(self._profile_hook)
 
+        # backend compiles in this process (the listener is installed when
+        # the first accelerator backend resolves; the span it lands on says
+        # which step compiled)
+        def _on_compile(seconds: float, _m=self.metrics) -> None:
+            _m.inc("jit_compiles_total")
+
+        self._compile_hook = _on_compile
+        obs.profile.add_compile_hook(self._compile_hook)
+
     # ---------------------------------------------------------------- ingest
     def register_signal(self, name: str, values: np.ndarray, *,
                         replace: bool = False) -> dict:
@@ -1132,3 +1141,4 @@ class CoresetEngine:
         self.queries.shutdown()
         self.scheduler.shutdown()
         obs.profile.remove_hook(self._profile_hook)
+        obs.profile.remove_compile_hook(self._compile_hook)

@@ -222,6 +222,28 @@ def test_trace_id_spans_coordinator_and_worker_hops(cluster):
         assert linked_ids & worker_span_ids
 
 
+def test_worker_trace_holds_its_band_build_phases(cluster):
+    # in-process workers record into private tracers: the build's phase
+    # spans (opened through the module-level helpers) must land there too
+    coord = cluster.coord
+    coord.register_signal("sig", _y(17))
+    dropped = obs.TRACER.stats()["spans_dropped"]
+    root = obs.start_trace("test.build")
+    with obs.TRACER.attach(root):
+        coord.get_coreset("sig", K, EPS)
+    root.end()
+    for n in cluster.nodes:
+        wt = n.tracer.get(root.trace_id, wait_s=2.0)
+        assert wt is not None
+        [build] = [s for s in wt["spans"] if s["name"] == "worker.band_build"]
+        phases = {s["name"] for s in wt["spans"]
+                  if s["parent_id"] == build["span_id"]}
+        assert {"core.bicriteria", "core.partition",
+                "core.caratheodory"} <= phases
+        assert n.tracer.stats()["spans_dropped"] == 0
+    assert obs.TRACER.stats()["spans_dropped"] == dropped
+
+
 def test_worker_error_envelope_carries_trace_headers(cluster):
     client = WorkerClient(cluster.nodes[0].url)
     root = obs.start_trace("test.err")

@@ -6,7 +6,9 @@ the same process.
 """
 import json
 import threading
+import time
 
+from repro import obs
 from repro.obs import (NOOP, Span, SpanContext, Tracer, format_traceparent,
                        mint_span_id, mint_trace_id, parse_traceparent)
 from repro.obs import profile
@@ -261,3 +263,150 @@ def test_span_reprs_do_not_crash():
     ctx = sp.context
     assert ctx.to_dict() == {"trace_id": sp.trace_id, "span_id": sp.span_id}
     sp.end()
+
+
+# ------------------------------------------------- private tracers, cpu_s
+def test_module_helpers_record_into_the_current_spans_tracer():
+    # beneath a private tracer's span, obs.span / obs.child_span must land
+    # in that tracer, not be dropped by the global one
+    tr = Tracer(capacity=4)
+    dropped = obs.TRACER.stats()["spans_dropped"]
+    root = tr.start_trace("req")
+    with tr.attach(root):
+        with obs.span("helper.span") as sp:
+            assert sp and sp.trace_id == root.trace_id
+            obs.child_span("helper.child").end()
+        with obs.cpu_span("helper.cpu"):
+            pass
+    root.end()
+    names = {s["name"] for s in tr.get(root.trace_id)["spans"]}
+    assert names == {"req", "helper.span", "helper.child", "helper.cpu"}
+    assert tr.stats()["spans_dropped"] == 0
+    assert obs.TRACER.stats()["spans_dropped"] == dropped
+
+
+def test_cpu_span_records_thread_time_only_when_live():
+    tr = Tracer(capacity=4)
+    with tr.cpu_span("orphan") as sp:
+        assert sp is NOOP
+    root = tr.start_trace("req")
+    with tr.attach(root):
+        with tr.cpu_span("busy", k=1):
+            t_end = time.perf_counter() + 0.02
+            while time.perf_counter() < t_end:
+                pass
+        with tr.cpu_span("asleep"):
+            time.sleep(0.05)
+    root.end()
+    by = {s["name"]: s for s in tr.get(root.trace_id)["spans"]}
+    assert by["busy"]["attrs"]["k"] == 1
+    assert 0.01 < by["busy"]["attrs"]["cpu_s"] <= \
+        by["busy"]["duration_us"] / 1e6 + 1e-3
+    assert by["asleep"]["attrs"]["cpu_s"] < 0.02
+    assert by["asleep"]["duration_us"] >= 5e4
+
+
+def _union_us(spans) -> float:
+    iv = sorted((s["start_us"], s["start_us"] + s["duration_us"])
+                for s in spans)
+    total, cur0, cur1 = 0.0, None, None
+    for a, b in iv:
+        if cur1 is None or a > cur1:
+            if cur1 is not None:
+                total += cur1 - cur0
+            cur0, cur1 = a, b
+        else:
+            cur1 = max(cur1, b)
+    return total + (cur1 - cur0 if cur1 is not None else 0.0)
+
+
+def test_sharded_build_phases_join_the_callers_trace():
+    """Every band thread re-enters the caller's span: one ``core.band`` per
+    band, each holding its phases and its integral-image dispatch, all in
+    the caller's trace, none dropped, and the children cover the build."""
+    from repro.core.sharded import band_bounds, sharded_coreset
+    from repro.data import piecewise_signal
+    # large enough that the pool's start-up stays a small share
+    y = piecewise_signal(512, 320, 6, noise=0.15, seed=4) + 100.0
+    tr = Tracer(capacity=4, max_spans_per_trace=1024)
+    root = tr.start_trace("engine.compress")
+    with tr.attach(root):
+        sharded_coreset(y, 6, 0.3, num_bands=4)
+    root.end()
+    spans = tr.get(root.trace_id)["spans"]
+    assert tr.stats()["spans_dropped"] == 0
+    assert all(s["trace_id"] == root.trace_id for s in spans)
+    kids: dict = {}
+    for s in spans:
+        kids.setdefault(s["parent_id"], []).append(s)
+    bands = [s for s in spans if s["name"] == "core.band"]
+    assert sorted((b["attrs"]["band"], b["attrs"]["row0"], b["attrs"]["row1"])
+                  for b in bands) == \
+        [(i, r0, r1) for i, (r0, r1) in enumerate(band_bounds(512, 4))]
+    for b in bands:
+        assert b["parent_id"] == root.span_id
+        names = {c["name"] for c in kids[b["span_id"]]}
+        assert {"core.bicriteria", "core.partition", "core.caratheodory",
+                "ops.dispatch"} <= names
+    top = {s["name"] for s in kids[root.span_id]}
+    assert top == {"core.shared_tolerance", "core.band", "core.compose"}
+    for s in spans:
+        if s["name"].startswith("core."):
+            assert s["attrs"]["cpu_s"] <= s["duration_us"] / 1e6 + 1e-3
+    assert _union_us(kids[root.span_id]) >= \
+        0.95 * tr.get(root.trace_id)["duration_us"]
+
+
+# ------------------------------------------------------------------ compiles
+def test_compile_lands_on_the_compiling_threads_span():
+    import jax
+    import jax.numpy as jnp
+    profile.install_compile_listener()
+    profile.install_compile_listener()       # idempotent
+    seen = []
+    hook = profile.add_compile_hook(seen.append)
+    tr = Tracer(capacity=4)
+    root = tr.start_trace("req")
+    try:
+        with tr.attach(root), tr.span("step") as sp:
+            jax.jit(lambda x: x * 3.0 + 1.5)(jnp.ones(7)).block_until_ready()
+    finally:
+        profile.remove_compile_hook(hook)
+        root.end()
+    assert sp.attrs["compiles"] >= 1
+    assert seen and all(s >= 0 for s in seen)
+    assert "compiles" not in (root.attrs or {})
+
+
+# ------------------------------------------------------ the profiler's clock
+def test_spans_share_the_profilers_clock(tmp_path):
+    """A span and a profiler annotation of the same step start within 1 ms
+    of each other, so a ``?format=chrome`` export lays over a device
+    trace without a shift."""
+    import jax
+    from jax.profiler import ProfileData
+    tr = Tracer(capacity=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        time.sleep(0.05)
+        root = tr.start_trace("req")
+        with tr.attach(root), tr.span("clock.probe"):
+            with jax.profiler.TraceAnnotation("clock.probe"):
+                time.sleep(0.01)
+        root.end()
+    finally:
+        jax.profiler.stop_trace()
+    span = next(s for s in tr.get(root.trace_id)["spans"]
+                if s["name"] == "clock.probe")
+    pd = ProfileData.from_file(str(next(tmp_path.glob("**/*.xplane.pb"))))
+    start = {}
+    for pl in pd.planes:
+        if pl.name == "Task Environment":
+            start.update(dict(pl.stats))
+        for ln in pl.lines:
+            for ev in ln.events:
+                if ev.name == "clock.probe":
+                    start["event_ns"] = ev.start_ns
+    # xplane events are stamped from the session's start, on the wall clock
+    event_us = (start["profile_start_time"] + start["event_ns"]) / 1e3
+    assert abs(event_us - span["start_us"]) < 1e3

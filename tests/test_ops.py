@@ -196,6 +196,47 @@ def test_delta_sat_one_row_band_from_row_zero(backend):
         np.asarray(want), ops.sat_moments(tail, backend="numpy"))
 
 
+@pytest.mark.parametrize("op", ["sat_moments", "delta_sat"])
+def test_compensated_dispatch_splits_transfer_and_device(op):
+    """Under a live span a compensated xla dispatch times its upload,
+    device call and read-back apart, with the bytes each moves; outside a
+    trace it returns what an unsplit call returns, bit for bit."""
+    import jax
+    from repro.kernels.sat2d.ref import (delta_sat_comp_ref,
+                                         sat_moments_comp_ref, split_hi_lo)
+    from repro.obs import Tracer
+    rng = np.random.default_rng(31)
+    n, m = 37, 29
+    y = rng.normal(size=(n, m)) + 100.0
+    if op == "sat_moments":
+        args = (y,)
+        want = ops.backends._recombine(
+            jax.jit(sat_moments_comp_ref)(*split_hi_lo(y)))
+        up, down = 8 * n * m, 24 * n * m
+    else:
+        carry = ops.sat_moments(y, backend="numpy")[:, 9, :]
+        args = (carry, y[10:])
+        want = ops.backends._recombine(jax.jit(delta_sat_comp_ref)(
+            *split_hi_lo(carry), *split_hi_lo(y[10:])))
+        up, down = 8 * (3 * m + (n - 10) * m), 24 * (n - 10) * m
+    fn = getattr(ops, op)
+    np.testing.assert_array_equal(fn(*args, backend="xla"), want)
+    tr = Tracer(capacity=4)
+    root = tr.start_trace("req")
+    with tr.attach(root):
+        got = fn(*args, backend="xla")
+    root.end()
+    np.testing.assert_array_equal(got, want)
+    spans = tr.get(root.trace_id)["spans"]
+    [disp] = [s for s in spans if s["name"] == "ops.dispatch"]
+    kids = {s["name"]: s for s in spans if s["parent_id"] == disp["span_id"]}
+    assert set(kids) == {"dispatch.h2d", "dispatch.device", "dispatch.d2h"}
+    assert kids["dispatch.h2d"]["attrs"]["bytes"] == up
+    assert kids["dispatch.d2h"]["attrs"]["bytes"] == down
+    assert sum(k["duration_us"] for k in kids.values()) <= \
+        disp["duration_us"]
+
+
 def test_delta_sat_validates_shapes():
     with pytest.raises(ValueError):
         ops.delta_sat(np.zeros((3, 4)), np.zeros((2, 5)))   # carry mismatch
@@ -317,6 +358,23 @@ def test_engine_stats_surface_ops_backends():
         assert set(snap) == set(ops.OPS)
     finally:
         eng.close()
+
+
+def test_engine_counts_backend_compiles():
+    from repro.service import CoresetEngine, ServiceMetrics
+    eng = CoresetEngine(workers=1, metrics=ServiceMetrics())
+    try:
+        # the first accelerator resolve installs the compile listener
+        ops.sat_moments(np.ones((19, 7)), backend="xla")
+        before = eng.metrics.get("jit_compiles_total")
+        ops.sat_moments(np.ones((23, 5)), backend="xla")   # a new shape
+        assert eng.metrics.get("jit_compiles_total") > before
+        assert "coreset_jit_compiles_total" in eng.metrics.render()
+    finally:
+        eng.close()
+    closed = eng.metrics.get("jit_compiles_total")
+    ops.sat_moments(np.ones((29, 3)), backend="xla")
+    assert eng.metrics.get("jit_compiles_total") == closed
 
 
 # ----------------------------------------------------------- real mesh path

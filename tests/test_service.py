@@ -694,6 +694,22 @@ def test_http_trace_retrieval_and_chrome_export():
         eng.close()
 
 
+def test_register_trace_times_body_read_and_decode():
+    eng, srv, base = _server()
+    try:
+        cl = CoresetClient(base)
+        cl.register_signal("s", values=_signal(17))
+        by = {s["name"]: s for s in cl.trace(cl.last_trace_id)["spans"]}
+        root = by["POST /v1/signals"]
+        for name in ("http.read_body", "http.decode"):
+            assert by[name]["parent_id"] == root["span_id"]
+            assert by[name]["duration_us"] <= root["duration_us"]
+        assert by["http.read_body"]["attrs"]["bytes"] >= N * M
+    finally:
+        srv.shutdown()
+        eng.close()
+
+
 def test_client_surfaces_trace_id_on_api_errors():
     eng, srv, base = _server()
     try:
