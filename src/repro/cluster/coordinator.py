@@ -12,8 +12,10 @@ Parity is the design invariant: the composed coreset must be **bitwise
 fingerprint-equal** to the single-host ``sharded_coreset`` thread-pool
 path.  Three shared pieces guarantee it:
 
-  * ``shared_tolerance`` — the coordinator computes the global per-block
-    cap from its own full-signal stats, identical float op order;
+  * ``shared_sigma`` / ``shared_tolerance`` — the coordinator computes the
+    global sigma and per-block cap from its own full-signal stats,
+    identical float op order, and ``certify_shared`` composes the gathered
+    bands and decides ``certified`` by the same rule;
   * ``band_bounds``       — the same linspace band layout; worker i owns
     band i (round-robin when bands > peers);
   * workers build ``signal_coreset(slab, k, eps, tolerance_override=tol)``
@@ -39,8 +41,8 @@ import numpy as np
 
 from repro import obs
 from repro.core.coreset import SignalCoreset, signal_coreset
-from repro.core.sharded import band_bounds, shared_tolerance
-from repro.core.streaming import compose
+from repro.core.sharded import (band_bounds, certify_shared, shared_sigma,
+                                shared_tolerance)
 from repro.service.admission import current_ticket
 from repro.service.engine import CoresetEngine, SignalState
 
@@ -279,7 +281,8 @@ class ClusterEngine(CoresetEngine):
         # sigma estimate -> shared per-block cap (reusing the delta-patched
         # integral images when a delta write already materialized them)
         ps = st.stats_snapshot(version)
-        tol = shared_tolerance(y, k, eps, _stats=ps)
+        sigma = shared_sigma(y, k, _stats=ps)
+        tol = shared_tolerance(sigma, k, eps)
         t0 = time.perf_counter()
         with obs.span("cluster.gather", signal=st.name, k=int(k),
                       bands=len(layout)) as g:
@@ -295,8 +298,8 @@ class ClusterEngine(CoresetEngine):
         self.metrics.observe("cluster_gather", time.perf_counter() - t0,
                              exemplar=g.trace_id if g else None)
         self.metrics.inc("cluster_gathers")
-        cs = compose([part for part, _ in results],
-                     [b0 for b0, _ in layout], n_total=n)
+        cs = certify_shared([part for part, _ in results],
+                            [b0 for b0, _ in layout], n, sigma)
         return cs, eps, version   # composition of disjoint bands is exact
 
     def _band_part(self, gather_span, name: str, y: np.ndarray, i: int,

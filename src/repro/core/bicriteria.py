@@ -61,20 +61,27 @@ def _keep_smallest(opt1s: np.ndarray, z: int) -> np.ndarray:
 
 
 # --------------------------------------------------------------- bound (b)
-def _rowwise_interval_bound(w0: np.ndarray, w1: np.ndarray, w2: np.ndarray,
-                            k: int) -> float:
+def _rowwise_interval_bound(w0: np.ndarray | None, w1: np.ndarray,
+                            w2: np.ndarray, k: int) -> float:
     """sum_i [ sum of the (t'-2k) smallest opt1 over t'=4k equal column
-    intervals of row i ]  <=  sum_i opt_k(row_i)  <=  opt_k(D)."""
-    n, m = w0.shape
+    intervals of row i ]  <=  sum_i opt_k(row_i)  <=  opt_k(D).
+
+    ``w0=None``: every cell weighs 1 (a dense signal), so an interval's
+    count is its width, bitwise what the prefix sums of ones give."""
+    n, m = w1.shape
     t = max(4 * k, 4)
     if m < 2:
         return 0.0
     bounds = np.unique(np.linspace(0, m, min(t, m) + 1).astype(np.int64))
-    p0 = np.concatenate([np.zeros((n, 1)), np.cumsum(w0, axis=1)], axis=1)
     p1 = np.concatenate([np.zeros((n, 1)), np.cumsum(w1, axis=1)], axis=1)
     p2 = np.concatenate([np.zeros((n, 1)), np.cumsum(w2, axis=1)], axis=1)
     lo, hi = bounds[:-1], bounds[1:]
-    s0 = p0[:, hi] - p0[:, lo]
+    if w0 is None:
+        s0 = (hi - lo).astype(np.float64)
+    else:
+        p0 = np.concatenate([np.zeros((n, 1)), np.cumsum(w0, axis=1)],
+                            axis=1)
+        s0 = p0[:, hi] - p0[:, lo]
     s1 = p1[:, hi] - p1[:, lo]
     s2 = p2[:, hi] - p2[:, lo]
     o = opt1_from_sums(s0, s1, s2)                     # (n, t)
@@ -115,8 +122,10 @@ def bicriteria(values: np.ndarray | None, k: int, *, nu: float = 8.0,
     n_live = float(w0.sum())
     threshold = max(int(k * math.log2(N + 1)), 4)
 
-    sigma_rows = _rowwise_interval_bound(w0, w1, w2, k)
-    sigma_cols = _rowwise_interval_bound(w0.T, w1.T, w2.T, k)
+    c0 = None if moments is None else w0
+    sigma_rows = _rowwise_interval_bound(c0, w1, w2, k)
+    sigma_cols = _rowwise_interval_bound(None if c0 is None else c0.T,
+                                         w1.T, w2.T, k)
 
     iter_losses: list[float] = []
     total_loss = 0.0

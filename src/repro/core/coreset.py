@@ -184,6 +184,12 @@ def signal_coreset(values: np.ndarray, k: int, eps: float, *,
         practical stance of the paper's §5 (empirical eps).  ``certified``
         on the result records whether the heuristic kicked in.
       * "certified": bi-criteria bounds only (used by the guarantee tests).
+
+    With ``tolerance_override`` in the practical fidelity no sigma is
+    estimated: sigma is the O(N) row/column bound alone, and ``certified``
+    says whether the handed tolerance is within that bound's budget.  The
+    band-parallel callers certify the shared sigma once, after composing
+    (``core.sharded.certify_shared``).
     """
     if not (0.0 < eps < 1.0):
         raise ValueError("eps must be in (0,1)")
@@ -203,6 +209,16 @@ def signal_coreset(values: np.ndarray, k: int, eps: float, *,
     ps_full = PrefixStats.build(y) if _stats is None else _stats
     if _sigma_hint is not None:       # size-bisection path: sigma known
         sigma, certified, bic = _sigma_hint
+    elif tolerance_override is not None and fidelity != "paper":
+        # the partition reads only (tolerance, max_slices), and practical
+        # max_slices does not depend on sigma: keep only the O(N)
+        # row/column bounds, enough to judge the handed tolerance
+        with obs.cpu_span("core.bicriteria", bound_only=True):
+            bic = bicriteria(y, k, nu=nu, gamma_1d=gamma_1d,
+                             fidelity=fidelity, max_practical_iters=0)
+        sigma = bic.sigma
+        certified = float(tolerance_override) <= resolve_partition_params(
+            sigma, k, eps, fidelity, bic.alpha_hat)[0]
     else:
         with obs.cpu_span("core.bicriteria"):
             bic = bicriteria(y, k, nu=nu, gamma_1d=gamma_1d,

@@ -17,6 +17,7 @@ load JAX.
 from __future__ import annotations
 
 import concurrent.futures as _fut
+import dataclasses
 
 import numpy as np
 
@@ -25,18 +26,16 @@ from repro import obs
 from .coreset import SignalCoreset, signal_coreset
 from .streaming import compose, recompress
 
-__all__ = ["sharded_coreset", "shared_tolerance", "band_bounds",
-           "fitting_loss_batched"]
+__all__ = ["sharded_coreset", "shared_sigma", "shared_tolerance",
+           "certify_shared", "band_bounds", "fitting_loss_batched"]
 
 
-def shared_tolerance(values: np.ndarray, k: int, eps: float,
-                     _stats=None) -> float:
-    """The global per-block opt1 cap (``tolerance_override``) shared across
-    band builds: one cheap greedy k-tree pass estimates sigma, and the
-    Lemma-14 budget ``eps^2 * sigma / k`` is split over intersected blocks
-    globally.  Extracted so every band-parallel caller — the thread-pool
-    path below and the cluster coordinator's scatter/gather — computes the
-    *identical* float (same op order), which is what keeps their composed
+def shared_sigma(values: np.ndarray, k: int, _stats=None) -> float:
+    """The global sigma estimate shared across band builds: one cheap
+    greedy k-tree pass, ``greedy loss / 4`` (floored at 1e-12).  Every
+    band-parallel caller, the thread-pool path below and the cluster
+    coordinator's scatter/gather, computes it here, so both derive the
+    *identical* tolerance float, which is what keeps their composed
     coresets bitwise fingerprint-equal.
     """
     from .segmentation import greedy_tree
@@ -45,8 +44,30 @@ def shared_tolerance(values: np.ndarray, k: int, eps: float,
     y = np.asarray(values, np.float64)
     ps = _stats if _stats is not None else PrefixStats.build(y)
     g = greedy_tree(ps, k)
-    sigma = max(true_loss(y, g.rects, g.labels, ps=ps) / 4.0, 1e-12)
+    return max(true_loss(y, g.rects, g.labels, ps=ps) / 4.0, 1e-12)
+
+
+def shared_tolerance(sigma: float, k: int, eps: float) -> float:
+    """The global per-block opt1 cap (``tolerance_override``) from the
+    shared sigma: the Lemma-14 budget ``eps^2 * sigma / k``, split over
+    intersected blocks globally."""
     return eps * eps * sigma / max(k, 1)
+
+
+def certify_shared(parts: list[SignalCoreset], row_offsets: list[int],
+                   n_total: int, sigma_shared: float) -> SignalCoreset:
+    """Compose band coresets built under the shared tolerance and decide
+    the guarantee once: ``certified`` iff ``sigma_shared <= sum of the
+    bands' sigma``.  Each band's sigma is a certified lower bound on its
+    own opt_k, and the restriction of any k-segmentation of the signal to
+    a row band is a <= k-segmentation of that band, so
+    ``opt_k(D) >= sum_b opt_k(band_b) >= sum_b sigma_b``."""
+    with obs.cpu_span("core.compose") as sp:
+        cs = compose(parts, row_offsets, n_total=n_total)
+        bound = float(sum(p.sigma for p in parts))
+        sp.set_attr("sigma_shared", float(sigma_shared))
+        sp.set_attr("sigma_bound", bound)
+    return dataclasses.replace(cs, certified=bool(sigma_shared <= bound))
 
 
 def band_bounds(n: int, num_bands: int) -> list[tuple[int, int]]:
@@ -78,9 +99,11 @@ def sharded_coreset(values: np.ndarray, k: int, eps: float, num_bands: int,
     """
     y = np.asarray(values, np.float64)
     n = y.shape[0]
+    sigma = None
     if share_tolerance and "tolerance_override" not in kw:
         with obs.cpu_span("core.shared_tolerance"):
-            tol = shared_tolerance(y, k, eps, _stats)
+            sigma = shared_sigma(y, k, _stats)
+            tol = shared_tolerance(sigma, k, eps)
         kw = dict(kw, tolerance_override=tol)
     bands = band_bounds(n, num_bands)
     # pool threads do not inherit context: each band re-enters the
@@ -94,8 +117,12 @@ def sharded_coreset(values: np.ndarray, k: int, eps: float, num_bands: int,
 
     with _fut.ThreadPoolExecutor(max_workers=max_workers or len(bands)) as ex:
         parts = list(ex.map(build, range(len(bands)), *zip(*bands)))
-    with obs.cpu_span("core.compose"):
-        cs = compose(parts, [b[0] for b in bands], n_total=n)
+    offsets = [b[0] for b in bands]
+    if sigma is not None:
+        cs = certify_shared(parts, offsets, n, sigma)
+    else:
+        with obs.cpu_span("core.compose"):
+            cs = compose(parts, offsets, n_total=n)
     return recompress(cs) if recompress_result else cs
 
 

@@ -19,8 +19,8 @@ import pytest
 from repro import obs
 from repro.cluster import (ClusterEngine, ShardWorker, WorkerClient,
                            WorkerRPCError, make_worker_server)
-from repro.core import random_tree_segmentation
-from repro.data import piecewise_signal
+from repro.core import random_tree_segmentation, sharded_coreset
+from repro.data import piecewise_signal, smooth_field
 from repro.service import CoresetEngine, ServiceMetrics
 
 N, M, K, EPS = 96, 64, 5, 0.3
@@ -81,6 +81,39 @@ def test_cluster_fingerprint_and_loss_parity(cluster):
         ls = cluster.single.tree_loss("sig", q.rects, q.labels, eps=EPS)
         assert abs(lc["loss"] - ls["loss"]) <= 1e-9
         assert lc["fingerprint"] == ls["fingerprint"]
+
+
+# fingerprints of the 3-band build at 512x320, recorded before bands
+# stopped estimating a sigma of their own: the partition reads only the
+# shared (tolerance, max_slices), so dropping that work changes no block
+_PINNED = {
+    "dc_offset_100": "03d1fcea6a2fbe4025152aedbfa4044c",
+    "k16": "587c41f64f28efe6e25fd14b3139acc3",
+    "smooth": "66ba492570e7b9f473bbf4213fc84aad",
+    "noisy": "91c37c74654c37e8d2f3c84fef35119e",
+}
+
+
+def _pinned_signal(name):
+    if name == "dc_offset_100":
+        return piecewise_signal(512, 320, 6, noise=0.15, seed=4) + 100.0, 6, 0.3
+    if name == "k16":
+        return piecewise_signal(512, 320, 16, noise=0.2, seed=5), 16, 0.2
+    if name == "smooth":
+        return smooth_field(512, 320, seed=6), 6, 0.3
+    return piecewise_signal(512, 320, 6, noise=1.0, seed=4), 6, 0.3
+
+
+@pytest.mark.parametrize("name", sorted(_PINNED))
+def test_pinned_fingerprints_thread_pool_and_cluster(cluster, name):
+    y, k, eps = _pinned_signal(name)
+    cs_t = sharded_coreset(y, k, eps, num_bands=3)
+    cluster.coord.register_signal(name, y)
+    cs_c, _, _ = cluster.coord.get_coreset(name, k, eps)
+    assert cs_t.fingerprint() == _PINNED[name]
+    assert cs_c.fingerprint() == _PINNED[name]
+    assert cs_c.certified == cs_t.certified
+    assert cluster.coord.metrics.get("cluster_degraded_builds") == 0
 
 
 def test_cluster_batch_query_parity(cluster):

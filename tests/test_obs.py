@@ -357,6 +357,50 @@ def test_sharded_build_phases_join_the_callers_trace():
         0.95 * tr.get(root.trace_id)["duration_us"]
 
 
+def _phases(build):
+    """(spans, children-by-parent) of ``build()`` traced in its own tracer."""
+    tr = Tracer(capacity=4, max_spans_per_trace=1024)
+    root = tr.start_trace("engine.compress")
+    with tr.attach(root):
+        build()
+    root.end()
+    spans = tr.get(root.trace_id)["spans"]
+    kids: dict = {}
+    for s in spans:
+        kids.setdefault(s["parent_id"], []).append(s)
+    return root, spans, kids
+
+
+def test_shared_build_bands_run_only_the_bounds():
+    """Under the shared tolerance every band's ``core.bicriteria`` says
+    ``bound_only`` and no band estimates a sigma of its own; the compose
+    span carries the shared sigma and the band bound it was judged by."""
+    from repro.core.sharded import sharded_coreset
+    from repro.data import piecewise_signal
+    y = piecewise_signal(256, 160, 6, noise=0.15, seed=4)
+    _, spans, kids = _phases(lambda: sharded_coreset(y, 6, 0.3, num_bands=4))
+    bands = [s for s in spans if s["name"] == "core.band"]
+    assert len(bands) == 4
+    for b in bands:
+        [bic] = [c for c in kids[b["span_id"]]
+                 if c["name"] == "core.bicriteria"]
+        assert bic["attrs"]["bound_only"] is True
+    assert not [s for s in spans if s["name"] == "core.sigma_greedy"]
+    [comp] = [s for s in spans if s["name"] == "core.compose"]
+    assert comp["attrs"]["sigma_shared"] > 0
+    assert comp["attrs"]["sigma_bound"] > 0
+
+
+def test_plain_build_still_estimates_its_sigma():
+    from repro.core import signal_coreset
+    from repro.data import piecewise_signal
+    y = piecewise_signal(128, 96, 6, noise=0.15, seed=4)
+    root, _, kids = _phases(lambda: signal_coreset(y, 6, 0.3))
+    phases = {c["name"]: c for c in kids[root.span_id]}
+    assert {"core.bicriteria", "core.sigma_greedy"} <= set(phases)
+    assert "bound_only" not in phases["core.bicriteria"]["attrs"]
+
+
 # ------------------------------------------------------------------ compiles
 def test_compile_lands_on_the_compiling_threads_span():
     import jax

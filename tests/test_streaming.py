@@ -1,9 +1,11 @@
 """Merge-reduce: compose / recompress / streaming / sharded construction."""
 import numpy as np
+import pytest
 
-from repro.core import (StreamingBuilder, fitting_loss, random_tree_segmentation,
-                        recompress, sharded_coreset, signal_coreset, true_loss)
-from repro.data import piecewise_signal
+from repro.core import (StreamingBuilder, compose, fitting_loss,
+                        random_tree_segmentation, recompress, sharded_coreset,
+                        signal_coreset, true_loss)
+from repro.data import piecewise_signal, smooth_field
 
 
 def _err(cs, y, seg):
@@ -118,3 +120,87 @@ def test_shared_tolerance_matches_single_build_size():
     full = signal_coreset(y, 10, 0.3)
     sh = sharded_coreset(y, 10, 0.3, num_bands=4)   # share_tolerance=True
     assert sh.size <= 3 * full.size
+
+
+def _band_bound_sum(y, k, num_bands):
+    from repro.core import bicriteria
+    from repro.core.sharded import band_bounds
+    return sum(bicriteria(y[a:b], k, max_practical_iters=0).sigma
+               for a, b in band_bounds(y.shape[0], num_bands))
+
+
+@pytest.mark.parametrize("kind,expect", [("noisy", True), ("smooth", False)])
+def test_shared_build_certifies_shared_sigma_against_band_bounds(kind, expect):
+    """A shared-tolerance build is certified iff the shared sigma is no
+    larger than the sum of the bands' O(N) bounds: on heavy noise the
+    row/column bounds reach greedy/4, on a smooth field they do not."""
+    from repro.core.sharded import shared_sigma
+    if kind == "noisy":
+        y = piecewise_signal(512, 320, 6, noise=1.0, seed=4)
+    else:
+        y = smooth_field(512, 320, seed=6)
+    sigma = shared_sigma(y, 6)
+    bound = _band_bound_sum(y, 6, 4)
+    assert (sigma <= bound) is expect
+    cs = sharded_coreset(y, 6, 0.3, num_bands=4)
+    assert cs.certified is expect
+
+
+def test_certify_shared_rule_is_sigma_against_the_band_sum():
+    from repro.core.sharded import band_bounds, certify_shared
+    y = piecewise_signal(128, 96, 6, noise=0.2, seed=2)
+    bands = band_bounds(128, 4)
+    parts = [signal_coreset(y[a:b], 6, 0.3, tolerance_override=0.05)
+             for a, b in bands]
+    bound = sum(p.sigma for p in parts)
+    offsets = [a for a, _ in bands]
+    at = certify_shared(parts, offsets, 128, bound)
+    above = certify_shared(parts, offsets, 128, np.nextafter(bound, np.inf))
+    assert at.certified is True
+    assert above.certified is False
+    # the rule decides only the flag: the composed blocks are the union
+    assert at.fingerprint() == above.fingerprint() == \
+        compose(parts, offsets, n_total=128).fingerprint()
+
+
+def test_single_build_under_override_certifies_the_handed_tolerance():
+    """A bare build handed a tolerance runs only the O(N) bounds, takes
+    its sigma from them, and is certified iff the tolerance is within
+    that sigma's budget eps^2 * sigma / k; its blocks are those of a
+    build that estimated sigma the full way."""
+    from repro.core import bicriteria
+    y = piecewise_signal(128, 96, 6, noise=0.2, seed=2)
+    k, eps = 6, 0.3
+    sigma = bicriteria(y, k, max_practical_iters=0).sigma
+    budget = eps * eps * sigma / k
+    within = signal_coreset(y, k, eps, tolerance_override=budget)
+    beyond = signal_coreset(y, k, eps,
+                            tolerance_override=np.nextafter(budget, np.inf))
+    assert within.sigma == beyond.sigma == sigma
+    assert within.bicriteria.n_iterations == 0
+    assert within.certified is True
+    assert beyond.certified is False
+    full = signal_coreset(y, k, eps)
+    hinted = signal_coreset(y, k, eps, tolerance_override=budget,
+                            _sigma_hint=(full.sigma, full.certified,
+                                         full.bicriteria))
+    assert hinted.fingerprint() == within.fingerprint()
+    # the paper fidelity keeps its own sigma: max_slices depends on it
+    paper = signal_coreset(y, k, eps, fidelity="paper",
+                           tolerance_override=budget)
+    assert paper.bicriteria.n_iterations > 0
+
+
+@pytest.mark.parametrize("shape,k,dc", [((120, 96), 6, 100.0),
+                                        ((64, 300), 16, 0.0)])
+def test_dense_bounds_equal_the_unit_moment_bounds_bitwise(shape, k, dc):
+    """A dense signal's row/column bounds count an interval by its width;
+    that is bitwise the prefix sums of a unit-weight moment raster."""
+    from repro.core import bicriteria
+    y = np.random.default_rng(5).normal(size=shape) + dc
+    dense = bicriteria(y, k, max_practical_iters=0)
+    mom = bicriteria(None, k, moments=(np.ones_like(y), y, y * y),
+                     max_practical_iters=0)
+    assert (dense.sigma_rows, dense.sigma_cols) == \
+        (mom.sigma_rows, mom.sigma_cols)
+    assert dense.sigma_rows > 0 and dense.sigma_cols > 0
