@@ -536,8 +536,8 @@ def _role_reference() -> int:
     sys.path.insert(0, str(SRC))
     from repro import ops
     from repro.core import fitting_loss
-    from repro.core.sharded import (sharded_coreset, shared_sigma,
-                                    shared_tolerance)
+    from repro.core.sharded import (band_bounds, sharded_coreset,
+                                    shared_sigma, shared_tolerance)
     from repro.launch.serve_coresets import enable_compile_cache
     enable_compile_cache()
     dev = ops.device_info()
@@ -545,7 +545,8 @@ def _role_reference() -> int:
         sys.exit(f"reference: no TPU ({dev['platform']})")
     y, _ = _signal(N, M)
     with ops.backend_override("numpy"):
-        tol = shared_tolerance(shared_sigma(y, K), K, EPS)
+        sigma = shared_sigma(y, K, band_bounds(N, CLUSTER_CHIPS)).sigma
+        tol = shared_tolerance(sigma, K, EPS)
     t0 = time.perf_counter()
     cs = sharded_coreset(y, K, EPS, num_bands=CLUSTER_CHIPS,
                          tolerance_override=tol)

@@ -13,13 +13,15 @@ fingerprint-equal** to the single-host ``sharded_coreset`` thread-pool
 path.  Three shared pieces guarantee it:
 
   * ``shared_sigma`` / ``shared_tolerance`` — the coordinator computes the
-    global sigma and per-block cap from its own full-signal stats,
-    identical float op order, and ``certify_shared`` composes the gathered
-    bands and decides ``certified`` by the same rule;
+    global sigma (from the bands' O(N) bounds and the greedy k-tree loss)
+    and per-block cap from the signal it holds, identical float op order,
+    and ``certify_shared`` composes the gathered bands and decides
+    ``certified`` by the same rule;
   * ``band_bounds``       — the same linspace band layout; worker i owns
     band i (round-robin when bands > peers);
-  * workers build ``signal_coreset(slab, k, eps, tolerance_override=tol)``
-    on the same bytes, and both wire codecs round-trip f64 exactly.
+  * workers build ``signal_coreset(slab, k, eps, tolerance_override=tol,
+    bound=bic)`` on the same bytes, ``bic`` the band's bound the
+    coordinator computed, and both wire codecs round-trip f64 exactly.
 
 Failure model (the ISSUE's degraded mode): an RPC answer of ``no_band`` /
 ``stale_band`` heals in-line — re-assign the slab (the coordinator always
@@ -40,6 +42,7 @@ import time
 import numpy as np
 
 from repro import obs
+from repro.core.bicriteria import BicriteriaResult
 from repro.core.coreset import SignalCoreset, signal_coreset
 from repro.core.sharded import (band_bounds, certify_shared, shared_sigma,
                                 shared_tolerance)
@@ -278,17 +281,19 @@ class ClusterEngine(CoresetEngine):
         if len(layout) <= 1 or not self._peers:
             return super()._build_dense(st, k, eps)
         # the one full-signal computation the coordinator keeps: the global
-        # sigma estimate -> shared per-block cap (reusing the delta-patched
-        # integral images when a delta write already materialized them)
+        # sigma -> shared per-block cap (reusing the delta-patched integral
+        # images when a delta write already materialized them); each band's
+        # bound travels with its build request
         ps = st.stats_snapshot(version)
-        sigma = shared_sigma(y, k, _stats=ps)
-        tol = shared_tolerance(sigma, k, eps)
+        sh = shared_sigma(y, k, layout, _stats=ps)
+        tol = shared_tolerance(sh.sigma, k, eps)
         t0 = time.perf_counter()
         with obs.span("cluster.gather", signal=st.name, k=int(k),
                       bands=len(layout)) as g:
             futs = [self._rpc.submit(self._band_part, g, st.name, y,
-                                     i, b0, b1, k, eps, tol)
-                    for i, (b0, b1) in enumerate(layout)]
+                                     i, b0, b1, k, eps, tol, bic)
+                    for i, ((b0, b1), bic) in enumerate(zip(layout,
+                                                            sh.band_bics))]
             results = [f.result() for f in futs]
             for _, peer_ctx in results:
                 if g and peer_ctx is not None:
@@ -299,17 +304,18 @@ class ClusterEngine(CoresetEngine):
                              exemplar=g.trace_id if g else None)
         self.metrics.inc("cluster_gathers")
         cs = certify_shared([part for part, _ in results],
-                            [b0 for b0, _ in layout], n, sigma)
+                            [b0 for b0, _ in layout], n, sh.sigma)
         return cs, eps, version   # composition of disjoint bands is exact
 
     def _band_part(self, gather_span, name: str, y: np.ndarray, i: int,
-                   b0: int, b1: int, k: int, eps: float, tol: float):
+                   b0: int, b1: int, k: int, eps: float, tol: float,
+                   bic: BicriteriaResult):
         """One band's coreset: worker RPC with heal-retry, or the local
         degraded build.  Returns (coreset, worker SpanContext | None)."""
         peer = self._owner(i)
         slab = y[b0:b1]
         if not self._usable(peer):
-            return self._local_part(slab, k, eps, tol), None
+            return self._local_part(slab, k, eps, tol, bic), None
         slab_hash = band_hash(slab)
         deadline = time.perf_counter() + self.rpc_timeout
         # re-enter the request's trace on this pool thread so the rpc span
@@ -323,7 +329,7 @@ class ClusterEngine(CoresetEngine):
                 for attempt in (0, 1):
                     try:
                         msg = peer.client.build(name, b0, b1 - b0, slab_hash,
-                                                k, eps, tol,
+                                                k, eps, tol, bound=bic,
                                                 deadline=deadline)
                         break
                     except WorkerRPCError as exc:
@@ -359,7 +365,7 @@ class ClusterEngine(CoresetEngine):
                 self._mark_down(peer)
                 self.metrics.inc("cluster_rpc_total", worker=peer.url,
                                  outcome="transport_error")
-                return self._local_part(slab, k, eps, tol), None
+                return self._local_part(slab, k, eps, tol, bic), None
             except WorkerRPCError as exc:
                 # an unexpected *answer* (not no_band/stale_band): the
                 # worker is alive but cannot serve this band — degrade
@@ -368,17 +374,17 @@ class ClusterEngine(CoresetEngine):
                     sp.set_attr("error", str(exc))
                 self.metrics.inc("cluster_rpc_total", worker=peer.url,
                                  outcome=f"http_{exc.http}")
-                return (self._local_part(slab, k, eps, tol),
+                return (self._local_part(slab, k, eps, tol, bic),
                         peer.client.last_peer_span)
 
     def _local_part(self, slab: np.ndarray, k: int, eps: float,
-                    tol: float) -> SignalCoreset:
+                    tol: float, bic: BicriteriaResult) -> SignalCoreset:
         """Degraded-mode band build: same bytes, same shared tolerance ->
         bitwise the coreset the worker would have returned.  Clients see a
         normal 200; only the counter records the downgrade."""
         self.metrics.inc("cluster_degraded_builds")
         return signal_coreset(slab, int(k), float(eps),
-                              tolerance_override=float(tol))
+                              tolerance_override=float(tol), bound=bic)
 
     # ------------------------------------------------------------- lifecycle
     def stats(self) -> dict:

@@ -52,6 +52,7 @@ __all__ = [
     "BandAssignRequest", "BandDeltaRequest", "BandBuildRequest", "BandAck",
     "BandCoresetResponse", "WorkerRPCError", "WorkerTransportError",
     "WorkerClient", "band_hash", "coreset_to_msg", "coreset_from_msg",
+    "bicriteria_from_wire",
 ]
 
 
@@ -98,7 +99,10 @@ class BandBuildRequest(P._Wire):
     ``core.sharded.shared_tolerance`` — computed once at the coordinator
     (it owns the full-signal integral images), so remote band builds are
     bitwise the thread-pool path's ``signal_coreset(y[b0:b1], k, eps,
-    tolerance_override=tol)``."""
+    tolerance_override=tol, bound=bic)``.  ``bound`` is that ``bic``, the
+    band's O(N) bound (``BicriteriaResult`` fields), which the coordinator
+    computed for the shared sigma; without it the worker computes it
+    itself, to the same float."""
     signal: P.SignalRef
     row0: int
     rows: int
@@ -107,6 +111,7 @@ class BandBuildRequest(P._Wire):
     eps: float
     tolerance_override: float
     deadline_ms: float | None = None
+    bound: dict | None = None
     _NESTED = {"signal": P.SignalRef}
 
 
@@ -164,11 +169,14 @@ def coreset_to_msg(cs: SignalCoreset, *, cache: str = "built",
         cache=cache, worker_id=worker_id)
 
 
+def bicriteria_from_wire(d: dict) -> BicriteriaResult:
+    return BicriteriaResult(**{f.name: d[f.name]
+                               for f in dataclasses.fields(BicriteriaResult)
+                               if f.name in d})
+
+
 def coreset_from_msg(msg: BandCoresetResponse) -> SignalCoreset:
-    bic = BicriteriaResult(**{
-        f.name: msg.bicriteria[f.name]
-        for f in dataclasses.fields(BicriteriaResult)
-        if f.name in msg.bicriteria})
+    bic = bicriteria_from_wire(msg.bicriteria)
     return SignalCoreset(
         n=int(msg.n), m=int(msg.m), k=int(msg.k), eps=float(msg.eps),
         rects=np.ascontiguousarray(msg.rects, np.int64),
@@ -307,6 +315,7 @@ class WorkerClient:
 
     def build(self, name: str, row0: int, rows: int, slab_hash: str,
               k: int, eps: float, tolerance_override: float, *,
+              bound: BicriteriaResult | None = None,
               deadline: float | None = None) -> BandCoresetResponse:
         ms = None if deadline is None else \
             max((deadline - time.perf_counter()) * 1e3, 0.0)
@@ -314,7 +323,9 @@ class WorkerClient:
                                rows=int(rows), band_hash=slab_hash,
                                k=int(k), eps=float(eps),
                                tolerance_override=float(tolerance_override),
-                               deadline_ms=ms)
+                               deadline_ms=ms,
+                               bound=None if bound is None else
+                               dataclasses.asdict(bound))
         return self.call("/v1/worker/band:build", msg, BandCoresetResponse,
                          deadline=deadline)
 

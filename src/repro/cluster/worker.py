@@ -46,7 +46,7 @@ from repro.service.metrics import ServiceMetrics
 
 from .rpc import (BandAck, BandAssignRequest, BandBuildRequest,
                   BandCoresetResponse, BandDeltaRequest, band_hash,
-                  coreset_to_msg)
+                  bicriteria_from_wire, coreset_to_msg)
 
 __all__ = ["ShardWorker", "make_worker_server"]
 
@@ -209,6 +209,8 @@ class ShardWorker:
             cs = signal_coreset(band, int(msg.k), float(msg.eps),
                                 tolerance_override=float(
                                     msg.tolerance_override),
+                                bound=None if msg.bound is None else
+                                bicriteria_from_wire(msg.bound),
                                 _stats=stats)
         with self._cache_lock:
             self._cache[key] = cs

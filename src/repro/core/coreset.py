@@ -30,7 +30,8 @@ from .bicriteria import BicriteriaResult, bicriteria
 from .caratheodory import block_representatives
 from .stats import PrefixStats
 
-__all__ = ["SignalCoreset", "signal_coreset"]
+__all__ = ["SignalCoreset", "signal_coreset", "band_bound", "greedy_loss",
+           "practical_sigma"]
 
 
 @dataclasses.dataclass
@@ -157,12 +158,48 @@ def resolve_partition_params(sigma: float, k: int, eps: float, fidelity: str,
     return float(tol), int(max_slices)
 
 
+def greedy_loss(ps: PrefixStats, k: int) -> float:
+    """The loss of one cheap greedy k-tree over the whole signal: an upper
+    bound on opt_k."""
+    from .segmentation import greedy_tree
+    from .fitting_loss import true_loss
+    g = greedy_tree(ps, k)
+    return true_loss(None, g.rects, g.labels, ps=ps)
+
+
+def practical_sigma(bound: float, greedy: float) -> tuple[float, bool]:
+    """(sigma, certified) of a practical build: ``max(bound, greedy / 6)``.
+
+    ``bound`` is a certified lower bound on opt_k, ``greedy`` the loss of a
+    greedy k-tree (an upper bound on it).  The bounds vanish when k >~
+    min(n, m)/4 (the paper's own experimental regime: its worst-case
+    machinery needs ~64 k^2 cells), and a partition cut to a vanishing
+    sigma keeps most cells; greedy / 6 is then a heuristic lower bound —
+    the practical stance of the paper's §5 (empirical eps) — and the
+    build is not ``certified``.  /6 calibrated on the worst family (smooth
+    fields): max rel err stays ~eps/2 at eps=0.1 (EXPERIMENTS.md
+    §Guarantee).  Single builds pass their bicriteria bound, band-parallel
+    builds the sum of their bands' (``core.sharded.shared_sigma``).
+    """
+    heur = greedy / 6.0
+    return (float(heur), False) if heur > bound else (float(bound), True)
+
+
+def band_bound(values: np.ndarray, k: int, **span_attrs) -> BicriteriaResult:
+    """The O(N) row/column bounds of ``bicriteria`` alone (no iteration):
+    a certified lower bound on opt_k of ``values``, in the span
+    ``core.bicriteria`` with ``bound_only=True``."""
+    with obs.cpu_span("core.bicriteria", bound_only=True, **span_attrs):
+        return bicriteria(values, k, max_practical_iters=0)
+
+
 def signal_coreset(values: np.ndarray, k: int, eps: float, *,
                    fidelity: str = "practical", nu: float = 8.0,
                    gamma_1d: float = 4.0, sigma_mode: str = "auto",
                    mask: np.ndarray | None = None,
                    tolerance_override: float | None = None,
                    max_slices_override: int | None = None,
+                   bound: BicriteriaResult | None = None,
                    _sigma_hint=None,
                    _stats: PrefixStats | None = None) -> SignalCoreset:
     """SIGNAL-CORESET(D, k, eps); see Theorem 8.
@@ -176,19 +213,16 @@ def signal_coreset(values: np.ndarray, k: int, eps: float, *,
     prefix-sum rebuild.
 
     ``sigma_mode``:
-      * "auto" (default): sigma = max(certified bi-criteria bound,
-        greedy-tree-loss / 4).  The certified bounds vanish when
-        k >~ min(n, m)/4 (the paper's own experimental regime: its worst-case
-        machinery needs ~64 k^2 cells); the greedy k-tree loss is an upper
-        bound on opt_k, so loss/4 is a heuristic lower bound — exactly the
-        practical stance of the paper's §5 (empirical eps).  ``certified``
-        on the result records whether the heuristic kicked in.
+      * "auto" (default): :func:`practical_sigma` of the certified
+        bi-criteria bound and the greedy k-tree loss; ``certified`` on the
+        result records whether its heuristic floor kicked in.
       * "certified": bi-criteria bounds only (used by the guarantee tests).
 
     With ``tolerance_override`` in the practical fidelity no sigma is
-    estimated: sigma is the O(N) row/column bound alone, and ``certified``
-    says whether the handed tolerance is within that bound's budget.  The
-    band-parallel callers certify the shared sigma once, after composing
+    estimated: sigma is the O(N) row/column bound alone (``bound``, when
+    the caller has computed it already), and ``certified`` says whether
+    the handed tolerance is within that bound's budget.  The band-parallel
+    callers certify the shared sigma once, after composing
     (``core.sharded.certify_shared``).
     """
     if not (0.0 < eps < 1.0):
@@ -213,9 +247,7 @@ def signal_coreset(values: np.ndarray, k: int, eps: float, *,
         # the partition reads only (tolerance, max_slices), and practical
         # max_slices does not depend on sigma: keep only the O(N)
         # row/column bounds, enough to judge the handed tolerance
-        with obs.cpu_span("core.bicriteria", bound_only=True):
-            bic = bicriteria(y, k, nu=nu, gamma_1d=gamma_1d,
-                             fidelity=fidelity, max_practical_iters=0)
+        bic = band_bound(y, k) if bound is None else bound
         sigma = bic.sigma
         certified = float(tolerance_override) <= resolve_partition_params(
             sigma, k, eps, fidelity, bic.alpha_hat)[0]
@@ -226,15 +258,9 @@ def signal_coreset(values: np.ndarray, k: int, eps: float, *,
         sigma = bic.sigma
         certified = True
         if sigma_mode == "auto" and fidelity != "paper":
-            from .segmentation import greedy_tree
-            from .fitting_loss import true_loss
             with obs.cpu_span("core.sigma_greedy"):
-                g = greedy_tree(ps_full, k)
-                # /6 calibrated on the worst family (smooth fields): max
-                # rel err stays ~eps/2 at eps=0.1 (EXPERIMENTS.md §Guarantee)
-                heur = true_loss(y, g.rects, g.labels, ps=ps_full) / 6.0
-            if heur > sigma:
-                sigma, certified = heur, False
+                greedy = greedy_loss(ps_full, k)
+            sigma, certified = practical_sigma(sigma, greedy)
 
     tol, max_slices = resolve_partition_params(sigma, k, eps, fidelity, bic.alpha_hat)
     if tolerance_override is not None:

@@ -6,13 +6,22 @@ Two-phase vectorized evaluation, mirroring the paper's case analysis:
     lam gives the *exact* loss  M2 - 2 lam M1 + lam^2 M0  (moment matching,
     Case (i) of Claim 14.1);
   * intersected blocks: the smoothed-assignment loss.  Leaves consume the
-    block's point-weight mass in leaf order; with Z = cumsum of per-leaf
-    overlap counts and U = cumsum of point weights, the mass of point i
-    assigned to leaf l is the overlap of the intervals [Z_{l-1}, Z_l) and
-    [U_{i-1}, U_i) — a closed form for the paper's while-loop (lines 9-25),
-    vectorized over (blocks x leaves x 4).  Any consistent consumption order
-    yields a valid "smoothed version" (Eqs. 9-11), so Lemma 14's guarantee
-    applies unchanged.
+    block's point-weight mass in ascending label order; with Z = cumsum of
+    per-leaf overlap counts and U = cumsum of point weights, the mass of
+    point i assigned to leaf l is the overlap of the intervals
+    [Z_{l-1}, Z_l) and [U_{i-1}, U_i) — a closed form for the paper's
+    while-loop (lines 9-25), vectorized over (blocks x leaves x 4).  Any
+    consistent consumption order yields a valid "smoothed version"
+    (Eqs. 9-11), so Lemma 14's guarantee applies unchanged.
+
+    Label order is the monotone pairing: a block's points are stored in
+    ascending label order (``caratheodory.block_representatives``), so the
+    leaf with the lowest label takes the lowest points.  In the tree's own
+    leaf order, a boundary that runs through a row band of blocks, each
+    straddling the same jump of the signal, handed the high side's leaf
+    the low points in every block of the band, an error of one sign: a
+    near-fit 16-leaf tree on a 2400 x 2400 tile read 22% at eps = 0.2
+    where label order reads 6.5%.
 
 Complexity O(|B2| * k) + O(|B|), matching the paper's O(k |C|) bound with the
 balanced-partition promise |B2| << |B|.
@@ -42,6 +51,8 @@ def fitting_loss(coreset, seg_rects: np.ndarray, seg_labels: np.ndarray,
     """
     seg_rects = np.asarray(seg_rects, np.int64).reshape(-1, 4)
     seg_labels = np.asarray(seg_labels, np.float64).ravel()
+    order = np.argsort(seg_labels, kind="stable")      # consumption order
+    seg_rects, seg_labels = seg_rects[order], seg_labels[order]
     B = coreset.num_blocks
     rects = coreset.rects
     M0, M1, M2 = coreset.moments[:, 0], coreset.moments[:, 1], coreset.moments[:, 2]

@@ -16,35 +16,82 @@ load JAX.
 """
 from __future__ import annotations
 
-import concurrent.futures as _fut
 import dataclasses
+# named here: ``concurrent.futures`` imports its thread module on first
+# use, ~3 ms that fell inside the first build's trace between its phases
+from concurrent.futures import Executor, ThreadPoolExecutor
 
 import numpy as np
 
 from repro import obs
 
-from .coreset import SignalCoreset, signal_coreset
+from .bicriteria import BicriteriaResult
+from .coreset import (SignalCoreset, band_bound, greedy_loss, practical_sigma,
+                      signal_coreset)
+from .stats import PrefixStats
 from .streaming import compose, recompress
 
-__all__ = ["sharded_coreset", "shared_sigma", "shared_tolerance",
-           "certify_shared", "band_bounds", "fitting_loss_batched"]
+__all__ = ["sharded_coreset", "SharedSigma", "shared_sigma",
+           "shared_tolerance", "certify_shared", "band_bounds",
+           "fitting_loss_batched"]
 
 
-def shared_sigma(values: np.ndarray, k: int, _stats=None) -> float:
-    """The global sigma estimate shared across band builds: one cheap
-    greedy k-tree pass, ``greedy loss / 4`` (floored at 1e-12).  Every
-    band-parallel caller, the thread-pool path below and the cluster
-    coordinator's scatter/gather, computes it here, so both derive the
-    *identical* tolerance float, which is what keeps their composed
-    coresets bitwise fingerprint-equal.
+@dataclasses.dataclass(frozen=True)
+class SharedSigma:
+    """The sigma a band-parallel build shares: :func:`practical_sigma` of
+    ``bound``, the sum of the bands' O(N) row/column bounds
+    (``band_bics``), and ``greedy``, the loss of a greedy k-tree over the
+    whole signal.  The restriction of any k-segmentation to a row band is
+    a <= k-segmentation of that band, so ``opt_k(D) >= sum_b opt_k(band_b)
+    >= bound``: the sum is a certified lower bound on opt_k of the whole
+    signal, as a single build's bicriteria bound is on its own."""
+
+    greedy: float
+    band_bics: tuple[BicriteriaResult, ...]
+
+    @property
+    def bound(self) -> float:
+        # summed in band order, as certify_shared sums the bands' sigma
+        return float(sum(b.sigma for b in self.band_bics))
+
+    @property
+    def sigma(self) -> float:
+        return practical_sigma(self.bound, self.greedy)[0]
+
+    @property
+    def certified(self) -> bool:
+        return practical_sigma(self.bound, self.greedy)[1]
+
+
+def shared_sigma(values: np.ndarray, k: int, bands: list[tuple[int, int]],
+                 *, pool: Executor | None = None,
+                 _stats=None) -> SharedSigma:
+    """The global sigma of a band-parallel build (:class:`SharedSigma`).
+    The band bounds run on ``pool`` (a private one if None) while this
+    thread runs the greedy pass.  Every band-parallel caller, the
+    thread-pool path below and the cluster coordinator's scatter/gather,
+    computes it here, so both derive the *identical* tolerance float,
+    which is what keeps their composed coresets bitwise fingerprint-equal.
     """
-    from .segmentation import greedy_tree
-    from .fitting_loss import true_loss
-    from .stats import PrefixStats
+    if pool is None:
+        with ThreadPoolExecutor(max_workers=len(bands)) as own:
+            return shared_sigma(values, k, bands, pool=own, _stats=_stats)
     y = np.asarray(values, np.float64)
-    ps = _stats if _stats is not None else PrefixStats.build(y)
-    g = greedy_tree(ps, k)
-    return max(true_loss(y, g.rects, g.labels, ps=ps) / 4.0, 1e-12)
+    with obs.cpu_span("core.shared_tolerance") as sp:
+        parent = obs.current_span()
+
+        def bound(i: int, r0: int, r1: int) -> BicriteriaResult:
+            with obs.attach(parent):
+                return band_bound(y[r0:r1], k, band=i)
+
+        futs = [pool.submit(bound, i, r0, r1)
+                for i, (r0, r1) in enumerate(bands)]
+        ps = _stats if _stats is not None else PrefixStats.build(y)
+        sh = SharedSigma(greedy_loss(ps, k), tuple(f.result() for f in futs))
+        sp.set_attr("sigma_greedy", sh.greedy / 6.0)
+        sp.set_attr("sigma_bound", sh.bound)
+        sp.set_attr("certified", sh.certified)
+    return sh
 
 
 def shared_tolerance(sigma: float, k: int, eps: float) -> float:
@@ -61,12 +108,14 @@ def certify_shared(parts: list[SignalCoreset], row_offsets: list[int],
     bands' sigma``.  Each band's sigma is a certified lower bound on its
     own opt_k, and the restriction of any k-segmentation of the signal to
     a row band is a <= k-segmentation of that band, so
-    ``opt_k(D) >= sum_b opt_k(band_b) >= sum_b sigma_b``."""
+    ``opt_k(D) >= sum_b opt_k(band_b) >= sum_b sigma_b``.  A sigma from
+    :func:`shared_sigma` is that sum unless its heuristic floor engaged."""
     with obs.cpu_span("core.compose") as sp:
         cs = compose(parts, row_offsets, n_total=n_total)
         bound = float(sum(p.sigma for p in parts))
         sp.set_attr("sigma_shared", float(sigma_shared))
         sp.set_attr("sigma_bound", bound)
+        sp.set_attr("blocks", cs.num_blocks)
     return dataclasses.replace(cs, certified=bool(sigma_shared <= bound))
 
 
@@ -85,12 +134,14 @@ def sharded_coreset(values: np.ndarray, k: int, eps: float, num_bands: int,
     """Build per-row-band coresets in parallel and compose them.
 
     ``share_tolerance``: derive the per-block opt1 cap from a *global* sigma
-    estimate (one cheap greedy k-tree pass — on a real cluster, a
-    tree-reduction over band statistics) and share it across bands.  The
-    Lemma-14 error budget sums over intersected blocks globally, so a global
-    cap keeps |C| at the single-build size; per-band caps (share_tolerance=
-    False, the pure merge-reduce setting) are also valid but ~bands-times
-    larger.
+    (:func:`shared_sigma` — on a real cluster, a tree-reduction over band
+    statistics) and share it across bands.  The Lemma-14 error budget sums
+    over intersected blocks globally, so a global cap keeps |C| at the
+    single-build size; per-band caps (share_tolerance=False, the pure
+    merge-reduce setting) are also valid but ~bands-times larger.  Each
+    band takes its bound from the shared sigma's pass (``bound``) instead
+    of computing it again, but in the ``paper`` fidelity, whose
+    ``max_slices`` reads each band's full bicriteria.
 
     ``_stats`` (internal): prebuilt full-signal integral images for the
     sigma estimate — the serving engine maintains them incrementally via
@@ -98,31 +149,38 @@ def sharded_coreset(values: np.ndarray, k: int, eps: float, num_bands: int,
     from-scratch re-SAT here.
     """
     y = np.asarray(values, np.float64)
-    n = y.shape[0]
-    sigma = None
-    if share_tolerance and "tolerance_override" not in kw:
-        with obs.cpu_span("core.shared_tolerance"):
-            sigma = shared_sigma(y, k, _stats)
-            tol = shared_tolerance(sigma, k, eps)
-        kw = dict(kw, tolerance_override=tol)
-    bands = band_bounds(n, num_bands)
-    # pool threads do not inherit context: each band re-enters the
-    # caller's span, so its phases and dispatch join the build's trace
-    parent = obs.current_span()
+    bands = band_bounds(y.shape[0], num_bands)
+    shared = share_tolerance and "tolerance_override" not in kw
+    band_kw = [kw] * len(bands)
+    with ThreadPoolExecutor(max_workers=max_workers or len(bands)) as ex:
+        if shared:
+            sh = shared_sigma(y, k, bands, pool=ex, _stats=_stats)
+            tol = shared_tolerance(sh.sigma, k, eps)
+            hand = kw.get("fidelity", "practical") != "paper"
+            band_kw = [dict(kw, tolerance_override=tol,
+                            **({"bound": b} if hand else {}))
+                       for b in sh.band_bics]
+        # pool threads do not inherit context: each band re-enters the
+        # caller's span, so its phases and dispatch join the build's trace
+        with obs.cpu_span("core.bands", bands=len(bands)):
+            parent = obs.current_span()
 
-    def build(i: int, r0: int, r1: int) -> SignalCoreset:
-        with obs.attach(parent), \
-                obs.cpu_span("core.band", band=i, row0=r0, row1=r1):
-            return signal_coreset(y[r0:r1], k, eps, **kw)
+            def build(i: int, r0: int, r1: int) -> SignalCoreset:
+                with obs.attach(parent), \
+                        obs.cpu_span("core.band", band=i, row0=r0, row1=r1):
+                    return signal_coreset(y[r0:r1], k, eps, **band_kw[i])
 
-    with _fut.ThreadPoolExecutor(max_workers=max_workers or len(bands)) as ex:
-        parts = list(ex.map(build, range(len(bands)), *zip(*bands)))
+            parts = list(ex.map(build, range(len(bands)), *zip(*bands)))
+            # join the pool inside the span: no thread's exit may fall
+            # between the build's phases
+            ex.shutdown()
     offsets = [b[0] for b in bands]
-    if sigma is not None:
-        cs = certify_shared(parts, offsets, n, sigma)
+    if shared:
+        cs = certify_shared(parts, offsets, y.shape[0], sh.sigma)
     else:
-        with obs.cpu_span("core.compose"):
-            cs = compose(parts, offsets, n_total=n)
+        with obs.cpu_span("core.compose") as sp:
+            cs = compose(parts, offsets, n_total=y.shape[0])
+            sp.set_attr("blocks", cs.num_blocks)
     return recompress(cs) if recompress_result else cs
 
 

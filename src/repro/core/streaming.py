@@ -138,13 +138,14 @@ def weighted_signal_coreset(n: int, m: int, rows: np.ndarray, cols: np.ndarray,
         sigma = bic.sigma
         certified = True
         if fidelity != "paper":
-            # heuristic sigma floor (see signal_coreset): greedy k-tree loss/4
+            # the heuristic sigma floor of every practical build; the
+            # greedy tree's loss at its leaves' weighted means
+            from .coreset import practical_sigma
             from .segmentation import greedy_tree
             g = greedy_tree(ps, k)
             s0, s1, s2 = ps.sums(g.rects[:, 0], g.rects[:, 1], g.rects[:, 2], g.rects[:, 3])
-            heur = float(np.maximum(s2 - s1 * s1 / np.maximum(s0, 1e-300), 0.0).sum()) / 6.0
-            if heur > sigma:
-                sigma, certified = heur, False
+            greedy = float(np.maximum(s2 - s1 * s1 / np.maximum(s0, 1e-300), 0.0).sum())
+            sigma, certified = practical_sigma(sigma, greedy)
     from .coreset import resolve_partition_params
     tol, max_slices = resolve_partition_params(sigma, k, eps, fidelity, bic.alpha_hat)
     if tolerance_override is not None:

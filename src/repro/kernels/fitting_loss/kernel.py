@@ -16,8 +16,9 @@ Layout (every in-kernel array is 2-D and lane-dense):
     (rows 5-7 zero) and whose lanes run tree-major, each tree's K leaves
     zero-padded to Kp.  A zero-area leaf consumes no mass, so padding
     changes no loss;
-  * the cumulative leaf mass Z (a cumsum over each tree's leaves) is a
-    segmented lane scan (``common.lane_prefix_sum``).
+  * the cumulative leaf mass Z (a cumsum over each tree's leaves, in
+    ascending label order, ``ref.by_label``) is a segmented lane scan
+    (``common.lane_prefix_sum``).
 
 Grid (T/TT, B/TB) with the B axis innermost: TPU grids execute
 sequentially with the last axis fastest, so each (1, TT*Kp) output tile
@@ -35,6 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..common import ceil_div, default_interpret, lane_prefix_sum
+from .ref import by_label
 
 __all__ = ["fitting_loss_call", "fitting_loss_batched_call"]
 
@@ -116,6 +118,7 @@ def fitting_loss_batched_call(rects, labels4, weights4, seg_rects, seg_labels,
     Bp = ceil_div(max(B, 1), tb) * tb
     Tp = ceil_div(T, tt) * tt
 
+    seg_rects, seg_labels = by_label(seg_rects, seg_labels)
     blk = jnp.concatenate([rects, labels4, weights4,
                            jnp.zeros((B, 4), rects.dtype)], axis=1)  # (B,16)
     blk = jnp.pad(blk, ((0, Bp - B), (0, 0)))  # zero-weight blocks: no loss

@@ -10,7 +10,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["fitting_loss_ref", "fitting_loss_batched_ref"]
+__all__ = ["fitting_loss_ref", "fitting_loss_batched_ref", "by_label"]
+
+
+def by_label(seg_rects, seg_labels):
+    """Each tree's leaves in ascending label order, the order in which
+    they consume a block's mass (``core.fitting_loss``); leading axes are
+    trees.  Leaves of equal label may take either order: their joint
+    consumption, hence the loss, is the same."""
+    order = jnp.argsort(seg_labels, axis=-1, stable=True)
+    return (jnp.take_along_axis(seg_rects, order[..., None], axis=-2),
+            jnp.take_along_axis(seg_labels, order, axis=-1))
 
 
 def fitting_loss_ref(rects, labels4, weights4, seg_rects, seg_labels):
@@ -25,6 +35,7 @@ def fitting_loss_ref(rects, labels4, weights4, seg_rects, seg_labels):
     merge-reduce recompression, where smoothing by area alone would drop
     (or leave unassigned) part of the mass.
     """
+    seg_rects, seg_labels = by_label(seg_rects, seg_labels)
     z_r = jnp.clip(jnp.minimum(rects[:, None, 1], seg_rects[None, :, 1])
                    - jnp.maximum(rects[:, None, 0], seg_rects[None, :, 0]), 0, None)
     z_c = jnp.clip(jnp.minimum(rects[:, None, 3], seg_rects[None, :, 3])

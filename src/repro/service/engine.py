@@ -322,6 +322,9 @@ class CoresetEngine:
 
         self._compile_hook = _on_compile
         obs.profile.add_compile_hook(self._compile_hook)
+        # builds whose sigma rests on the greedy floor, not on a certified
+        # bound (core.coreset.practical_sigma); listed from the start
+        self.metrics.inc("uncertified_builds_total", 0)
 
     # ---------------------------------------------------------------- ingest
     def register_signal(self, name: str, values: np.ndarray, *,
@@ -820,6 +823,8 @@ class CoresetEngine:
         # actual coreset constructions (scheduler's builds_completed counts
         # finished jobs, which include re-lookup short-circuits above)
         self.metrics.inc("coreset_builds")
+        if not cs.certified:
+            self.metrics.inc("uncertified_builds_total")
         return entry
 
     def _build_dense(self, st: SignalState, k: int, eps: float,

@@ -130,9 +130,14 @@ class DecisionTreeRegressor:
                         + r1 * r1 / np.maximum(r0, 1e-300)
                         - s1 * s1 / max(s0, 1e-300))
             gain = np.where(ok, gain, -np.inf)
-            f, b = np.unravel_index(np.argmax(gain), gain.shape)
-            if not np.isfinite(gain[f, b]) or gain[f, b] <= self.min_gain:
+            best = gain.max()
+            if not np.isfinite(best) or best <= self.min_gain:
                 return
+            # gains equal but for rounding (two features cutting off the
+            # same rows) go to the first (feature, bin): the sums' order,
+            # weighted or duplicated rows, must not pick the split
+            tied = gain >= best - 1e-10 * s2
+            f, b = np.unravel_index(np.argmax(tied), gain.shape)
             heapq.heappush(heap, (-float(gain[f, b]), counter, node_id, idx,
                                   depth, (int(f), int(b))))
             counter += 1

@@ -83,14 +83,16 @@ def test_cluster_fingerprint_and_loss_parity(cluster):
         assert lc["fingerprint"] == ls["fingerprint"]
 
 
-# fingerprints of the 3-band build at 512x320, recorded before bands
-# stopped estimating a sigma of their own: the partition reads only the
-# shared (tolerance, max_slices), so dropping that work changes no block
+# fingerprints of the 3-band build at 512x320, recorded when the shared
+# sigma took the single build's rule (``practical_sigma``): the sum of the
+# bands' O(N) bounds, floored at greedy / 6.  On ``noisy`` the sum lies
+# above greedy / 6 and sets the sigma; on the other three it lies 6-27x
+# under greedy / 4 and the floor stands
 _PINNED = {
-    "dc_offset_100": "03d1fcea6a2fbe4025152aedbfa4044c",
-    "k16": "587c41f64f28efe6e25fd14b3139acc3",
-    "smooth": "66ba492570e7b9f473bbf4213fc84aad",
-    "noisy": "91c37c74654c37e8d2f3c84fef35119e",
+    "dc_offset_100": "9e8c32e4c9d9f553f1cbd88e6a310151",
+    "k16": "044f5f477851488a0018c5d27030e0dc",
+    "smooth": "62c9d39ebccabf54da310110482c7f04",
+    "noisy": "f78e48eefcf7d60c770badb3d8c475ab",
 }
 
 
@@ -114,6 +116,9 @@ def test_pinned_fingerprints_thread_pool_and_cluster(cluster, name):
     assert cs_c.fingerprint() == _PINNED[name]
     assert cs_c.certified == cs_t.certified
     assert cluster.coord.metrics.get("cluster_degraded_builds") == 0
+    assert cs_c.certified is (name == "noisy")
+    assert cluster.coord.metrics.get("uncertified_builds_total") == \
+        int(not cs_c.certified)
 
 
 def test_cluster_batch_query_parity(cluster):
@@ -271,8 +276,9 @@ def test_worker_trace_holds_its_band_build_phases(cluster):
         [build] = [s for s in wt["spans"] if s["name"] == "worker.band_build"]
         phases = {s["name"] for s in wt["spans"]
                   if s["parent_id"] == build["span_id"]}
-        assert {"core.bicriteria", "core.partition",
-                "core.caratheodory"} <= phases
+        # the band's bound came with the request: no bicriteria here
+        assert {"core.partition", "core.caratheodory"} <= phases
+        assert "core.bicriteria" not in phases
         assert n.tracer.stats()["spans_dropped"] == 0
     assert obs.TRACER.stats()["spans_dropped"] == dropped
 

@@ -292,8 +292,10 @@ def test_cpu_span_records_thread_time_only_when_live():
     root = tr.start_trace("req")
     with tr.attach(root):
         with tr.cpu_span("busy", k=1):
-            t_end = time.perf_counter() + 0.02
-            while time.perf_counter() < t_end:
+            # spin on the thread's own CPU clock: on a loaded machine 20 ms
+            # of wall time may hold only a few ms of CPU
+            t_end = time.thread_time() + 0.02
+            while time.thread_time() < t_end:
                 pass
         with tr.cpu_span("asleep"):
             time.sleep(0.05)
@@ -321,9 +323,13 @@ def _union_us(spans) -> float:
 
 
 def test_sharded_build_phases_join_the_callers_trace():
-    """Every band thread re-enters the caller's span: one ``core.band`` per
-    band, each holding its phases and its integral-image dispatch, all in
-    the caller's trace, none dropped, and the children cover the build."""
+    """Every pool thread re-enters the caller's trace: the bands' bounds
+    beneath ``core.shared_tolerance``, one ``core.band`` per band beneath
+    ``core.bands``, each holding its phases and its integral-image
+    dispatch, none dropped.  The caller's thread holds every top-level
+    phase, waits for and joins the pool inside them, and nothing of the
+    build's own falls between them, so the phases cover the build however
+    late the threads run."""
     from repro.core.sharded import band_bounds, sharded_coreset
     from repro.data import piecewise_signal
     # large enough that the pool's start-up stays a small share
@@ -339,17 +345,21 @@ def test_sharded_build_phases_join_the_callers_trace():
     kids: dict = {}
     for s in spans:
         kids.setdefault(s["parent_id"], []).append(s)
-    bands = [s for s in spans if s["name"] == "core.band"]
-    assert sorted((b["attrs"]["band"], b["attrs"]["row0"], b["attrs"]["row1"])
-                  for b in bands) == \
-        [(i, r0, r1) for i, (r0, r1) in enumerate(band_bounds(512, 4))]
+    top = {s["name"]: s for s in kids[root.span_id]}
+    assert len(kids[root.span_id]) == 3
+    assert set(top) == {"core.shared_tolerance", "core.bands", "core.compose"}
+    expect = [(i, r0, r1) for i, (r0, r1) in enumerate(band_bounds(512, 4))]
+    bounds = [s for s in kids[top["core.shared_tolerance"]["span_id"]]
+              if s["name"] == "core.bicriteria"]
+    assert sorted(b["attrs"]["band"] for b in bounds) == [0, 1, 2, 3]
+    bands = kids[top["core.bands"]["span_id"]]
+    assert sorted((b["name"], b["attrs"]["band"], b["attrs"]["row0"],
+                   b["attrs"]["row1"]) for b in bands) == \
+        [("core.band",) + e for e in expect]
     for b in bands:
-        assert b["parent_id"] == root.span_id
         names = {c["name"] for c in kids[b["span_id"]]}
-        assert {"core.bicriteria", "core.partition", "core.caratheodory",
+        assert {"core.partition", "core.caratheodory",
                 "ops.dispatch"} <= names
-    top = {s["name"] for s in kids[root.span_id]}
-    assert top == {"core.shared_tolerance", "core.band", "core.compose"}
     for s in spans:
         if s["name"].startswith("core."):
             assert s["attrs"]["cpu_s"] <= s["duration_us"] / 1e6 + 1e-3
@@ -372,23 +382,40 @@ def _phases(build):
 
 
 def test_shared_build_bands_run_only_the_bounds():
-    """Under the shared tolerance every band's ``core.bicriteria`` says
-    ``bound_only`` and no band estimates a sigma of its own; the compose
-    span carries the shared sigma and the band bound it was judged by."""
+    """Under the shared tolerance each band's bound is one ``core.bicriteria``
+    span saying ``bound_only``, run beside the greedy pass beneath
+    ``core.shared_tolerance``; no band computes a bound or a sigma of its
+    own.  The shared tolerance carries both estimates and whether the bound
+    set the sigma (``certified``); the compose span carries the shared
+    sigma, the band bound it was judged by and the composed block count."""
     from repro.core.sharded import sharded_coreset
     from repro.data import piecewise_signal
     y = piecewise_signal(256, 160, 6, noise=0.15, seed=4)
-    _, spans, kids = _phases(lambda: sharded_coreset(y, 6, 0.3, num_bands=4))
+    cs = None
+
+    def build():
+        nonlocal cs
+        cs = sharded_coreset(y, 6, 0.3, num_bands=4)
+    _, spans, kids = _phases(build)
     bands = [s for s in spans if s["name"] == "core.band"]
     assert len(bands) == 4
     for b in bands:
-        [bic] = [c for c in kids[b["span_id"]]
-                 if c["name"] == "core.bicriteria"]
-        assert bic["attrs"]["bound_only"] is True
+        assert "core.bicriteria" not in {c["name"]
+                                         for c in kids[b["span_id"]]}
+    [tol] = [s for s in spans if s["name"] == "core.shared_tolerance"]
+    bics = [c for c in kids[tol["span_id"]] if c["name"] == "core.bicriteria"]
+    assert len(bics) == 4
+    assert all(b["attrs"]["bound_only"] is True for b in bics)
     assert not [s for s in spans if s["name"] == "core.sigma_greedy"]
+    a = tol["attrs"]
+    assert a["sigma_greedy"] > 0 and a["sigma_bound"] > 0
+    assert a["certified"] is (a["sigma_bound"] >= a["sigma_greedy"])
+    assert a["certified"] is cs.certified
     [comp] = [s for s in spans if s["name"] == "core.compose"]
-    assert comp["attrs"]["sigma_shared"] > 0
-    assert comp["attrs"]["sigma_bound"] > 0
+    assert comp["attrs"]["sigma_shared"] == max(a["sigma_greedy"],
+                                                a["sigma_bound"])
+    assert comp["attrs"]["sigma_bound"] == a["sigma_bound"]
+    assert comp["attrs"]["blocks"] == cs.num_blocks
 
 
 def test_plain_build_still_estimates_its_sigma():

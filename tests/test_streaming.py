@@ -133,17 +133,23 @@ def _band_bound_sum(y, k, num_bands):
 def test_shared_build_certifies_shared_sigma_against_band_bounds(kind, expect):
     """A shared-tolerance build is certified iff the shared sigma is no
     larger than the sum of the bands' O(N) bounds: on heavy noise the
-    row/column bounds reach greedy/4, on a smooth field they do not."""
-    from repro.core.sharded import shared_sigma
+    row/column bounds reach greedy / 6 and set the sigma, on a smooth
+    field they do not, and the greedy floor stands."""
+    from repro.core.coreset import greedy_loss
+    from repro.core.sharded import band_bounds, shared_sigma
+    from repro.core.stats import PrefixStats
     if kind == "noisy":
         y = piecewise_signal(512, 320, 6, noise=1.0, seed=4)
     else:
         y = smooth_field(512, 320, seed=6)
-    sigma = shared_sigma(y, 6)
+    greedy = greedy_loss(PrefixStats.build(y), 6)
     bound = _band_bound_sum(y, 6, 4)
-    assert (sigma <= bound) is expect
+    assert (greedy / 6 <= bound) is expect
+    sh = shared_sigma(y, 6, band_bounds(512, 4))
+    assert (sh.greedy, sh.bound) == (greedy, bound)
+    assert sh.sigma == max(greedy / 6, bound)
     cs = sharded_coreset(y, 6, 0.3, num_bands=4)
-    assert cs.certified is expect
+    assert cs.certified is sh.certified is expect
 
 
 def test_certify_shared_rule_is_sigma_against_the_band_sum():
