@@ -16,7 +16,7 @@ import dataclasses
 
 import numpy as np
 
-from .slice_partition import Rect, slice_partition, slices_count_if
+from .slice_partition import Rect, band_slices, slices_count_if
 from .stats import PrefixStats
 
 __all__ = ["balanced_partition", "BalancedPartition"]
@@ -29,6 +29,7 @@ class BalancedPartition:
     rects: np.ndarray          # (B, 4) int64 rows of (r0, r1, c0, c1)
     band_bounds: np.ndarray    # (H+1,) row indices of committed horizontal bands
     tolerance: float           # gamma^2 * sigma: upper bound on each opt1(B)
+    searches: int = 0          # column and row extent searches run
 
     @property
     def num_blocks(self) -> int:
@@ -61,7 +62,17 @@ def balanced_partition(ps: PrefixStats, tolerance: float,
 
     rects: list[Rect] = []
     band_bounds = [0]
+    searches = 0
     r0 = 0
+
+    def fits(r1: int) -> bool:
+        """Whether the band [r0, r1) partitions into <= max_slices slices."""
+        nonlocal searches
+        band = ps.band(r0, r1)
+        ok = slices_count_if(band, tol, stop_above=max_slices) <= max_slices
+        searches += band.searches
+        return ok
+
     while r0 < n:
         # Find the maximal band [r0, r1) whose partition fits in max_slices,
         # by exponential growth + binary search over the (monotone) slice
@@ -70,15 +81,14 @@ def balanced_partition(ps: PrefixStats, tolerance: float,
         # non-monotone the committed band is merely narrower than maximal,
         # which affects no guarantee — every block still satisfies the
         # tolerance and the cap.)
-        if slices_count_if(ps, r0, r0 + 1, tol, stop_above=max_slices) > max_slices:
+        if not fits(r0 + 1):
             # single-row overflow: committed as-is (Fig. 2, yellow case)
-            cur = slice_partition(ps, r0, r0 + 1, tol)
             r1 = r0 + 1
         else:
             step, r1 = 1, r0 + 1
             while r1 < n:
                 cand = min(r1 + step, n)
-                if slices_count_if(ps, r0, cand, tol, stop_above=max_slices) <= max_slices:
+                if fits(cand):
                     r1 = cand
                     step *= 2
                 else:
@@ -86,13 +96,14 @@ def balanced_partition(ps: PrefixStats, tolerance: float,
             lo, hi = r1, min(r1 + step, n)  # invariant: [r0, lo) fits
             while lo < hi:
                 mid = (lo + hi + 1) // 2
-                if slices_count_if(ps, r0, mid, tol, stop_above=max_slices) <= max_slices:
+                if fits(mid):
                     lo = mid
                 else:
                     hi = mid - 1
             r1 = lo
-            cur = slice_partition(ps, r0, r1, tol)
-        rects.extend(cur)
+        band = ps.band(r0, r1)
+        rects.extend(band_slices(band, tol))
+        searches += band.searches
         band_bounds.append(r1)
         r0 = r1
 
@@ -100,4 +111,5 @@ def balanced_partition(ps: PrefixStats, tolerance: float,
         rects=np.asarray(rects, dtype=np.int64).reshape(-1, 4),
         band_bounds=np.asarray(band_bounds, dtype=np.int64),
         tolerance=float(tol),
+        searches=searches,
     )

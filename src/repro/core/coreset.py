@@ -268,9 +268,10 @@ def signal_coreset(values: np.ndarray, k: int, eps: float, *,
     if max_slices_override is not None:
         max_slices = int(max_slices_override)
 
-    with obs.cpu_span("core.partition"):
+    with obs.cpu_span("core.partition") as sp:
         part: BalancedPartition = balanced_partition(ps_full, tol, max_slices)
         block_id = part.block_id_raster(n, m)
+        sp.set_attr("searches", part.searches)
     with obs.cpu_span("core.caratheodory"):
         labels, weights, moments = block_representatives(
             y.ravel(), block_id.ravel(), part.num_blocks)

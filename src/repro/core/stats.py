@@ -16,6 +16,13 @@ The unmasked/unweighted build routes through the ``repro.ops.sat_moments``
 dispatcher (numpy oracle on host, the ``repro.kernels.sat2d`` Pallas kernel
 on TPU, env-overridable); this module remains the owner of the float64
 query API.
+
+The balanced partition's monotone searches (``max_col_extent`` and
+``max_row_extent``) run through :class:`BandQuery` and :class:`ColumnQuery`:
+a search binds the two image lines it reads once, as ``memoryview``s, and
+runs every probe on Python floats.  The probe's arithmetic order is part of
+the contract, since the coreset fingerprints depend on the rects it picks
+(see :class:`BandQuery`).
 """
 from __future__ import annotations
 
@@ -23,7 +30,7 @@ import dataclasses
 
 import numpy as np
 
-__all__ = ["PrefixStats", "opt1_from_sums"]
+__all__ = ["PrefixStats", "BandQuery", "ColumnQuery", "opt1_from_sums"]
 
 
 def opt1_from_sums(s0, s1, s2):
@@ -45,12 +52,19 @@ class PrefixStats:
     """Integral images of (count, y, y^2) for a (possibly masked, weighted) signal.
 
     ``p0/p1/p2`` have shape (n+1, m+1); entry [i, j] is the sum over the
-    sub-matrix [0:i, 0:j].  Queries take half-open index ranges.
+    sub-matrix [0:i, 0:j].  Queries take half-open index ranges.  The images
+    are held C-contiguous float64, made so once here, so that the searches
+    can bind their rows and columns as ``memoryview``s of doubles.
     """
 
     p0: np.ndarray
     p1: np.ndarray
     p2: np.ndarray
+
+    def __post_init__(self):
+        for name in ("p0", "p1", "p2"):
+            object.__setattr__(self, name, np.ascontiguousarray(
+                getattr(self, name), dtype=np.float64))
 
     # ------------------------------------------------------------------ build
     @staticmethod
@@ -226,8 +240,9 @@ class PrefixStats:
         return opt1_from_sums(*self.sums(r0, r1, c0, c1))
 
     def opt1_scalar(self, r0: int, r1: int, c0: int, c1: int) -> float:
-        """Scalar fast path for the greedy searches (no ufunc machinery):
-        identical math to :meth:`opt1` for single rectangles."""
+        """opt1 of one rectangle from numpy scalars: the arithmetic, term for
+        term, that every probe of :class:`BandQuery` and
+        :class:`ColumnQuery` runs on Python floats."""
         p0, p1, p2 = self.p0, self.p1, self.p2
         s0 = p0[r1, c1] - p0[r0, c1] - p0[r1, c0] + p0[r0, c0]
         if s0 <= 0.0:
@@ -242,6 +257,10 @@ class PrefixStats:
         return float(self.opt1(0, n, 0, m))
 
     # ------------------------------------------------- monotone-window search
+    def band(self, r0: int, r1: int) -> "BandQuery":
+        """The searches of the row band [r0:r1) (see :class:`BandQuery`)."""
+        return BandQuery(self, r0, r1)
+
     def max_col_extent(self, r0: int, r1: int, c0: int, sigma: float) -> int:
         """Largest c_end in (c0, m] with opt1([r0:r1, c0:c_end]) <= sigma.
 
@@ -253,28 +272,105 @@ class PrefixStats:
 
         Returns c0 if even the single first column exceeds sigma.
         """
-        m = self.shape[1]
-        if self.opt1_scalar(r0, r1, c0, c0 + 1) > sigma:
-            return c0
-        lo, hi = c0 + 1, m  # invariant: opt1(.., c0, lo) <= sigma
-        while lo < hi:
-            mid = (lo + hi + 1) // 2
-            if self.opt1_scalar(r0, r1, c0, mid) <= sigma:
-                lo = mid
-            else:
-                hi = mid - 1
-        return lo
+        return BandQuery(self, r0, r1).col_extent(c0, sigma)
 
     def max_row_extent(self, c0: int, c1: int, r0: int, sigma: float) -> int:
         """Row-direction twin of :meth:`max_col_extent` (for B^T recursion)."""
-        n = self.shape[0]
-        if self.opt1_scalar(r0, r0 + 1, c0, c1) > sigma:
-            return r0
-        lo, hi = r0 + 1, n
-        while lo < hi:
-            mid = (lo + hi + 1) // 2
-            if self.opt1_scalar(r0, mid, c0, c1) <= sigma:
-                lo = mid
+        return ColumnQuery(self, c0, c1).row_extent(r0, sigma)
+
+
+class BandQuery:
+    """The monotone searches of one row band [r0:r1) of a :class:`PrefixStats`.
+
+    Rows r0 and r1 of the three images are bound once, as ``memoryview``s
+    (O(1) whatever the width), and every probe of :meth:`col_extent` reads
+    them as Python floats instead of indexing numpy scalars.  The column
+    fallback, :meth:`row_extent`, binds columns c and c+1 the same way, once
+    per column.  ``searches`` counts the searches run.
+
+    A probe computes what :meth:`PrefixStats.opt1_scalar` does, in its
+    order: each moment is ``((p[r1, c1] - p[r0, c1]) - p[r1, c0]) +
+    p[r0, c0]``, then ``s2 - (s1 * s1) / s0``, clamped at 0 (0 where
+    s0 <= 0).  Python floats and ``np.float64`` are both IEEE doubles, so
+    the searches pick the same rects bit for bit; that order is part of
+    the contract, since the coreset fingerprints depend on it.
+    """
+
+    __slots__ = ("ps", "r0", "r1", "searches", "_rows", "_col")
+
+    def __init__(self, ps: PrefixStats, r0: int, r1: int):
+        self.ps, self.r0, self.r1 = ps, r0, r1
+        self.searches = 0
+        self._rows = tuple(memoryview(p[r]) for p in (ps.p0, ps.p1, ps.p2)
+                           for r in (r0, r1))
+        self._col: ColumnQuery | None = None
+
+    def col_extent(self, c0: int, sigma: float) -> int:
+        """:meth:`PrefixStats.max_col_extent` over this band: the single
+        first column, then a binary search over (c0 + 1, m]."""
+        self.searches += 1
+        a0, b0, a1, b1, a2, b2 = self._rows       # a: row r0, b: row r1
+        a0c, b0c, a1c, b1c = a0[c0], b0[c0], a1[c0], b1[c0]
+        a2c, b2c = a2[c0], b2[c0]
+        lo, hi, c1 = c0, len(a0) - 1, c0 + 1
+        while True:     # invariant: lo == c0 or opt1(.., c0, lo) <= sigma
+            s0 = ((b0[c1] - a0[c1]) - b0c) + a0c
+            if s0 <= 0.0:
+                v = 0.0
             else:
-                hi = mid - 1
-        return lo
+                s1 = ((b1[c1] - a1[c1]) - b1c) + a1c
+                s2 = ((b2[c1] - a2[c1]) - b2c) + a2c
+                v = s2 - (s1 * s1) / s0
+            if (v if v > 0.0 else 0.0) <= sigma:
+                lo = c1
+            else:
+                hi = c1 - 1
+            if lo >= hi:
+                return lo
+            c1 = (lo + hi + 1) // 2
+
+    def row_extent(self, c: int, r0: int, sigma: float) -> int:
+        """:meth:`PrefixStats.max_row_extent` down column c from row r0
+        (the column fallback of Algorithm 1), counted in ``searches``."""
+        self.searches += 1
+        col = self._col
+        if col is None or col.c0 != c:
+            col = self._col = ColumnQuery(self.ps, c, c + 1)
+        return col.row_extent(r0, sigma)
+
+
+class ColumnQuery:
+    """The row-direction twin of :class:`BandQuery`: columns c0 and c1 of the
+    three images bound once as ``memoryview``s, probed in Python floats in
+    the order of :meth:`PrefixStats.opt1_scalar`, ``((p[r1, c1] -
+    p[r0, c1]) - p[r1, c0]) + p[r0, c0]``."""
+
+    __slots__ = ("c0", "_cols")
+
+    def __init__(self, ps: PrefixStats, c0: int, c1: int):
+        self.c0 = c0
+        self._cols = tuple(memoryview(p[:, c]) for p in (ps.p0, ps.p1, ps.p2)
+                           for c in (c0, c1))
+
+    def row_extent(self, r0: int, sigma: float) -> int:
+        """Largest r_end in (r0, n] with opt1([r0:r_end, c0:c1]) <= sigma;
+        r0 if even the single first row exceeds sigma."""
+        a0, b0, a1, b1, a2, b2 = self._cols       # a: column c0, b: column c1
+        a0r, b0r, a1r, b1r = a0[r0], b0[r0], a1[r0], b1[r0]
+        a2r, b2r = a2[r0], b2[r0]
+        lo, hi, r1 = r0, len(a0) - 1, r0 + 1
+        while True:     # invariant: lo == r0 or opt1(r0, lo, ..) <= sigma
+            s0 = ((b0[r1] - b0r) - a0[r1]) + a0r
+            if s0 <= 0.0:
+                v = 0.0
+            else:
+                s1 = ((b1[r1] - b1r) - a1[r1]) + a1r
+                s2 = ((b2[r1] - b2r) - a2[r1]) + a2r
+                v = s2 - (s1 * s1) / s0
+            if (v if v > 0.0 else 0.0) <= sigma:
+                lo = r1
+            else:
+                hi = r1 - 1
+            if lo >= hi:
+                return lo
+            r1 = (lo + hi + 1) // 2

@@ -428,6 +428,25 @@ def test_plain_build_still_estimates_its_sigma():
     assert "bound_only" not in phases["core.bicriteria"]["attrs"]
 
 
+def test_partition_span_counts_its_searches():
+    """``core.partition`` carries ``searches``: the column and row extent
+    searches its balanced partition ran, counted per search."""
+    from repro.core import PrefixStats, balanced_partition, signal_coreset
+    from repro.data import piecewise_signal
+    y = piecewise_signal(128, 96, 6, noise=0.15, seed=4)
+    cs = None
+
+    def build():
+        nonlocal cs
+        cs = signal_coreset(y, 6, 0.3)
+    _, spans, _ = _phases(build)
+    [part] = [s for s in spans if s["name"] == "core.partition"]
+    searches = part["attrs"]["searches"]
+    assert searches > 0
+    assert searches == balanced_partition(
+        PrefixStats.build(y), cs.tolerance, cs.max_slices).searches
+
+
 # ------------------------------------------------------------------ compiles
 def test_compile_lands_on_the_compiling_threads_span():
     import jax
