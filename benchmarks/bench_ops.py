@@ -8,14 +8,14 @@ number ``scripts/ci_smoke.sh`` gates on (<= 1e-4 relative): the serving
 engine's /v1/query/loss:batch hot path rides the batched kernel on TPU, so
 it must agree with the dense dispatched path it replaced.
 
-Results merge into ``benchmarks/results/bench_ops.json`` keyed by op and
-backend (existing keys from other runs are preserved).  ``--tune`` populates
-the kernel autotune cache (``repro.ops.autotune``) before the sweep, so the
-accelerator rows run with their tuned configurations and the ``autotune``
-section can gate on them; every backend row carries selection provenance
-(host/device, tuned config, cache hit/miss).
+The ``compensated`` section measures the two-float paths against the f64
+oracle in the scaled measure the variance identity feels
+(``scripts/check_bench_regression.py ops`` holds them to 1e-6).
 
-  python -m benchmarks.bench_ops [--fast] [--tune]
+Results merge into ``benchmarks/results/bench_ops.json`` keyed by op and
+backend (existing keys from other runs are preserved).
+
+  python -m benchmarks.bench_ops [--fast]
 """
 from __future__ import annotations
 
@@ -35,7 +35,8 @@ except ImportError:  # pragma: no cover
 from repro import ops                                        # noqa: E402
 from repro.core import random_tree_segmentation, signal_coreset  # noqa: E402
 from repro.data import piecewise_signal                      # noqa: E402
-from repro.ops import autotune                               # noqa: E402
+from repro.kernels.sat2d.ref import scaled_rel_err           # noqa: E402
+from repro.obs.profile import shape_bucket                   # noqa: E402
 
 
 def _merge_save(obj: dict) -> None:
@@ -111,15 +112,10 @@ def _ingest_delta_gate(n: int, m: int, band_rows: int) -> dict:
         scratch.close()
 
 
-def run(fast: bool = False, tune: bool = False) -> dict:
+def run(fast: bool = False) -> dict:
     rng = np.random.default_rng(0)
     results: dict = {}
     repeat = 2 if fast else 3
-
-    if tune:
-        autotune.tune_all(budget="quick" if fast else "full")
-        emit("ops/autotune_populate", 0.0,
-             f"entries={len(autotune.get_cache().entries)}")
 
     def sweep(op_name, call, parity_of, size=None):
         per = {}
@@ -131,18 +127,11 @@ def run(fast: bool = False, tune: bool = False) -> dict:
                 ref = parity_of(out)                   # numpy runs first
             per[b] = {"us_per_call": dt * 1e6,
                       "rel_delta_vs_numpy": _rel(parity_of(out), ref)}
-            if b != "numpy" and size is not None:
-                # selection provenance: the tuned config this row ran with
-                # (what the backend's autotune.plan consult returned) and
-                # whether it was a cache hit at this shape bucket
-                cfg = autotune.plan(op_name, b, size)
-                per[b]["tuned_config"] = cfg
-                per[b]["tune_cache"] = "hit" if cfg else "miss"
             emit(f"ops/{op_name}_{b}", dt * 1e6,
                  f"rel_vs_numpy={per[b]['rel_delta_vs_numpy']:.2e}")
         if size is not None:
             per["auto_backend"] = ops.selected_backend(op_name, size)
-            per["shape_bucket"] = autotune.shape_bucket(size)
+            per["shape_bucket"] = shape_bucket(size)
         return per
 
     # ---- sat_moments
@@ -222,10 +211,9 @@ def run(fast: bool = False, tune: bool = False) -> dict:
          f"rebuild_ms={results['ingest_delta']['rebuild_ms']:.1f} "
          f"parity={results['ingest_delta']['loss_parity_rel']:.2e}")
 
-    # ---- autotune: tuned-vs-oracle gates, compensated parity certificates,
-    # the hist_split Pallas fix before/after, and dispatch overhead
-    results["autotune"] = _autotune_section(fast, codes, w, w * yv,
-                                            w * yv * yv, B, y)
+    # ---- compensated-f32 parity vs the f64 oracle (fresh runs)
+    results["compensated"] = _compensated_section(codes, w, w * yv,
+                                                  w * yv * yv, B, y)
 
     # selection state alongside the numbers (what auto would pick here)
     results["selection"] = {op: s["selected"]
@@ -234,120 +222,41 @@ def run(fast: bool = False, tune: bool = False) -> dict:
     return results
 
 
-def _autotune_section(fast, codes, w, wy, wy2, B, y) -> dict:
-    """The rows ``check_bench_regression --suite autotune`` gates on.
-
-    ``best_accel_ratio`` proves at least one op has a tuned accelerator
-    backend beating the numpy oracle at its large-shape bucket (from the
-    cache entries the tuner measured on this host; interpret-mode Pallas
-    entries are excluded off-TPU, mirroring ``autotune.tuned_backend``).
-    The ``compensated`` rows are fresh parity measurements — not replays of
-    cached numbers — of the two-float paths against the f64 oracle.
-    """
-    sec: dict = {"provenance": {**autotune.snapshot(),
-                                "host": autotune.host_fingerprint()}}
-
-    # tuned accel vs oracle, from the measured cache entries
-    cache = autotune.get_cache()
-    if not cache.entries:
-        # cold cache (bench run without --tune): measure, but do not persist
-        autotune.tune_all(budget="quick", save=False)
-    device = autotune.device_kind()
-    best = None
-    for key, e in cache.entries.items():
-        op, backend, dev, _bucket = key.split("|")
-        if dev != device or (backend == "pallas" and device != "tpu"):
-            continue
-        if not e.get("us") or not e.get("numpy_us"):
-            continue
-        ratio = e["numpy_us"] / e["us"]
-        if best is None or ratio > best["ratio"]:
-            best = {"ratio": ratio, "op": op, "backend": backend,
-                    "bucket": e.get("bucket"), "config": e.get("config"),
-                    "us": e["us"], "numpy_us": e["numpy_us"]}
-    sec["best_accel"] = best or {"ratio": 0.0}
-    sec["best_accel_ratio"] = (best or {}).get("ratio", 0.0)
-    emit("ops/autotune_best_accel", (best or {}).get("us", 0.0),
-         f"{(best or {}).get('op')}/{(best or {}).get('backend')} "
-         f"ratio={sec['best_accel_ratio']:.2f}")
-
-    # compensated-f32 parity certificates vs the f64 oracle (fresh runs)
+def _compensated_section(codes, w, wy, wy2, B, y) -> dict:
+    """The parity rows ``check_bench_regression ops`` holds to 1e-6: the
+    two-float paths of ``sat_moments`` (xla) and ``hist_split`` (pallas
+    partials, xla chunked) against the f64 oracle."""
+    sec: dict = {}
     want = ops.sat_moments(y, backend="numpy")
     t0 = time.perf_counter()
     got = ops.sat_moments(y, backend="xla", config={"compensated": True})
     comp_us = (time.perf_counter() - t0) * 1e6
     plain = ops.sat_moments(y, backend="xla", config={"compensated": False})
-    sec.setdefault("compensated", {})["sat_moments"] = {
-        "rel_err": autotune._scaled_rel_err(got, want),
-        "plain_rel_err": autotune._scaled_rel_err(plain, want),
+    sec["sat_moments"] = {
+        "rel_err": scaled_rel_err(got, want),
+        "plain_rel_err": scaled_rel_err(plain, want),
         "us": comp_us, "backend": "xla", "shape": list(y.shape)}
 
     wanth = ops.hist_split(codes, w, wy, wy2, B, backend="numpy")
     t0 = time.perf_counter()
     goth = ops.hist_split(codes, w, wy, wy2, B, backend="pallas",
-                          config={"variant": "partials", "tile_p": 2048})
+                          config={"compensated": True})
     hist_us = (time.perf_counter() - t0) * 1e6
     gotx = ops.hist_split(codes, w, wy, wy2, B, backend="xla",
-                          config={"variant": "chunked", "compensated": True})
-    sec["compensated"]["hist_split"] = {
-        "rel_err": autotune._scaled_rel_err(goth, wanth),
-        "xla_chunked_rel_err": autotune._scaled_rel_err(gotx, wanth),
+                          config={"compensated": True})
+    sec["hist_split"] = {
+        "rel_err": scaled_rel_err(goth, wanth),
+        "xla_chunked_rel_err": scaled_rel_err(gotx, wanth),
         "us": hist_us, "backend": "pallas", "variant": "partials",
         "shape": [int(codes.shape[0]), int(codes.shape[1]), int(B)]}
-    for op_name, row in sec["compensated"].items():
+    for op_name, row in sec.items():
         emit(f"ops/compensated_{op_name}", row["us"],
              f"rel_err={row['rel_err']:.2e}")
-
-    # the hist_split Pallas pathology fix, before/after at the bench shape
-    # (the old kernel ran F x P/TP grid steps with a (B, TP) @ (TP, S=8)
-    # layout wasting 15/16 of the MXU output tile)
-    def _hist_variant(variant):
-        call = lambda: ops.hist_split(    # noqa: E731
-            codes, w, wy, wy2, B, backend="pallas",
-            config={"variant": variant, "tile_p": 2048 if variant != "legacy"
-                    else 512})
-        call()                                          # warmup / compile
-        t0 = time.perf_counter()
-        call()
-        return (time.perf_counter() - t0) * 1e6
-    legacy_us = _hist_variant("legacy")
-    fused_us = _hist_variant("fused")
-    sec["hist_split_pallas_fix"] = {
-        "legacy_us": legacy_us, "fused_us": fused_us,
-        "speedup": legacy_us / max(fused_us, 1e-9),
-        "shape": [int(codes.shape[0]), int(codes.shape[1]), int(B)]}
-    emit("ops/hist_split_pallas_fix", fused_us,
-         f"legacy_us={legacy_us:.0f} speedup={legacy_us / max(fused_us, 1e-9):.1f}x")
-
-    # dispatch overhead of the tuned consult (warm cache vs disabled)
-    import os
-    sz = int(codes.size)
-
-    def _selects():
-        t0 = time.perf_counter()
-        for _ in range(2000):
-            ops.select_backend("hist_split", sz)
-        return (time.perf_counter() - t0) / 2000 * 1e6
-    tuned_us = _selects()
-    os.environ[autotune.DISABLE_ENV_VAR] = "off"
-    try:
-        untuned_us = _selects()
-    finally:
-        del os.environ[autotune.DISABLE_ENV_VAR]
-    sec["dispatch_overhead"] = {
-        "tuned_select_us": tuned_us, "untuned_select_us": untuned_us,
-        "ratio": tuned_us / max(untuned_us, 1e-9)}
-    emit("ops/autotune_select_overhead", tuned_us,
-         f"untuned_us={untuned_us:.3f} "
-         f"ratio={sec['dispatch_overhead']['ratio']:.2f}")
     return sec
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
-    ap.add_argument("--tune", action="store_true",
-                    help="populate the kernel autotune cache before the "
-                         "sweep (quick budget with --fast, full otherwise)")
     args = ap.parse_args()
-    run(fast=args.fast, tune=args.tune)
+    run(fast=args.fast)

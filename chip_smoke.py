@@ -128,7 +128,7 @@ class _Checks:
 
     def scaled(self, name: str, got, want, bound: float) -> None:
         """max |got - want| over max(max |want|, 1): the integral-image
-        measure (``autotune._scaled_rel_err``)."""
+        measure (``repro.kernels.sat2d.ref.scaled_rel_err``)."""
         got = np.asarray(got, np.float64)
         want = np.asarray(want, np.float64)
         i = int(np.argmax(np.abs(got - want)))
@@ -148,7 +148,6 @@ class _Checks:
 def _require_tpu():
     """Set up the compile cache, then refuse anything but a TPU."""
     sys.path.insert(0, str(SRC))
-    os.environ["REPRO_AUTOTUNE"] = "0"     # the platform's static selection
     from repro.launch.serve_coresets import enable_compile_cache
     cache_dir = enable_compile_cache()
     import jax
@@ -454,7 +453,6 @@ def _child_env(chip: int | None) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(SRC) + (os.pathsep + env["PYTHONPATH"]
                                     if env.get("PYTHONPATH") else "")
-    env["REPRO_AUTOTUNE"] = "0"
     if chip is None:
         env["JAX_PLATFORMS"] = "cpu"
         return env

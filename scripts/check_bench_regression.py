@@ -8,10 +8,6 @@ a gated row regressed by more than ``--factor`` (default 1.25 = +25%).
 Gated rows (lower is better, all wall-clock):
 
   bench_ops.json       <op>.numpy.us_per_call   per canonical op
-  bench_ops.json       autotune.hist_split_pallas_fix.fused_us (the
-                       ``autotune`` suite: the fixed one-grid-axis Pallas
-                       histogram kernel must not regress toward the legacy
-                       F x P/TP pathology)
   bench_service.json   <mode>.register_seconds  per wire mode present
   bench_service.json   cluster.register_seconds + cluster.loss.p50_ms
                        (the ``cluster`` suite: loadgen over the distributed
@@ -26,15 +22,12 @@ Gated rows (lower is better, all wall-clock):
 
 Absolute rows (gated against a fixed limit, not a baseline ratio):
 
+  bench_ops.json       compensated.{sat_moments,hist_split}.rel_err <= 1e-6
+  (the ``ops`` suite) — the compensated-f32 paths must hold their parity
+  vs the f64 oracle
   bench_service.json   <mode>.tracing.overhead_frac < 0.05 — request
   tracing must cost under 5% on the loss-query p50 (the A/B probe in
   bench_service measures tracing-on vs tracing-off on the same server)
-  bench_ops.json       autotune.best_accel.us_over_numpy < 1.0 — at least
-  one op must have a tuned accelerator backend beating the numpy oracle at
-  its large-shape bucket; autotune.compensated.{sat_moments,hist_split}
-  .rel_err <= 1e-6 — the compensated-f32 paths must hold their parity
-  certificate vs the f64 oracle; autotune.dispatch_overhead.tuned_select_us
-  — the tuned-cache consult must stay microscopic on the dispatch hot path;
   delta_mix.post_reanchor_miss_rate <= 0.01 — a disjoint-delta re-anchor
   must leave subsequent builds as pure cache hits;
   stream.encode_peak_ratio <= 0.5 — the v2 chunked encoder's peak memory
@@ -121,43 +114,20 @@ def _service_abs_rows(doc: dict):
                    float(tracing["overhead_frac"]), _TRACING_OVERHEAD_MAX)
 
 
-def _autotune_rows(doc: dict):
-    """Relative rows of the ``autotune`` section of bench_ops.json."""
-    sec = doc.get("autotune")
+_PARITY_RTOL = 1e-6            # compensated-f32 parity vs f64 oracle
+
+
+def _ops_abs_rows(doc: dict):
+    """Fixed ceilings: the compensated-f32 parity of the integral-image and
+    histogram paths against the f64 oracle (scaled relative error)."""
+    sec = doc.get("compensated")
     if not isinstance(sec, dict):
         return
-    fix = sec.get("hist_split_pallas_fix")
-    if isinstance(fix, dict) and "fused_us" in fix:
-        yield ("autotune.hist_split_pallas_fix.fused_us",
-               float(fix["fused_us"]), _OPS_FLOOR_US)
-
-
-_PARITY_RTOL = 1e-6            # compensated-f32 certificate vs f64 oracle
-_SELECT_OVERHEAD_MAX_US = 50.0  # tuned-consult cost per select_backend
-
-
-def _autotune_abs_rows(doc: dict):
-    """Absolute rows: the tuned-accel win, the compensated-parity
-    certificates, and the dispatch-consult overhead (all lower-is-better,
-    fixed ceilings)."""
-    sec = doc.get("autotune")
-    if not isinstance(sec, dict):
-        return
-    best = sec.get("best_accel")
-    if isinstance(best, dict) and best.get("numpy_us"):
-        # < 1.0 means a tuned accelerator backend beat the numpy oracle at
-        # its large-shape bucket — the headline acceptance row
-        yield ("autotune.best_accel.us_over_numpy",
-               float(best["us"]) / float(best["numpy_us"]), 1.0)
     for op in ("sat_moments", "hist_split"):
-        row = (sec.get("compensated") or {}).get(op)
+        row = sec.get(op)
         if isinstance(row, dict) and "rel_err" in row:
-            yield (f"autotune.compensated.{op}.rel_err",
-                   float(row["rel_err"]), _PARITY_RTOL)
-    ovh = sec.get("dispatch_overhead")
-    if isinstance(ovh, dict) and "tuned_select_us" in ovh:
-        yield ("autotune.dispatch_overhead.tuned_select_us",
-               float(ovh["tuned_select_us"]), _SELECT_OVERHEAD_MAX_US)
+            yield (f"compensated.{op}.rel_err", float(row["rel_err"]),
+                   _PARITY_RTOL)
 
 
 _MISS_RATE_MAX = 0.01          # post-re-anchor builds must be cache hits
@@ -220,11 +190,7 @@ def _qos_abs_rows(doc: dict):
 _SUITES = {
     "ops": ("bench_ops.json", _ops_rows,
             [[sys.executable, "-m", "benchmarks.bench_ops", "--fast"]],
-            None),
-    "autotune": ("bench_ops.json", _autotune_rows,
-                 [[sys.executable, "-m", "benchmarks.bench_ops", "--fast",
-                   "--tune"]],
-                 _autotune_abs_rows),
+            _ops_abs_rows),
     "service": ("bench_service.json", _service_rows,
                 [[sys.executable, "benchmarks/bench_service.py", "--smoke",
                   "--encoding", "json"],
@@ -341,7 +307,7 @@ def check(which: str, factor: float, update: bool, retries: int) -> int:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("which", nargs="?", default="all",
-                    choices=("ops", "autotune", "service", "cluster",
+                    choices=("ops", "service", "cluster",
                              "stream", "qos", "all"))
     ap.add_argument("--update", action="store_true",
                     help="refresh baselines from fresh results")

@@ -8,17 +8,13 @@
 #   tests     tier-1 pytest suite
 #   ops       bench_ops backend sweep + batched-Pallas-vs-dense parity gate
 #             (<= 1e-4 relative) + real 8-device-mesh parity + bench_ops
-#             wall-clock regression gate vs benchmarks/baselines
+#             wall-clock regression gate vs benchmarks/baselines +
+#             compensated-f32 parity vs the f64 oracle (<= 1e-6)
 #   delta     delta-ingest gates (delta-vs-rebuild loss parity <= 1e-9,
 #             delta beats full re-ingest) + deprecation-warning-clean run
 #   service   serve_coresets self-check + 2s closed-loop loadgen per wire
 #             encoding + binary-beats-JSON registration gate + bench_service
 #             regression gate
-#   tune      kernel autotuning gates: quick-budget tune populates a cache,
-#             round-trip + corrupt-cache fallback, env override beats tuned
-#             selection, then bench_ops --tune + the autotune regression
-#             suite (tuned accel beats numpy, compensated-f32 parity <=
-#             1e-6, dispatch-consult overhead bounded)
 #   coalesce  cross-request query coalescing gate: 16 concurrent same-signal
 #             loss queries must fuse into <= 4 scoring dispatches with
 #             per-request losses <= 1e-9 off the uncoalesced path
@@ -86,7 +82,7 @@ EOF
   # with XLA_FLAGS); this step just runs it by name so a smoke log shows it
   python -m pytest -q tests/test_ops.py -k mesh_sharded
 
-  echo "== bench_ops wall-clock regression gate =="
+  echo "== bench_ops wall-clock + compensated-parity regression gate =="
   # the gate re-measures failing rows itself (per-row min over runs):
   # micro-timings are load-sensitive and one sample proves nothing
   python scripts/check_bench_regression.py ops
@@ -161,66 +157,6 @@ EOF
   python scripts/check_bench_regression.py service
 }
 
-stage_tune() {
-  # the stage owns its cache file: CI must not share the checkout's table
-  local tune_cache="${REPRO_AUTOTUNE_CACHE:-${TMPDIR:-/tmp}/repro_ci_autotune.json}"
-  export REPRO_AUTOTUNE_CACHE="$tune_cache"
-  rm -f "$tune_cache"
-
-  echo "== kernel autotune: populate the tuning cache (quick budget) =="
-  python -m repro.ops.autotune --budget quick
-
-  echo "== tuning-cache round-trip, corrupt-cache fallback, override wins =="
-  python - <<'EOF'
-import json, os, pathlib, sys
-from repro import ops
-from repro.ops import autotune
-
-cache = autotune.get_cache()
-assert cache.loaded_from_disk and cache.entries, \
-    f"tune run did not round-trip through {cache.path}"
-print(f"[ci_smoke] tuning cache round-trip: {len(cache.entries)} entries "
-      f"from {cache.path} (fingerprint {autotune.kernel_fingerprint()})")
-
-# a tuned winner must exist for at least one op at its tuned bucket...
-tuned = [(k.split("|")[0], v["size"]) for k, v in cache.entries.items()
-         if ops.select_backend(k.split("|")[0], v["size"]) != "numpy"]
-if not tuned:
-    sys.exit("[ci_smoke] FAIL: no tuned selection fired at any tuned bucket")
-op, size = tuned[0]
-sel = ops.select_backend(op, size)
-print(f"[ci_smoke] tuned selection: {op}@{size} -> {sel}")
-
-# ...and every explicit pin must still beat it
-os.environ[ops.ENV_VAR] = "numpy"
-assert ops.select_backend(op, size) == "numpy", "env must beat tuned"
-del os.environ[ops.ENV_VAR]
-with ops.backend_override("numpy"):
-    assert ops.select_backend(op, size) == "numpy", "override must beat tuned"
-print("[ci_smoke] REPRO_OPS_BACKEND + backend_override beat tuned selection")
-
-# a corrupt cache file must fall back to heuristics, never fail dispatch
-path = pathlib.Path(cache.path)
-backup = path.read_text()
-path.write_text("{corrupt json")
-autotune.reset_cache()
-assert not autotune.get_cache().entries, "corrupt cache must load empty"
-assert ops.select_backend(op, size) in ops.BACKENDS
-ops.sat_moments([[1.0, 2.0], [3.0, 4.0]])      # dispatch survives
-path.write_text(backup)
-autotune.reset_cache()
-errs = autotune.counters_snapshot()["cache_load_errors"]
-assert errs >= 1, "corrupt load must be counted"
-print(f"[ci_smoke] corrupt-cache fallback clean (cache_load_errors={errs})")
-EOF
-
-  echo "== bench_ops with tuning (--fast --tune) =="
-  python -m benchmarks.bench_ops --fast --tune
-
-  echo "== autotune regression gate (tuned accel win + parity + overhead) =="
-  python scripts/check_bench_regression.py autotune
-}
-
 stage_coalesce() {
   echo "== cross-request query coalescing gate =="
   python scripts/coalesce_gate.py
@@ -278,7 +214,7 @@ stage_qos() {
   python scripts/check_bench_regression.py qos
 }
 
-ALL_STAGES=(lint tests ops delta tune service coalesce trace stream cluster qos)
+ALL_STAGES=(lint tests ops delta service coalesce trace stream cluster qos)
 # bash 3.2 (macOS) treats an empty array as unbound under set -u, so pick
 # the default stage list off $# instead of the array length
 if [ $# -eq 0 ]; then
@@ -289,7 +225,7 @@ fi
 
 for stage in "${STAGES[@]}"; do
   case "$stage" in
-    lint|tests|ops|delta|tune|service|coalesce|trace|stream|cluster|qos) "stage_${stage}" ;;
+    lint|tests|ops|delta|service|coalesce|trace|stream|cluster|qos) "stage_${stage}" ;;
     *) echo "[ci_smoke] unknown stage '${stage}' (known: ${ALL_STAGES[*]})" >&2
        exit 2 ;;
   esac

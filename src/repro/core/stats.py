@@ -77,9 +77,8 @@ class PrefixStats:
         if mask is None and weights is None:
             # the common (unmasked, unweighted) path goes through the op
             # dispatcher: numpy oracle by default on host (same float64
-            # cumsums as before), the sat2d Pallas kernel on TPU or under
-            # REPRO_OPS_BACKEND.  The float32 accelerator backends trade
-            # precision for bandwidth; the query API stays float64.
+            # cumsums as before), the compensated sat2d Pallas kernel on
+            # TPU; the query API stays float64.
             from repro import ops
             return PrefixStats.from_sat(
                 np.asarray(ops.sat_moments(y), np.float64))

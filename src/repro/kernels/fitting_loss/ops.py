@@ -23,7 +23,7 @@ _MANY_DEPRECATION_WARNED = False
 def coreset_loss(cs, seg_rects, seg_labels, interpret: bool | None = None,
                  tile_b: int = 1024):
     """Algorithm-5 loss of one segmentation against a SignalCoreset.
-    ``tile_b`` is the coreset-block tile edge the autotuner searches over."""
+    ``tile_b`` is the coreset-block tile edge."""
     return fitting_loss_call(
         jnp.asarray(cs.rects, jnp.float32), jnp.asarray(cs.labels, jnp.float32),
         jnp.asarray(cs.weights, jnp.float32),
@@ -36,7 +36,7 @@ def coreset_loss_batched(cs, seg_rects, seg_labels,
                          tile_b: int = 512, tile_t: int = 8):
     """(T,) losses via the batched kernel: seg_rects (T, K, 4),
     seg_labels (T, K) scored in one pallas_call.  ``tile_b``/``tile_t``
-    are the block/tree tile edges the autotuner searches over."""
+    are the block/tree tile edges."""
     return fitting_loss_batched_call(
         jnp.asarray(cs.rects, jnp.float32), jnp.asarray(cs.labels, jnp.float32),
         jnp.asarray(cs.weights, jnp.float32),

@@ -19,17 +19,14 @@ tile once per P tile instead of once per (feature, P) tile.
 
 The matmul is laid out as (S, TP) @ (TP, B): the B bins ride the 128-wide
 lane axis (fully utilized for B >= 128) and the S value channels ride the
-sublane axis.  The transposed layout this kernel replaced — (B, TP) @
-(TP, S) with S = 8 output lanes — wasted 15/16 of every MXU output tile and
-ran F x P/TP grid steps; it survives as ``variant="legacy"`` so the
-autotuner can measure the difference on real hardware (and so the bench can
-record the before/after), but is never picked.
+sublane axis.  The transposed layout — (B, TP) @ (TP, S) with S = 8 output
+lanes — would waste 15/16 of every MXU output tile.
 
-``accumulate=False`` ("partials" variant) skips the cross-tile accumulation
-and emits per-P-tile partial histograms (P/TP, F, S, B) instead: the host
-combines them in f64, turning the f32 scatter-order error of a long P axis
-into a handful of f64 adds — the compensated path the autotuner certifies
-for precision-pinned dispatch.
+The "partials" variant skips the cross-tile accumulation and emits
+per-P-tile partial histograms (P/TP, F, S, B) instead: the host combines
+them in f64, turning the f32 scatter-order error of a long P axis into a
+handful of f64 adds — the compensated path (``hist_split`` with
+``config={"compensated": True}``).
 """
 from __future__ import annotations
 
@@ -83,23 +80,6 @@ def _hist_kernel_partials(codes_ref, vals_ref, o_ref):
                               preferred_element_type=o_ref.dtype)
 
 
-def _hist_kernel_legacy(codes_ref, vals_ref, o_ref):
-    # pre-fix kernel, kept for the autotuner/bench as variant="legacy":
-    # grid (F, P/TP), one feature per step, (B, TP) @ (TP, S) layout
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    codes = codes_ref[0, :]                                   # (TP,) int32
-    n_bins = o_ref.shape[1]
-    onehot = (codes[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (codes.shape[0], n_bins), 1)).astype(vals_ref.dtype)
-    o_ref[0] += jnp.dot(onehot.T, vals_ref[...], precision=_PRECISION,
-                        preferred_element_type=o_ref.dtype)
-
-
 @functools.partial(jax.jit, static_argnames=("n_bins", "tile_p", "variant",
                                              "interpret"))
 def histograms_kernel_call(codes_fp: jnp.ndarray, vals: jnp.ndarray,
@@ -108,9 +88,9 @@ def histograms_kernel_call(codes_fp: jnp.ndarray, vals: jnp.ndarray,
                            interpret: bool | None = None) -> jnp.ndarray:
     """codes_fp: (F, P) int32; vals: (P, S<=8) f32.
 
-    Returns (F, n_bins, S) for ``variant`` in {"fused", "legacy"}; the
-    "partials" variant returns (P/TP, F, n_bins, S) per-tile partials for
-    the host to combine in f64 (the compensated path).
+    Returns (F, n_bins, S) for ``variant="fused"``; the "partials" variant
+    returns (P/TP, F, n_bins, S) per-tile partials for the host to combine
+    in f64 (the compensated path).
     """
     if interpret is None:
         interpret = default_interpret()
@@ -124,19 +104,6 @@ def histograms_kernel_call(codes_fp: jnp.ndarray, vals: jnp.ndarray,
         vals = jnp.pad(vals, ((0, pad), (0, 0)))  # zero weights: no effect
     Pp = codes_fp.shape[1]
     vals_p = jnp.pad(vals, ((0, 0), (0, _S_PAD - S))) if S < _S_PAD else vals
-    if variant == "legacy":
-        out = pl.pallas_call(
-            _hist_kernel_legacy,
-            grid=(F, Pp // tp),
-            in_specs=[
-                pl.BlockSpec((1, tp), lambda f, p: (f, p)),
-                pl.BlockSpec((tp, _S_PAD), lambda f, p: (p, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, n_bins, _S_PAD), lambda f, p: (f, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((F, n_bins, _S_PAD), vals.dtype),
-            interpret=interpret,
-        )(codes_fp, vals_p)
-        return out[:, :, :S]
     if variant == "partials":
         out = pl.pallas_call(
             _hist_kernel_partials,

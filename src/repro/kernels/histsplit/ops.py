@@ -20,8 +20,8 @@ def histograms(codes, w, wy, wy2, n_bins: int, *, tile_p: int = 2048,
     the kernel bins all six channels and emits per-P-tile partial
     histograms, and the cross-tile + hi/lo reduction happens here in f64 —
     so neither the input cast nor the scatter order of a long P axis leaves
-    f32-level error in the bin sums.  Tile size and variant are what the
-    autotuner searches over.
+    f32-level error in the bin sums.  ``variant="fused"`` accumulates the
+    three f32 channels across tiles on the device.
     """
     codes_fp = jnp.asarray(np.asarray(codes).T, jnp.int32)       # (F, P)
     if variant == "partials":

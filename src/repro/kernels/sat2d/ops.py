@@ -64,10 +64,9 @@ def sat_moments(y: jnp.ndarray, tile: int = _DEFAULT_TILE,
 
     The three channels are folded into the row axis so both scan passes run
     as single kernel launches ((3n, m) row scan; (m, 3n) per-channel column
-    scan via a channel-blocked layout).  ``tile`` is the Pallas block edge
-    the autotuner searches over; ``donate=True`` releases ``y``'s device
-    buffer to XLA (accelerator platforms only — the caller must not reuse
-    it)."""
+    scan via a channel-blocked layout).  ``tile`` is the Pallas block
+    edge; ``donate=True`` releases ``y``'s device buffer to XLA
+    (accelerator platforms only — the caller must not reuse it)."""
     fn = (_sat_moments_donate if donate and _donation_supported()
           else _sat_moments_plain)
     return fn(y, tile=tile, interpret=interpret)

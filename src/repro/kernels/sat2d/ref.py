@@ -8,8 +8,8 @@ the ``lo`` channel instead of being discarded.  The inputs are split the
 same way (``hi = f32(x)``, ``lo = f32(x - f64(hi))``), which also captures
 the f64 -> f32 cast error of the raw signal.  Recombining ``hi + lo`` in
 f64 on the host yields integral images within ~1e-10 scaled relative error
-of the f64 oracle — comfortably inside the 1e-6 certificate the autotuner
-requires before it lifts a precision pin — at roughly 3-4x the flops of the
+(:func:`scaled_rel_err`) of the f64 oracle — comfortably inside the 1e-6
+parity bound the tests hold them to — at roughly 3-4x the flops of the
 plain f32 scan, all of them accelerator-resident.
 """
 from __future__ import annotations
@@ -21,7 +21,7 @@ from ..common import two_sum
 
 __all__ = [
     "sat2d_ref", "sat_moments_ref", "delta_sat_ref", "sat_stack_ref",
-    "split_hi_lo", "comp_cumsum", "sat_moments_comp_ref",
+    "split_hi_lo", "scaled_rel_err", "comp_cumsum", "sat_moments_comp_ref",
     "delta_sat_comp_ref", "sat_stack_comp_ref",
 ]
 
@@ -72,6 +72,18 @@ def split_hi_lo(x) -> tuple[jnp.ndarray, jnp.ndarray]:
     hi = np.asarray(x, np.float32)
     lo = np.asarray(x - np.asarray(hi, np.float64), np.float32)
     return jnp.asarray(hi), jnp.asarray(lo)
+
+
+def scaled_rel_err(got, want) -> float:
+    """max |got - want| scaled by the output's own magnitude (floor 1): the
+    error measure the S2 - S1^2/S0 identity actually feels.  Elementwise
+    relative error is meaningless here — integral images pass through
+    near-zero entries whose denominators amplify benign f32 rounding."""
+    import numpy as np
+    got = np.asarray(got, np.float64).ravel()
+    want = np.asarray(want, np.float64).ravel()
+    scale = max(float(np.max(np.abs(want))) if want.size else 0.0, 1.0)
+    return float(np.max(np.abs(got - want))) / scale if got.size else 0.0
 
 
 def comp_cumsum(hi: jnp.ndarray, lo: jnp.ndarray,

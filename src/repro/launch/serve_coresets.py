@@ -190,21 +190,12 @@ def _runtime_hygiene(verbose: bool = True) -> None:
 
       * persistent XLA compilation cache (:func:`enable_compile_cache`):
         jit recompiles of the same kernels across restarts are pure waste
-        on a serving box;
-      * pre-load the kernel autotune cache so the first dispatch does not
-        pay the disk read + fingerprint check mid-request.
+        on a serving box.
     """
     cache_dir = enable_compile_cache()
-    from repro.ops import autotune
-    snap = autotune.snapshot()
     if verbose:
         print(f"[serve_coresets] XLA compilation cache: {cache_dir}",
               flush=True)
-        print(f"[serve_coresets] autotune cache: {snap['entries']} "
-              f"entries from {snap['cache_path']} "
-              f"(loaded={snap['cache_loaded']}, "
-              f"fingerprint {snap['fingerprint']}, "
-              f"precision={snap['precision_mode']})", flush=True)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -272,7 +263,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "reject on predicted deadline misses")
     ap.add_argument("--no-runtime-hygiene", action="store_true",
                     help="skip startup hygiene (persistent XLA compilation "
-                         "cache, autotune-cache preload)")
+                         "cache)")
     ap.add_argument("--smoke", action="store_true",
                     help="self-check with concurrent SDK clients, then exit")
     return ap

@@ -14,8 +14,8 @@ The serving/tuning hot paths reduce to six primitives:
                                          buckets in one dispatch
 
 Each dispatches through the backend registry (numpy oracle / jitted xla /
-Pallas kernel) with capability+size auto-selection and the
-``REPRO_OPS_BACKEND`` env override — see ``registry.py`` for the rules.
+Pallas kernel), selected by platform and problem size — see
+``registry.py`` for the rule.
 Core, trees, and the serving engine all route through this module instead
 of importing kernel modules directly, so both the read path (losses,
 histograms) and the write path (delta ingest, streaming compress) are
@@ -25,16 +25,14 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import autotune  # noqa: F401  (tuning cache + precision promotion)
 from . import backends as _backends  # noqa: F401  (registers implementations)
-from .registry import (BACKENDS, ENV_VAR, OPS, BackendError,
-                       available_backends, backend_override, device_info,
-                       dispatch, register, resolve, select_backend, snapshot)
+from .registry import (BACKENDS, OPS, BackendError, available_backends,
+                       backend_override, device_info, dispatch, register,
+                       resolve, select_backend, snapshot)
 
 __all__ = [
-    "OPS", "BACKENDS", "ENV_VAR", "BackendError", "autotune",
-    "available_backends", "backend_override", "device_info", "dispatch",
-    "register",
+    "OPS", "BACKENDS", "BackendError", "available_backends",
+    "backend_override", "device_info", "dispatch", "register",
     "resolve", "select_backend", "selected_backend", "snapshot",
     "sat_moments", "delta_sat", "fitting_loss", "fitting_loss_batched",
     "hist_split", "streaming_compress",

@@ -10,22 +10,21 @@ kernel packages.  Conventions:
     ``core.sharded``) use the kernel/ref modules directly;
   * the ``pallas`` implementations accept ``interpret=`` so tests can pin
     interpret mode explicitly;
-  * every implementation accepts ``config=`` — a tuning configuration dict
-    (tile sizes, lowering variant, compensated-summation flag; the xla
-    and pallas integral-image backends default to ``compensated`` — plain
-    f32 only when a config asks for it).  ``None``
-    means "consult the autotune cache for this problem size" (a cold cache
-    yields ``{}`` and the built-in defaults below); the tuner passes
-    explicit configs while measuring.  The numpy oracle ignores it.
+  * every implementation accepts ``config=``, a dict of which the
+    accelerator backends read one key, ``compensated``: the two-float
+    (TwoSum) path.  The integral-image ops (``sat_moments``,
+    ``delta_sat``, ``streaming_compress``) default to it, so plain f32
+    runs only where a caller passes ``{"compensated": False}``;
+    ``hist_split`` defaults to its plain f32 lowering.  Tile sizes are
+    the kernel wrappers' defaults.  The numpy oracle ignores ``config``.
 
 The dense jnp math exists exactly once, in ``repro.kernels.*.ref`` — the
 xla backends jit those oracles; nothing here re-derives a formula.  The
 ``compensated`` configs run the two-float (TwoSum) twins of the same refs
 and recombine the (hi, lo) pairs in f64 on the host: accelerator-resident
 f32 arithmetic whose result matches the f64 oracle to ~1e-10 scaled
-relative error — the path that lets the autotuner lift a precision pin
-(see ``autotune.py``).  ``_compensated`` runs them, and under a live
-dispatch span times the upload, the device call and the read-back apart.
+relative error.  ``_compensated`` runs them, and under a live dispatch
+span times the upload, the device call and the read-back apart.
 """
 from __future__ import annotations
 
@@ -35,8 +34,12 @@ import numpy as np
 
 from repro import obs
 
-from . import autotune
 from .registry import register
+
+
+def _is_compensated(config, default: bool = True) -> bool:
+    """The one key of ``config`` an accelerator backend reads."""
+    return bool((config or {}).get("compensated", default))
 
 
 def _recombine(pair) -> np.ndarray:
@@ -98,9 +101,7 @@ def _sat_moments_xla():
     f_comp = jax.jit(sat_moments_comp_ref)
 
     def sat_moments(y, config=None):
-        cfg = config if config is not None else autotune.plan(
-            "sat_moments", "xla", 3 * np.asarray(y).size)
-        if cfg.get("compensated", True):
+        if _is_compensated(config):
             return _compensated(f_comp, y)
         return np.asarray(f(jnp.asarray(y, jnp.float32)))
     return sat_moments
@@ -113,16 +114,12 @@ def _sat_moments_pallas():
     from repro.kernels.sat2d.ops import sat_moments_comp
 
     def sat_moments(y, interpret=None, config=None):
-        cfg = config if config is not None else autotune.plan(
-            "sat_moments", "pallas", 3 * np.asarray(y).size)
-        tile = int(cfg.get("tile", 256))
-        if cfg.get("compensated", True):
+        if _is_compensated(config):
             return _compensated(functools.partial(
-                sat_moments_comp, tile=tile, interpret=interpret), y)
+                sat_moments_comp, interpret=interpret), y)
         # donate: the device copy made here is never reused on the host
         return np.asarray(kernel_sat_moments(
-            jnp.asarray(y, jnp.float32), tile=tile,
-            interpret=interpret, donate=True))
+            jnp.asarray(y, jnp.float32), interpret=interpret, donate=True))
     return sat_moments
 
 
@@ -157,9 +154,7 @@ def _delta_sat_xla():
     f_comp = jax.jit(delta_sat_comp_ref)
 
     def delta_sat(carry, tail, config=None):
-        cfg = config if config is not None else autotune.plan(
-            "delta_sat", "xla", 3 * np.asarray(tail).size)
-        if cfg.get("compensated", True):
+        if _is_compensated(config):
             # the stored carry enters as its own (hi, lo) pair, so chained
             # patches keep full two-float precision across calls
             return _compensated(f_comp, carry, tail)
@@ -175,16 +170,12 @@ def _delta_sat_pallas():
                                         delta_sat_moments_comp)
 
     def delta_sat(carry, tail, interpret=None, config=None):
-        cfg = config if config is not None else autotune.plan(
-            "delta_sat", "pallas", 3 * np.asarray(tail).size)
-        tile = int(cfg.get("tile", 256))
-        if cfg.get("compensated", True):
+        if _is_compensated(config):
             return _compensated(functools.partial(
-                delta_sat_moments_comp, tile=tile, interpret=interpret),
-                carry, tail)
+                delta_sat_moments_comp, interpret=interpret), carry, tail)
         return np.asarray(delta_sat_moments(
             jnp.asarray(carry, jnp.float32), jnp.asarray(tail, jnp.float32),
-            tile=tile, interpret=interpret, donate=True))
+            interpret=interpret, donate=True))
     return delta_sat
 
 
@@ -223,12 +214,7 @@ def _fitting_loss_pallas():
     from repro.kernels.fitting_loss.ops import coreset_loss
 
     def fl(cs, seg_rects, seg_labels, interpret=None, config=None):
-        cfg = config if config is not None else autotune.plan(
-            "fitting_loss", "pallas",
-            cs.num_blocks * max(np.asarray(seg_rects).reshape(-1, 4).shape[0],
-                                1))
         return float(coreset_loss(cs, seg_rects, seg_labels,
-                                  tile_b=int(cfg.get("tile_b", 1024)),
                                   interpret=interpret))
     return fl
 
@@ -269,15 +255,8 @@ def _fitting_loss_batched_pallas():
     from repro.kernels.fitting_loss.ops import coreset_loss_batched
 
     def fb(cs, seg_rects, seg_labels, interpret=None, config=None):
-        sr = np.asarray(seg_rects)
-        cfg = config if config is not None else autotune.plan(
-            "fitting_loss_batched", "pallas",
-            cs.num_blocks * sr.shape[0] * max(sr.shape[1], 1))
         return np.asarray(coreset_loss_batched(
-            cs, seg_rects, seg_labels,
-            tile_b=int(cfg.get("tile_b", 512)),
-            tile_t=int(cfg.get("tile_t", 8)),
-            interpret=interpret), np.float64)
+            cs, seg_rects, seg_labels, interpret=interpret), np.float64)
     return fb
 
 
@@ -308,7 +287,7 @@ def _hist_split_xla():
     # compensated variant: P-axis chunk combined in f64.  Short chunks keep
     # the *within*-chunk f32 accumulation (which the hi/lo split does not
     # compensate — only the input cast error) to ~32 adds per bin, an order
-    # of magnitude inside the 1e-6 certificate bound.
+    # of magnitude inside the 1e-6 parity bound (tests/test_ops.py).
     _CHUNK = 8192
 
     # segment-sum per feature: O(P*F) work and memory, unlike the one-hot
@@ -319,18 +298,6 @@ def _hist_split_xla():
         def one(c):
             return jax.ops.segment_sum(vals, c, num_segments=n_bins)
         return jax.vmap(one, in_axes=1)(codes)          # (F, n_bins, S)
-
-    # one flat segment-sum over F*n_bins fused ids instead of a vmap of F
-    # scatters — algorithmically the same sums, a different XLA lowering
-    @functools.partial(jax.jit, static_argnames=("n_bins",))
-    def _hist_flat(codes, vals, n_bins):
-        P, F = codes.shape
-        ids = (codes + jnp.arange(F, dtype=codes.dtype)[None, :] * n_bins)
-        out = jax.ops.segment_sum(
-            jnp.broadcast_to(vals[:, None, :], (P, F, vals.shape[1]))
-            .reshape(P * F, vals.shape[1]),
-            ids.reshape(P * F), num_segments=F * n_bins)
-        return out.reshape(F, n_bins, vals.shape[1])
 
     # compensated: per-chunk f32 segment sums of the (hi, lo) channel pairs,
     # combined across chunks (and hi+lo) in f64 on the host
@@ -344,9 +311,7 @@ def _hist_split_xla():
 
     def hist(codes, w, wy, wy2, n_bins, config=None):
         codes = np.asarray(codes)
-        cfg = config if config is not None else autotune.plan(
-            "hist_split", "xla", codes.size)
-        if cfg.get("compensated"):
+        if _is_compensated(config, default=False):
             pairs = [split_hi_lo(a) for a in (w, wy, wy2)]
             vals = jnp.stack([p[0] for p in pairs]
                              + [p[1] for p in pairs], axis=1)   # (P, 6)
@@ -361,12 +326,11 @@ def _hist_split_xla():
                 cj.reshape(C, _CHUNK, -1), vals.reshape(C, _CHUNK, 6),
                 n_bins), np.float64)
             return out[..., :3].sum(axis=0) + out[..., 3:].sum(axis=0)
-        f = _hist_flat if cfg.get("variant") == "flat" else _hist_vmap
         vals = jnp.stack([jnp.asarray(w, jnp.float32),
                           jnp.asarray(wy, jnp.float32),
                           jnp.asarray(wy2, jnp.float32)], axis=1)
-        return np.asarray(f(jnp.asarray(codes, jnp.int32), vals, n_bins),
-                          np.float64)
+        return np.asarray(_hist_vmap(jnp.asarray(codes, jnp.int32), vals,
+                                     n_bins), np.float64)
     return hist
 
 
@@ -375,13 +339,11 @@ def _hist_split_pallas():
     from repro.kernels.histsplit.ops import histograms
 
     def hist(codes, w, wy, wy2, n_bins, interpret=None, config=None):
-        cfg = config if config is not None else autotune.plan(
-            "hist_split", "pallas", np.asarray(codes).size)
-        return np.asarray(histograms(
-            codes, w, wy, wy2, n_bins,
-            tile_p=int(cfg.get("tile_p", 2048)),
-            variant=cfg.get("variant", "fused"),
-            interpret=interpret), np.float64)
+        variant = ("partials" if _is_compensated(config, default=False)
+                   else "fused")
+        return np.asarray(histograms(codes, w, wy, wy2, n_bins,
+                                     variant=variant, interpret=interpret),
+                          np.float64)
     return hist
 
 
@@ -417,10 +379,6 @@ def _finish_from_sats(coresets, preps, sats, k, eps):
     return out
 
 
-def _compress_size(coresets) -> int:
-    return 3 * sum(int(cs.n) * int(cs.m) for cs in coresets)
-
-
 @register("streaming_compress", "numpy")
 def _streaming_compress_numpy():
     def sc(coresets, k=None, eps=None, config=None):
@@ -445,10 +403,8 @@ def _streaming_compress_xla():
 
     def sc(coresets, k=None, eps=None, config=None):
         from repro.core.streaming import _recompress_prep
-        cfg = config if config is not None else autotune.plan(
-            "streaming_compress", "xla", _compress_size(coresets))
         preps = [_recompress_prep(cs) for cs in coresets]
-        if cfg.get("compensated", True):
+        if _is_compensated(config):
             sats = _compensated(f_comp, _stack_rasters(preps, np.float64))
         else:
             sats = np.asarray(f(jnp.asarray(_stack_rasters(preps))))
@@ -463,17 +419,13 @@ def _streaming_compress_pallas():
 
     def sc(coresets, k=None, eps=None, interpret=None, config=None):
         from repro.core.streaming import _recompress_prep
-        cfg = config if config is not None else autotune.plan(
-            "streaming_compress", "pallas", _compress_size(coresets))
-        tile = int(cfg.get("tile", 256))
         preps = [_recompress_prep(cs) for cs in coresets]
-        if cfg.get("compensated", True):
+        if _is_compensated(config):
             sats = _compensated(functools.partial(
-                sat_stack_comp, tile=tile, interpret=interpret),
+                sat_stack_comp, interpret=interpret),
                 _stack_rasters(preps, np.float64))
         else:
             sats = np.asarray(sat_stack(jnp.asarray(_stack_rasters(preps)),
-                                        tile=tile, interpret=interpret,
-                                        donate=True))
+                                        interpret=interpret, donate=True))
         return _finish_from_sats(coresets, preps, sats, k, eps)
     return sc

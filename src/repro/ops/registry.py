@@ -10,28 +10,24 @@ maps to up to three registered *backends*,
     pallas  — the TPU kernel (interpret-mode on CPU)
 
 and callers go through :func:`dispatch`, never through a kernel module
-directly.  Selection order:
+directly.  An explicit ``backend=`` argument wins; otherwise
+:func:`select_backend` applies one rule:
 
-  1. explicit ``backend=`` argument (callers that must pin a path);
-  2. a :func:`backend_override` context (tests);
-  3. the ``REPRO_OPS_BACKEND`` environment variable — either one backend
-     name for every op (``REPRO_OPS_BACKEND=pallas``) or a comma list of
-     ``op=backend`` pairs with an optional bare default
-     (``REPRO_OPS_BACKEND=xla,hist_split=numpy``);
-  4. the **autotune cache** (see ``autotune.py``): a persisted, measured
-     winner for this (op, device, shape bucket) — only consulted when it
-     beat the numpy oracle at tune time, and for precision-pinned ops only
-     with a passing compensated-parity certificate;
-  5. capability: on a TPU host, ``pallas`` (the kernels are written for
-     it; the integral-image kernels run compensated two-float scans by
-     default — plain f32 images lose the S2 - S1^2/S0 cancellation on
-     signals with a DC offset);
-  6. size: below the per-op ``XLA_SIZE_THRESHOLD`` the numpy oracle wins
-     (no dispatch/compile overhead), above it the jitted xla path.
-     Precision-critical ops (``XLA_SIZE_THRESHOLD[op] is None``) never
-     size-promote to the float32 accelerator backends, and interpret-mode
-     Pallas is never auto-selected — on CPU it is a correctness path, not
-     a fast one.
+  1. the innermost :func:`backend_override` context, if one is active
+     (tests);
+  2. on a TPU host, ``pallas`` (the kernels are written for it; the
+     integral-image kernels run compensated two-float scans by default —
+     plain f32 images lose the S2 - S1^2/S0 cancellation on signals with
+     a DC offset);
+  3. ``xla`` where the op has an ``XLA_SIZE_THRESHOLD`` and the problem
+     reaches it;
+  4. otherwise the numpy oracle.
+
+Precision-critical ops (``XLA_SIZE_THRESHOLD[op] is None``) therefore
+never size-promote to the float32 accelerator backends off a TPU, and
+interpret-mode Pallas is never selected on the CPU — there it is a
+correctness path, not a fast one.  Selection reads neither the
+environment nor any file: it depends on the platform and the input only.
 
 Implementations are registered as *factories* resolved on first use, so
 importing ``repro.ops`` pulls in neither jax nor the kernel packages and
@@ -51,7 +47,7 @@ from repro.obs import profile as _profile
 from repro.obs import trace as _trace
 
 __all__ = [
-    "OPS", "BACKENDS", "ENV_VAR", "BackendError", "register",
+    "OPS", "BACKENDS", "BackendError", "register",
     "available_backends", "select_backend", "resolve", "dispatch",
     "backend_override", "snapshot", "device_info",
 ]
@@ -59,17 +55,16 @@ __all__ = [
 OPS = ("sat_moments", "delta_sat", "fitting_loss", "fitting_loss_batched",
        "hist_split", "streaming_compress")
 BACKENDS = ("numpy", "xla", "pallas")
-ENV_VAR = "REPRO_OPS_BACKEND"
 
 # auto-selection crossover (problem "size" is op-specific, computed by the
 # public wrappers in __init__): below -> numpy oracle, above -> jitted xla.
 # None = NEVER size-promote: sat_moments, delta_sat, hist_split and
 # streaming_compress feed the variance identity S2 - S1^2/S0, which is
 # catastrophically cancellation-sensitive — their float32 xla/pallas
-# backends are only used when explicitly pinned (env/override), tuned with
-# a parity certificate, or on TPU, where the integral-image backends
-# default to their compensated configs.  The two loss ops sum non-negative
-# terms, so f32 promotion is safe.
+# backends are only used when explicitly pinned (argument/override) or on
+# TPU, where the integral-image backends default to their compensated
+# configs.  The two loss ops sum non-negative terms, so f32 promotion is
+# safe.
 XLA_SIZE_THRESHOLD = {
     "sat_moments": None,               # precision-critical (f64 oracle)
     "delta_sat": None,                 # patches the same integral images
@@ -106,37 +101,6 @@ def register(op: str, backend: str):
 
 def available_backends(op: str) -> tuple[str, ...]:
     return tuple(b for b in BACKENDS if (op, b) in _FACTORIES)
-
-
-def _env_choice(op: str) -> str | None:
-    """Parse REPRO_OPS_BACKEND: bare default + op-specific overrides."""
-    spec = os.environ.get(ENV_VAR, "").strip()
-    if not spec:
-        return None
-    default = specific = None
-    for part in spec.split(","):
-        part = part.strip()
-        if not part:
-            continue
-        if "=" in part:
-            o, b = (s.strip() for s in part.split("=", 1))
-            if o not in OPS:
-                # a typo'd op name must not silently pin nothing — the
-                # operator asked for a precision/backend pin and would get
-                # the auto-selection rules instead
-                raise BackendError(
-                    f"{ENV_VAR}={spec!r} names unknown op {o!r}; "
-                    f"ops are {OPS}")
-            if o == op:
-                specific = b
-        elif default is None:
-            default = part
-    choice = specific or default
-    if choice is not None and choice not in BACKENDS:
-        raise BackendError(
-            f"{ENV_VAR}={spec!r} names unknown backend {choice!r}; "
-            f"valid backends are {BACKENDS}")
-    return choice
 
 
 @functools.cache
@@ -178,13 +142,6 @@ def select_backend(op: str, size: int | None = None) -> str:
         raise BackendError(f"unknown op {op!r}; ops are {OPS}")
     if _OVERRIDE:
         return _OVERRIDE[-1]
-    env = _env_choice(op)
-    if env is not None:
-        return env
-    from . import autotune
-    tuned = autotune.tuned_backend(op, size)
-    if tuned is not None:
-        return tuned
     if _platform_is_tpu():
         return "pallas"
     thr = XLA_SIZE_THRESHOLD[op]
@@ -268,7 +225,6 @@ def snapshot() -> dict:
         out[op] = {
             "available": list(available_backends(op)),
             "selected": select_backend(op),
-            "env_override": _env_choice(op),
             "xla_threshold": XLA_SIZE_THRESHOLD[op],
         }
     return out

@@ -708,7 +708,6 @@ class _Handler(BaseHTTPRequestHandler):
         elif v1_path == "/v1/stats":
             self._reply_json(200, eng.stats(), deprecated_for=successor)
         else:  # /v1/metrics
-            eng.sync_autotune_metrics()   # scrape sees fresh ops_autotune_*
             self._reply_json(200, eng.metrics.render().encode(),
                              content_type="text/plain; version=0.0.4",
                              deprecated_for=successor)

@@ -26,7 +26,6 @@ import time
 import numpy as np
 
 from repro import obs, ops
-from repro.ops import autotune
 from repro.core.coreset import SignalCoreset, signal_coreset, signal_coreset_to_size
 from repro.core.sharded import (MESH_BACKEND, fitting_loss_batched,
                                 sharded_coreset)
@@ -291,10 +290,6 @@ class CoresetEngine:
         self._forests: "collections.OrderedDict[tuple, tuple]" = \
             collections.OrderedDict()
         self._forests_lock = threading.Lock()
-        # last autotune counter values already folded into self.metrics —
-        # autotune's counters are process-global monotonic, ServiceMetrics
-        # counters are per-engine, so each sync adds only the delta
-        self._autotune_synced: dict[str, int] = {}
 
         # ops-dispatch profiling: the registry's hook seam feeds per-(op,
         # backend, shape-bucket) wall time into THIS engine's metrics, so
@@ -1106,22 +1101,7 @@ class CoresetEngine:
         return out
 
     # ------------------------------------------------------------- lifecycle
-    def sync_autotune_metrics(self) -> None:
-        """Fold the autotune module's process-global counters into this
-        engine's metrics as ``ops_autotune_*`` (delta since last sync), so
-        the Prometheus render and /v1/stats expose cache hit/miss, tune
-        runs, and promoted-to-compensated-f32 dispatch counts next to the
-        ``ops_backend_*`` series."""
-        for name, val in autotune.counters_snapshot().items():
-            delta = int(val) - self._autotune_synced.get(name, 0)
-            # a zero delta still registers the family, so the very first
-            # scrape sees every ops_autotune_* series (at 0) rather than
-            # the family popping into existence mid-run
-            self.metrics.inc(f"ops_autotune_{name}", max(delta, 0))
-            self._autotune_synced[name] = int(val)
-
     def stats(self) -> dict:
-        self.sync_autotune_metrics()
         return {"signals": self.list_signals(), "cache": self.cache.stats(),
                 "builds_in_flight": self.scheduler.in_flight(),
                 "queries_in_flight": self.queries.in_flight(),
@@ -1130,7 +1110,6 @@ class CoresetEngine:
                     "window_s": self.queries.window,
                     "max_fuse": self.queries.max_fuse},
                 "ops_backends": ops.snapshot(),
-                "ops_autotune": autotune.snapshot(),
                 "tracing": obs.TRACER.stats(),
                 "admission": ({**self.admission.snapshot(),
                                "scheduler_load": {

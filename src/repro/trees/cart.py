@@ -16,7 +16,7 @@ The histogram build is the training hot spot; it dispatches through
 ``repro.ops.hist_split`` — numpy bincount oracle, xla segment-sum, or the
 one-hot-matmul Pallas kernel in ``repro.kernels.histsplit`` (GPU scatter-
 atomics have no TPU analogue — see DESIGN.md §4).  ``hist_backend``
-selects: "auto" (dispatcher rules / REPRO_OPS_BACKEND), "numpy", "xla",
+selects: "auto" (the dispatcher's platform/size rule), "numpy", "xla",
 "pallas", or the legacy alias "jax" (= "pallas", the kernel path).
 """
 from __future__ import annotations

@@ -82,7 +82,7 @@ def tune_k(values: np.ndarray, train_mask: np.ndarray, test_mask: np.ndarray,
     """Sweep max_leaves=k over the given training methods; §5 protocol.
 
     ``hist_backend`` selects the split-histogram op backend for the default
-    forest factory ("auto" = dispatcher rules / REPRO_OPS_BACKEND).
+    forest factory ("auto" = the dispatcher's platform/size rule).
     """
     rng = rng or np.random.default_rng(0)
     forest_factory = forest_factory or (lambda k: RandomForestRegressor(

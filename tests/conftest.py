@@ -1,18 +1,7 @@
-import os
 import pathlib
 import sys
-import tempfile
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
-
-# Tier-1 selection tests assert the *static* dispatch heuristics; a warm
-# developer tuning cache (.autotune.json in the checkout) must not flip them.
-# Point the autotune cache at a fresh per-run path unless the environment
-# already pins one; tuning tests repoint it again via monkeypatch.
-os.environ.setdefault(
-    "REPRO_AUTOTUNE_CACHE",
-    os.path.join(tempfile.gettempdir(),
-                 f"repro_test_autotune_{os.getpid()}.json"))
 
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
 # real single device; only launch/dryrun.py forces 512 host devices.

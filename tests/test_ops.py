@@ -1,6 +1,8 @@
-"""repro.ops — registry selection rules, backend parity (including awkward
-shapes through the batched Pallas kernel's pad paths), the env override,
-and the real multi-device mesh path for the dispatched batched loss."""
+"""repro.ops — the registry's one selection rule, backend parity (including
+awkward shapes through the batched Pallas kernel's pad paths), compensated
+f32 parity against the f64 oracle, and the real multi-device mesh path for
+the dispatched batched loss."""
+import itertools
 import os
 import subprocess
 import sys
@@ -13,6 +15,7 @@ import pytest
 from repro import ops
 from repro.core import fitting_loss, random_tree_segmentation, signal_coreset
 from repro.data import piecewise_signal
+from repro.kernels.sat2d.ref import scaled_rel_err
 
 RNG = np.random.default_rng(0)
 
@@ -35,41 +38,43 @@ def test_every_op_has_all_three_backends():
         assert ops.available_backends(op) == ops.BACKENDS
 
 
-def test_env_override_bare_and_per_op(monkeypatch):
-    monkeypatch.setenv(ops.ENV_VAR, "xla")
-    assert all(ops.select_backend(op) == "xla" for op in ops.OPS)
-    monkeypatch.setenv(ops.ENV_VAR, "xla,hist_split=numpy")
-    assert ops.select_backend("fitting_loss") == "xla"
-    assert ops.select_backend("hist_split") == "numpy"
-    monkeypatch.setenv(ops.ENV_VAR, "nonsense")
-    with pytest.raises(ops.BackendError):
-        ops.select_backend("fitting_loss")
-    # a typo'd OP name must fail loudly, not silently pin nothing
-    monkeypatch.setenv(ops.ENV_VAR, "histsplit=numpy")
-    with pytest.raises(ops.BackendError):
-        ops.select_backend("hist_split")
+def _tpu(monkeypatch, on: bool) -> None:
+    monkeypatch.setattr(ops.registry, "_platform_is_tpu", lambda: on)
+
+
+@pytest.mark.parametrize("op,platform,size", itertools.product(
+    ops.OPS, ("cpu", "tpu"), ("below", "at_or_above")))
+def test_selection_rule(monkeypatch, op, platform, size):
+    """The one rule: ``pallas`` on a TPU; elsewhere ``xla`` once an op with
+    a size threshold reaches it, else the numpy oracle.  The precision-
+    critical ops (threshold None: they feed S2 - S1^2/S0) never size-
+    promote at any size, and interpret-mode Pallas is never selected off a
+    TPU."""
+    _tpu(monkeypatch, platform == "tpu")
+    thr = ops.registry.XLA_SIZE_THRESHOLD[op]
+    n = {"below": (thr or 1 << 16) - 1,
+         "at_or_above": thr if thr is not None else 10**12}[size]
+    if platform == "tpu":
+        want = "pallas"
+    elif thr is not None and size == "at_or_above":
+        want = "xla"
+    else:
+        want = "numpy"
+    assert ops.select_backend(op, n) == want
+    assert ops.selected_backend(op, n) == want
+    assert ops.selected_backend(op, n, backend="numpy") == "numpy"
 
 
 def test_backend_override_context_beats_env(monkeypatch):
-    monkeypatch.setenv(ops.ENV_VAR, "xla")
+    """The override context beats every other selection input — the
+    platform rule included; no environment variable takes part."""
+    _tpu(monkeypatch, True)
     with ops.backend_override("numpy"):
         assert ops.select_backend("sat_moments") == "numpy"
-    assert ops.select_backend("sat_moments") == "xla"
-
-
-def test_size_auto_selection_numpy_small_xla_large(monkeypatch):
-    monkeypatch.delenv(ops.ENV_VAR, raising=False)
-    thr = ops.registry.XLA_SIZE_THRESHOLD["fitting_loss_batched"]
-    assert ops.select_backend("fitting_loss_batched", thr - 1) == "numpy"
-    assert ops.select_backend("fitting_loss_batched", thr) == "xla"
-
-
-def test_precision_critical_ops_never_size_promote(monkeypatch):
-    # sat_moments / hist_split feed S2 - S1^2/S0 (catastrophic cancellation
-    # in float32): the f64 numpy oracle must hold at ANY size unless pinned
-    monkeypatch.delenv(ops.ENV_VAR, raising=False)
-    for op in ("sat_moments", "hist_split"):
-        assert ops.select_backend(op, 10**12) == "numpy"
+        with ops.backend_override("xla"):
+            assert ops.select_backend("sat_moments") == "xla"
+        assert ops.select_backend("sat_moments") == "numpy"
+    assert ops.select_backend("sat_moments") == "pallas"
 
 
 def test_unknown_backend_and_op_raise():
@@ -235,6 +240,62 @@ def test_compensated_dispatch_splits_transfer_and_device(op):
     assert kids["dispatch.d2h"]["attrs"]["bytes"] == down
     assert sum(k["duration_us"] for k in kids.values()) <= \
         disp["duration_us"]
+
+
+# -------------------------------- compensated-f32 parity, awkward shapes
+# The two-float paths of the integral-image and histogram ops must match
+# the f64 oracle to 1e-6 in the scaled measure S2 - S1^2/S0 feels.
+PARITY_RTOL = 1e-6
+_COMP_RNG = np.random.default_rng(7)
+_COMPENSATED = {"compensated": True}
+
+
+def test_compensated_sat_parity_off_tile_quantum():
+    # 131 x 67: off the 128-row tile quantum, large offset so plain f32
+    # cumsum error is visible while the two-float path stays in bound
+    y = _COMP_RNG.normal(size=(131, 67)) + 1e6
+    want = ops.sat_moments(y, backend="numpy")
+    got = ops.sat_moments(y, backend="xla", config=_COMPENSATED)
+    assert scaled_rel_err(got, want) <= PARITY_RTOL
+
+
+def test_compensated_delta_sat_parity():
+    y = _COMP_RNG.normal(size=(34, 257)) + 1e5  # odd band, off-quantum width
+    carry = ops.sat_moments(y[:1], backend="numpy")[:, 0, :]
+    want = ops.delta_sat(carry, y[1:], backend="numpy")
+    got = ops.delta_sat(carry, y[1:], backend="xla", config=_COMPENSATED)
+    assert scaled_rel_err(got, want) <= PARITY_RTOL
+
+
+def _hist_problem(P, F, B, zero_frac=0.0):
+    codes = _COMP_RNG.integers(0, B, size=(P, F)).astype(np.uint8)
+    w = _COMP_RNG.uniform(0.5, 1.5, P)
+    if zero_frac:
+        w[_COMP_RNG.random(P) < zero_frac] = 0.0  # zero-weight rows vanish
+    yv = _COMP_RNG.normal(size=P) + 100.0
+    return codes, w, w * yv, w * yv * yv
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"],
+                         ids=["xla-chunked", "pallas-partials"])
+def test_compensated_hist_parity_awkward_shapes(backend):
+    # P=4097: off both the 2048 Pallas tile and the 8192 XLA chunk quantum,
+    # so the padded tail blocks (zero-weight by construction) are exercised
+    codes, w, wy, wy2 = _hist_problem(4097, 3, 16, zero_frac=0.1)
+    want = ops.hist_split(codes, w, wy, wy2, 16, backend="numpy")
+    got = ops.hist_split(codes, w, wy, wy2, 16, backend=backend,
+                         config=_COMPENSATED)
+    assert scaled_rel_err(got, want) <= PARITY_RTOL
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"],
+                         ids=["xla-chunked", "pallas-partials"])
+def test_compensated_hist_parity_single_bin(backend):
+    codes, w, wy, wy2 = _hist_problem(1023, 2, 1)    # n_bins=1 degenerate
+    want = ops.hist_split(codes, w, wy, wy2, 1, backend="numpy")
+    got = ops.hist_split(codes, w, wy, wy2, 1, backend=backend,
+                         config=_COMPENSATED)
+    assert scaled_rel_err(got, want) <= PARITY_RTOL
 
 
 def test_delta_sat_validates_shapes():
