@@ -14,8 +14,7 @@ Eq. (1) of the paper.
 
 The unmasked/unweighted build routes through the ``repro.ops.sat_moments``
 dispatcher (numpy oracle on host, the ``repro.kernels.sat2d`` Pallas kernel
-on TPU, env-overridable); this module remains the owner of the float64
-query API.
+on TPU); this module remains the owner of the float64 query API.
 
 The balanced partition's monotone searches (``max_col_extent`` and
 ``max_row_extent``) run through :class:`BandQuery` and :class:`ColumnQuery`:
@@ -78,7 +77,8 @@ class PrefixStats:
             # the common (unmasked, unweighted) path goes through the op
             # dispatcher: numpy oracle by default on host (same float64
             # cumsums as before), the compensated sat2d Pallas kernel on
-            # TPU; the query API stays float64.
+            # TPU, whose images land in the query layout and are wrapped
+            # uncopied; the query API stays float64.
             from repro import ops
             return PrefixStats.from_sat(
                 np.asarray(ops.sat_moments(y), np.float64))
@@ -97,8 +97,20 @@ class PrefixStats:
     @staticmethod
     def from_sat(s: np.ndarray) -> "PrefixStats":
         """Wrap (3, n, m) inclusive integral images (one ``sat_moments`` /
-        ``delta_sat`` output) into the zero-padded (n+1, m+1) query layout."""
+        ``delta_sat`` output) into the zero-padded (n+1, m+1) query layout.
+
+        The interior ``buf[:, 1:, 1:]`` of a zero-bordered C-contiguous
+        (3, n+1, m+1) f64 buffer, which the compensated ``sat_moments``
+        backends return, is wrapped without a copy: the images are views
+        of ``buf``.  Any other input is copied."""
         n, m = s.shape[1], s.shape[2]
+        buf = s.base
+        if (isinstance(buf, np.ndarray) and buf.dtype == np.float64
+                and buf.shape == (3, n + 1, m + 1) and buf.flags.c_contiguous
+                and s.dtype == np.float64 and s.strides == buf.strides
+                and s.ctypes.data == buf[:, 1:, 1:].ctypes.data
+                and not buf[:, 0, :].any() and not buf[:, :, 0].any()):
+            return PrefixStats(*buf)
         ps = []
         for c in range(3):
             out = np.zeros((n + 1, m + 1), dtype=np.float64)

@@ -158,16 +158,12 @@ def _scan_2d_comp(hi, lo, tile, interpret, init=None):
 @_comp_jit
 def sat_moments_comp(y_hi, y_lo, tile: int = _DEFAULT_TILE,
                      interpret: bool | None = None):
-    """(hi, lo) pairs of the (3, n, m) moment integral images — the Pallas
-    twin of ``ref.sat_moments_comp_ref`` (analytic ones channel)."""
-    n, m = y_hi.shape
-    h, l = _scan_2d_comp(jnp.stack([y_hi, y_hi * y_hi]),
+    """(hi, lo) pairs of the S1 and S2 integral images, (2, n, m) each —
+    the Pallas twin of ``ref.sat_moments_comp_ref`` (the count image is
+    made on the host)."""
+    return _scan_2d_comp(jnp.stack([y_hi, y_hi * y_hi]),
                          jnp.stack([y_lo, 2.0 * y_hi * y_lo]), tile,
                          interpret)
-    counts = ((jnp.arange(1, n + 1, dtype=jnp.float32)[:, None]
-               * jnp.arange(1, m + 1, dtype=jnp.float32)[None, :])[None])
-    return (jnp.concatenate([counts, h], axis=0),
-            jnp.concatenate([jnp.zeros_like(counts), l], axis=0))
 
 
 @_comp_jit

@@ -94,23 +94,20 @@ def comp_cumsum(hi: jnp.ndarray, lo: jnp.ndarray,
 
 def sat_moments_comp_ref(y_hi: jnp.ndarray, y_lo: jnp.ndarray
                          ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(hi, lo) pairs of the (3, n, m) moment integral images.
+    """(hi, lo) pairs of the S1 and S2 moment integral images, (2, n, m)
+    each.
 
-    The ones channel is analytic — ``S0[i, j] = (i+1)(j+1)`` exactly, and
-    f32 holds integers up to 2^24 — so only the S1/S2 channels pay for the
-    compensated scans.  ``y^2`` enters as the pair
+    The count image ``S0[i, j] = (i+1)(j+1)`` depends on the shape alone,
+    so the device neither computes nor ships it: the host makes it exactly
+    in f64 where it lands the pairs (``repro.ops.backends``).  Only S1 and
+    S2 pay for the compensated scans.  ``y^2`` enters as the pair
     ``(hi*hi, 2*hi*lo)``: the dropped ``lo^2`` term is ~2^-96 relative.
     """
-    n, m = y_hi.shape
     hi2, lo2 = y_hi * y_hi, 2.0 * y_hi * y_lo
     stk_hi = jnp.stack([y_hi, hi2], 0)
     stk_lo = jnp.stack([y_lo, lo2], 0)
     h, l = comp_cumsum(stk_hi, stk_lo, axis=2)
-    h, l = comp_cumsum(h, l, axis=1)
-    counts = ((jnp.arange(1, n + 1, dtype=jnp.float32)[:, None]
-               * jnp.arange(1, m + 1, dtype=jnp.float32)[None, :])[None])
-    return (jnp.concatenate([counts, h], axis=0),
-            jnp.concatenate([jnp.zeros_like(counts), l], axis=0))
+    return comp_cumsum(h, l, axis=1)
 
 
 def delta_sat_comp_ref(carry_hi, carry_lo, tail_hi, tail_lo

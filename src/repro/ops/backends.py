@@ -25,6 +25,13 @@ and recombine the (hi, lo) pairs in f64 on the host: accelerator-resident
 f32 arithmetic whose result matches the f64 oracle to ~1e-10 scaled
 relative error.  ``_compensated`` runs them, and under a live dispatch
 span times the upload, the device call and the read-back apart.
+
+The compensated ``sat_moments`` reads back the S1 and S2 pairs alone,
+16·n·m bytes (``delta_sat`` reads back all three channels, 24·b·m).
+``_land_sat`` sums them straight into a zero-bordered (3, n+1, m+1) f64
+buffer in ``PrefixStats``' query layout, beside the exact count image
+made on the host, and returns its (3, n, m) interior, which
+``PrefixStats.from_sat`` wraps without a copy.
 """
 from __future__ import annotations
 
@@ -48,9 +55,25 @@ def _recombine(pair) -> np.ndarray:
     return np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
 
 
-def _compensated(call, *host) -> np.ndarray:
+def _land_sat(pair) -> np.ndarray:
+    """f64 (3, n, m) integral images from the (2, n, m) S1/S2 pairs of a
+    compensated ``sat_moments`` call: the interior ``buf[:, 1:, 1:]`` of a
+    zero-bordered (3, n+1, m+1) buffer whose channel 0 is the exact count
+    image ``i·j``.  Each f32 pair is summed in f64 into its place, with no
+    f64 temporaries; the bits are ``_recombine``'s."""
+    hi, lo = (np.asarray(a) for a in pair)
+    _, n, m = hi.shape
+    buf = np.empty((3, n + 1, m + 1))
+    np.multiply.outer(np.arange(n + 1.0), np.arange(m + 1.0), out=buf[0])
+    buf[1:, 0, :] = 0.0
+    buf[1:, 1:, 0] = 0.0
+    np.add(hi, lo, out=buf[1:, 1:, 1:], dtype=np.float64)
+    return buf[:, 1:, 1:]
+
+
+def _compensated(call, *host, land=_recombine) -> np.ndarray:
     """``call`` on the (hi, lo) f32 pairs of the f64 host arrays ``host``,
-    its output pair recombined in f64 on the host.
+    its output pair brought to f64 on the host by ``land``.
 
     Inside a live ``ops.dispatch`` span the dispatch gets three children,
     each ended at a sync: ``dispatch.h2d`` (split and upload, ``bytes``
@@ -59,7 +82,7 @@ def _compensated(call, *host) -> np.ndarray:
     untraced call always has: no sync, the same output."""
     from repro.kernels.sat2d.ref import split_hi_lo
     if not obs.current_span():
-        return _recombine(call(*(a for x in host for a in split_hi_lo(x))))
+        return land(call(*(a for x in host for a in split_hi_lo(x))))
     import jax
     with obs.span("dispatch.h2d") as sp:
         pairs = jax.block_until_ready(
@@ -72,7 +95,7 @@ def _compensated(call, *host) -> np.ndarray:
     del pairs
     with obs.span("dispatch.d2h") as sp:
         sp.set_attr("bytes", sum(int(a.nbytes) for a in out))
-        return _recombine(out)
+        return land(out)
 
 
 # ------------------------------------------------------------- sat_moments
@@ -102,7 +125,7 @@ def _sat_moments_xla():
 
     def sat_moments(y, config=None):
         if _is_compensated(config):
-            return _compensated(f_comp, y)
+            return _compensated(f_comp, y, land=_land_sat)
         return np.asarray(f(jnp.asarray(y, jnp.float32)))
     return sat_moments
 
@@ -116,7 +139,7 @@ def _sat_moments_pallas():
     def sat_moments(y, interpret=None, config=None):
         if _is_compensated(config):
             return _compensated(functools.partial(
-                sat_moments_comp, interpret=interpret), y)
+                sat_moments_comp, interpret=interpret), y, land=_land_sat)
         # donate: the device copy made here is never reused on the host
         return np.asarray(kernel_sat_moments(
             jnp.asarray(y, jnp.float32), interpret=interpret, donate=True))

@@ -214,10 +214,13 @@ def test_compensated_dispatch_splits_transfer_and_device(op):
     n, m = 37, 29
     y = rng.normal(size=(n, m)) + 100.0
     if op == "sat_moments":
+        # the device ships the S1/S2 pairs alone; the counts are the host's
         args = (y,)
-        want = ops.backends._recombine(
-            jax.jit(sat_moments_comp_ref)(*split_hi_lo(y)))
-        up, down = 8 * n * m, 24 * n * m
+        h, l = jax.jit(sat_moments_comp_ref)(*split_hi_lo(y))
+        counts = np.multiply.outer(np.arange(1.0, n + 1), np.arange(1.0, m + 1))
+        want = np.concatenate([counts[None], np.asarray(h, np.float64)
+                               + np.asarray(l, np.float64)])
+        up, down = 8 * n * m, 16 * n * m
     else:
         carry = ops.sat_moments(y, backend="numpy")[:, 9, :]
         args = (carry, y[10:])
@@ -240,6 +243,80 @@ def test_compensated_dispatch_splits_transfer_and_device(op):
     assert kids["dispatch.d2h"]["attrs"]["bytes"] == down
     assert sum(k["duration_us"] for k in kids.values()) <= \
         disp["duration_us"]
+
+
+@pytest.mark.parametrize("shape", [(37, 29), (2 * 128 + 3, 3 * 128 + 5)],
+                         ids=["37x29", "259x389"])
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_compensated_build_lands_in_query_layout(backend, shape):
+    """A compensated build's images are the read-back pairs summed in f64
+    beside the exact host counts, as three planes of one zero-bordered
+    (3, n+1, m+1) buffer that ``from_sat`` wraps without a copy."""
+    import jax
+    from repro.core.stats import PrefixStats
+    from repro.kernels.sat2d.ops import sat_moments_comp
+    from repro.kernels.sat2d.ref import sat_moments_comp_ref, split_hi_lo
+    n, m = shape
+    y = np.random.default_rng(41).normal(size=shape) + 100.0
+    if backend == "xla":
+        h, l = jax.jit(sat_moments_comp_ref)(*split_hi_lo(y))
+    else:   # the Pallas scan sums in its own blocked order
+        h, l = sat_moments_comp(*split_hi_lo(y), interpret=True)
+    assert h.shape == l.shape == (2, n, m)
+    with ops.backend_override(backend):
+        ps = PrefixStats.build(y)
+    planes = (ps.p0, ps.p1, ps.p2)
+    np.testing.assert_array_equal(
+        ps.p0, np.multiply.outer(np.arange(n + 1), np.arange(m + 1)))
+    for c in (1, 2):
+        np.testing.assert_array_equal(planes[c][0], 0.0)
+        np.testing.assert_array_equal(planes[c][:, 0], 0.0)
+        np.testing.assert_array_equal(
+            planes[c][1:, 1:], np.asarray(h[c - 1], np.float64)
+            + np.asarray(l[c - 1], np.float64))
+    buf = ps.p0.base
+    assert buf.shape == (3, n + 1, m + 1) and buf.flags.c_contiguous
+    for p in planes:
+        assert p.flags.c_contiguous and p.base is buf
+    with ops.backend_override("numpy"):
+        want = PrefixStats.build(y)
+    for got_c, want_c in zip(planes, (want.p0, want.p1, want.p2)):
+        assert scaled_rel_err(got_c, want_c) <= PARITY_RTOL
+
+
+def _bordered(s, border=0.0):
+    buf = np.full((3, s.shape[1] + 1, s.shape[2] + 1), border)
+    buf[:, 1:, 1:] = s
+    return buf
+
+
+@pytest.mark.parametrize("case", ["plain", "nonzero_border", "sub_view",
+                                  "f32_buffer"])
+def test_from_sat_copies_anything_but_a_landing(case):
+    """Only the interior view of a zero-bordered f64 (3, n+1, m+1) buffer
+    is wrapped in place; any other (3, n, m) input gives fresh planes with
+    the same values, so a later in-place patch cannot reach the caller's
+    array."""
+    from repro.core.stats import PrefixStats
+    y = np.random.default_rng(43).normal(size=(9, 7))
+    s = ops.sat_moments(y, backend="numpy")
+    if case == "plain":
+        arg = s
+    elif case == "nonzero_border":
+        arg = _bordered(s, border=1.0)[:, 1:, 1:]
+    elif case == "sub_view":    # the interior of a larger zero border
+        arg = np.pad(_bordered(s), ((0, 0), (0, 1), (0, 1)))[:, 1:-1, 1:-1]
+    else:
+        arg = _bordered(s).astype(np.float32)[:, 1:, 1:]
+    ps = PrefixStats.from_sat(arg)
+    for c, p in enumerate((ps.p0, ps.p1, ps.p2)):
+        assert not np.shares_memory(p, arg)
+        np.testing.assert_array_equal(p[0], 0.0)
+        np.testing.assert_array_equal(p[:, 0], 0.0)
+        np.testing.assert_array_equal(p[1:, 1:], arg[c])
+    landed = _bordered(s)[:, 1:, 1:]
+    ps = PrefixStats.from_sat(landed)
+    assert all(np.shares_memory(p, landed) for p in (ps.p0, ps.p1, ps.p2))
 
 
 # -------------------------------- compensated-f32 parity, awkward shapes

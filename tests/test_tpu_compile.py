@@ -8,7 +8,8 @@ tests compile each kernel of the served path for a described — not attached
 k = 16 trees, the built coreset's ~1.6k blocks) and check that the Pallas
 kernel is in the compiled program.  The compensated (two-float) integral
 images, which the chip runs by default, are also checked against the
-device's memory.
+device's memory, and compiled at the benchmark's 2400 x 2400 tile and its
+600 x 2400 bands too.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import
@@ -26,6 +27,8 @@ STREAM_ROWS = N + 64    # rows of the smoke's raster after its append
 BLOCKS = 1588           # blocks of its (k=16, eps=0.2) coreset
 K = T = 16              # leaves per tree, trees per batch
 HIST_P, HIST_F, HIST_BINS = 6352, 2, 256   # forest fit on the coreset points
+TILE = 2400             # a benchmark record: one MODIS tile, built whole
+TILE_BAND = TILE // 4   # and as four row bands
 V5E_HBM_BYTES = 16 * 10**9
 
 
@@ -124,6 +127,19 @@ def test_sat_moments_comp_compiles(one_chip):
     compiled = sat_moments_comp.lower(y, y, interpret=False).compile()
     _assert_kernel(compiled)
     _fits(compiled)
+
+
+@pytest.mark.parametrize("rows", [TILE, TILE_BAND])
+def test_sat_moments_comp_compiles_at_cell_shapes(one_chip, rows):
+    """The benchmark's builds: the whole tile and each band read back the
+    S1/S2 pairs alone, (2, n, m) each; the host makes the counts."""
+    from repro.kernels.sat2d.ops import sat_moments_comp
+    y = _sds((rows, TILE), one_chip)
+    compiled = sat_moments_comp.lower(y, y, interpret=False).compile()
+    _assert_kernel(compiled)
+    _fits(compiled)
+    assert [(o.shape, o.dtype) for o in compiled.out_info] == \
+        [((2, rows, TILE), jnp.float32)] * 2
 
 
 @pytest.mark.parametrize("rows", [DELTA_ROWS, REPLACE_TAIL])
